@@ -24,12 +24,6 @@ Package map (TPU-first, not a port):
 
 __version__ = "0.1.0"
 
-# Shim first: modules below use jax.shard_map / jax.set_mesh /
-# jax.lax.pcast, synthesized on pre-0.5 jax (utils/jax_compat.py).
-from nanodiloco_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from nanodiloco_tpu.models.config import LlamaConfig  # noqa: F401
 from nanodiloco_tpu.parallel.diloco import Diloco, DilocoConfig  # noqa: F401
 

@@ -398,6 +398,20 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
     )
 
 
+def _setup_devices(args: argparse.Namespace) -> None:
+    """First thing a compiling subcommand does, before anything touches
+    a JAX backend: apply ``--force-cpu-devices`` and place the
+    persistent compile cache (utils.enable_compile_cache)."""
+    from nanodiloco_tpu.utils import (
+        enable_compile_cache,
+        force_virtual_cpu_devices,
+    )
+
+    if args.force_cpu_devices:
+        force_virtual_cpu_devices(args.force_cpu_devices)
+    enable_compile_cache()
+
+
 def build_generate_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="nanodiloco_tpu generate",
@@ -439,10 +453,7 @@ def build_generate_parser() -> argparse.ArgumentParser:
 
 def generate_main(argv: list[str]) -> None:
     args = build_generate_parser().parse_args(argv)
-    if args.force_cpu_devices:
-        from nanodiloco_tpu.utils import force_virtual_cpu_devices
-
-        force_virtual_cpu_devices(args.force_cpu_devices)
+    _setup_devices(args)
     import jax
 
     from nanodiloco_tpu.data import get_tokenizer
@@ -641,10 +652,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
 
 def serve_main(argv: list[str]) -> None:
     args = build_serve_parser().parse_args(argv)
-    if args.force_cpu_devices:
-        from nanodiloco_tpu.utils import force_virtual_cpu_devices
-
-        force_virtual_cpu_devices(args.force_cpu_devices)
+    _setup_devices(args)
     import signal
     import threading
     import time
@@ -1012,6 +1020,16 @@ def fleet_main(argv: list[str]) -> None:
     import signal
     import threading
     import time
+
+    import jax
+
+    # The router is a host-side process and a chip belongs to one
+    # process at a time: the replicas hold the chips. What this process
+    # computes itself — the deploy watcher's orbax directory reads and
+    # the canary's eval loss — stays on the CPU backend, so it can never
+    # take a chip from a replica it fronts or launches. (Set in config,
+    # not in os.environ: --autoscale-template children do not inherit it.)
+    jax.config.update("jax_platforms", "cpu")
 
     from nanodiloco_tpu.fleet import DeployController, FleetRouter, Replica
 
@@ -2236,10 +2254,7 @@ def main(argv: list[str] | None = None) -> None:
         report_main(argv[1:])
         return
     args = build_parser().parse_args(argv)
-    if args.force_cpu_devices:
-        from nanodiloco_tpu.utils import force_virtual_cpu_devices
-
-        force_virtual_cpu_devices(args.force_cpu_devices)
+    _setup_devices(args)
     # rank-0-only console, same gate as train()'s notices: on a pod every
     # host runs main(). Checked only after the device setup above — the
     # process index initializes the backend.

@@ -1,23 +1,28 @@
 """ctypes bindings for the native tokenshard reader (csrc/tokenshard.cpp).
 
-The shared library is built on first use with g++ (cached beside the
-source); every call degrades gracefully to a pure-numpy implementation
-when no compiler is available, so the framework never hard-depends on
-the native layer — it is a throughput upgrade, not a requirement.
+The shared library is built on first use with g++ from the committed
+source, into a file named by a hash of that source and the compiler
+flags — a library built from other source, with other flags or on
+another machine's ``-march`` is never picked up. Where no compiler is
+available every call runs a pure-numpy implementation of the same
+format; which reader is in use is said once on stderr.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "csrc")
 _SRC = os.path.join(_CSRC, "tokenshard.cpp")
-_LIB_PATH = os.path.join(_CSRC, "libtokenshard.so")
+# no -march=native: the checkout is copied between machines as it stands
+_FLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-pthread")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _lib_failed = False
@@ -26,42 +31,61 @@ _MAGIC = b"TSHRD\x01\x00\x00"
 _HEADER = 24
 
 
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        key = hashlib.sha256(f.read() + " ".join(_FLAGS).encode()).hexdigest()
+    return os.path.join(_CSRC, f"libtokenshard-{key[:16]}.so")
+
+
 def _build_and_load() -> ctypes.CDLL | None:
     global _lib, _lib_failed
     with _lock:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            if not os.path.exists(_LIB_PATH) or (
-                os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-            ):
+            path = _lib_path()
+            if not os.path.exists(path):
+                # build beside the target and rename: concurrent builders
+                # (test workers) each install a whole file
+                tmp = f"{path}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                     "-fPIC", "-pthread", "-o", _LIB_PATH, _SRC],
+                    ["g++", *_FLAGS, "-o", tmp, _SRC],
                     check=True, capture_output=True,
                 )
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.ts_write.restype = ctypes.c_int
-            lib.ts_write.argtypes = [ctypes.c_char_p, ctypes.c_void_p,
-                                     ctypes.c_uint64, ctypes.c_uint64]
-            lib.ts_open.restype = ctypes.c_void_p
-            lib.ts_open.argtypes = [ctypes.c_char_p]
-            lib.ts_n_seqs.restype = ctypes.c_uint64
-            lib.ts_n_seqs.argtypes = [ctypes.c_void_p]
-            lib.ts_seq_len.restype = ctypes.c_uint64
-            lib.ts_seq_len.argtypes = [ctypes.c_void_p]
-            lib.ts_close.argtypes = [ctypes.c_void_p]
-            lib.ts_gather.restype = ctypes.c_int
-            lib.ts_gather.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
-                                      ctypes.c_uint64, ctypes.c_void_p, ctypes.c_int]
-            lib.ts_shuffled_indices.argtypes = [
-                ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
-                ctypes.c_uint64, ctypes.c_void_p,
-            ]
-            _lib = lib
-        except Exception:
+                os.replace(tmp, path)
+            lib = ctypes.CDLL(path)
+        except (OSError, subprocess.CalledProcessError) as e:
             _lib_failed = True
-            _lib = None
+            detail = getattr(e, "stderr", b"") or b""
+            print(
+                f"[nanodiloco] tokenshard: numpy reader in use (native "
+                f"build/load failed: {e} {detail.decode(errors='replace')[-300:]})",
+                file=sys.stderr,
+            )
+            return None
+        lib.ts_write.restype = ctypes.c_int
+        lib.ts_write.argtypes = [ctypes.c_char_p, ctypes.c_void_p,
+                                 ctypes.c_uint64, ctypes.c_uint64]
+        lib.ts_open.restype = ctypes.c_void_p
+        lib.ts_open.argtypes = [ctypes.c_char_p]
+        lib.ts_n_seqs.restype = ctypes.c_uint64
+        lib.ts_n_seqs.argtypes = [ctypes.c_void_p]
+        lib.ts_seq_len.restype = ctypes.c_uint64
+        lib.ts_seq_len.argtypes = [ctypes.c_void_p]
+        lib.ts_close.argtypes = [ctypes.c_void_p]
+        lib.ts_gather.restype = ctypes.c_int
+        lib.ts_gather.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
+                                  ctypes.c_uint64, ctypes.c_void_p, ctypes.c_int]
+        lib.ts_shuffled_indices.argtypes = [
+            ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.c_uint64, ctypes.c_void_p,
+        ]
+        _lib = lib
+        print(
+            f"[nanodiloco] tokenshard: native reader in use "
+            f"({os.path.basename(path)})",
+            file=sys.stderr,
+        )
         return _lib
 
 

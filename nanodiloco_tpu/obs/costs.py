@@ -12,14 +12,18 @@ program at lowering time and logging it ONCE into the run JSONL turns
 regression-gateable artifact (``report cost``, ``mfu_analytic`` in
 ``report compare``).
 
-Scope honesty: the numbers come from ``Lowered.cost_analysis()`` — the
-pre-optimization HLO walked by XLA's cost model. Lowering is a trace +
-StableHLO emission (seconds, host-only); it does NOT pay a second XLA
-compile, and matmul/attention FLOPs — the MFU numerator — are
-invariant under the optimization passes that follow. ``bytes accessed``
-is the cost model's pre-fusion estimate and overstates what the
-optimized program touches; it is recorded for trend tracking, not as
-an HBM-traffic truth.
+Scope honesty: where the backend can walk un-compiled HLO (the CPU)
+the numbers come from ``Lowered.cost_analysis()`` — a trace + StableHLO
+emission (seconds, host-only), no second XLA compile; matmul/attention
+FLOPs — the MFU numerator — are invariant under the optimization passes
+that follow, and ``bytes accessed`` is the pre-fusion estimate, which
+overstates what the optimized program touches (trend tracking, not an
+HBM-traffic truth). The TPU plug-in answers nothing there, so on the
+chip ``lowered_cost`` compiles the program and reads the executable's
+analysis: the same cost model after optimization, paid for with one
+compile of each analysed program at start-up (the persistent compile
+cache hands the dispatched program back). A Pallas kernel is a custom
+call the cost model bills nothing for either way.
 
 Loop caveat (measured, load-bearing): XLA's cost model counts each
 ``while``/``scan`` BODY exactly once, whatever the trip count — in
@@ -33,8 +37,8 @@ executable (trend tracking: a new fusion or an extra collective moves
 them), and a per-token ``flops`` from a PROBE lowering of one
 microbatch's fwd+bwd with every scan force-unrolled
 (``unrolled_scans``), where the cost model genuinely bills all L
-layers and every CE chunk. The probe is lowering-only (abstract
-inputs, never compiled or executed).
+layers and every CE chunk. The probe has abstract inputs and is never
+executed (nor compiled, where lowering alone yields the analysis).
 
 No jax import at module level (obs/ stays importable host-side
 everywhere); functions that need the backend import it lazily.
@@ -42,7 +46,6 @@ everywhere); functions that need the backend import it lazily.
 
 from __future__ import annotations
 
-import os
 from contextlib import contextmanager
 from typing import Any
 
@@ -52,8 +55,8 @@ def unrolled_scans():
     """Force every ``jax.lax.scan`` lowered inside this context to
     fully unroll — so a cost-analysis probe bills ALL loop iterations
     instead of XLA's body-counted-once default (module docstring).
-    Lowering-only tool: an unrolled 32-layer stack is a big StableHLO
-    module but never compiles or runs. Patches the module attribute the
+    Analysis-only tool: an unrolled 32-layer stack is a big StableHLO
+    module that never runs. Patches the module attribute the
     model code calls (``jax.lax.scan``), restores it on exit; callers
     hold no other tracing in flight (the train loop probes once, before
     round 1's dispatch)."""
@@ -71,34 +74,38 @@ def unrolled_scans():
     finally:
         jax.lax.scan = orig
 
-# bf16 peak TFLOP/s per chip by device kind substring (first match
-# wins). Override with BENCH_PEAK_TFLOPS when the kind string is
-# missing or wrong. Single source of truth — bench.py delegates here.
-PEAK_TFLOPS_BY_KIND = [
-    ("v6", 918.0),
-    ("v5p", 459.0),
-    ("v5", 197.0),   # v5e / "v5 lite"
-    ("v4", 275.0),
-    ("v3", 123.0),
-]
+# bf16 peak TFLOP/s per chip, keyed by the EXACT ``device_kind`` string
+# jax reports. Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation (v5e: 197, v5p: 459, v6e: 918,
+# v4: 275); the kind strings are those of jax 0.9.0's own table
+# (jax/_src/pallas/mosaic/tpu_info.py). "TPU v5 lite" is the one this
+# stack has been seen to report (chip_smoke.py, PR 21). A kind that is
+# not here is an error, not a default: add it with its source.
+PEAK_TFLOPS_BY_KIND = {
+    "TPU v4": 275.0,
+    "TPU v5 lite": 197.0,   # v5e
+    "TPU v5": 459.0,        # v5p
+    "TPU v6 lite": 918.0,   # v6e
+}
 
 
 def detect_peak_tflops() -> tuple[float | None, str]:
-    """(bf16 peak TFLOP/s per chip or None, device kind string) for the
-    current backend. ``BENCH_PEAK_TFLOPS`` overrides the table; an
-    unknown kind (CPU included) yields None — consumers must report
-    "no peak known", never fake an MFU against a made-up ceiling."""
+    """(bf16 peak TFLOP/s per chip, device kind string) for the current
+    backend. The peak is None on the CPU backend only — a CPU run is
+    asked for by name and has no MFU; an accelerator whose kind is not
+    in the table raises, so no MFU is ever computed against a guessed
+    ceiling."""
     import jax
 
-    kind = jax.devices()[0].device_kind
-    env = os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        return float(env), kind
-    low = kind.lower()
-    for sub, peak in PEAK_TFLOPS_BY_KIND:
-        if sub in low:
-            return peak, kind
-    return None, kind
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None, dev.device_kind
+    if dev.device_kind not in PEAK_TFLOPS_BY_KIND:
+        raise ValueError(
+            f"no bf16 peak known for device kind {dev.device_kind!r}; add "
+            "it to obs/costs.py PEAK_TFLOPS_BY_KIND with its source"
+        )
+    return PEAK_TFLOPS_BY_KIND[dev.device_kind], dev.device_kind
 
 
 def train_flops_per_token(cfg, seq: int, moe_tokens: int | None = None) -> float:
@@ -128,27 +135,37 @@ def train_flops_per_token(cfg, seq: int, moe_tokens: int | None = None) -> float
 
 
 def lowered_cost(lowered) -> dict[str, float] | None:
-    """Normalize ``jax.stages.Lowered.cost_analysis()`` across jax
-    versions (a dict on some releases, a one-element list of dicts on
-    others) into ``{"flops", "bytes_accessed"}``. None when the
-    backend's cost model reports nothing usable — callers must treat
-    that as "no analytics", never as zero cost."""
+    """``{"flops", "bytes_accessed"}`` of one ``jax.stages.Lowered``
+    program. ``Lowered.cost_analysis()`` where the backend walks
+    un-compiled HLO (the CPU does); through the TPU plug-in it returns
+    nothing (measured on a v5e, PR 21), and the program is compiled and
+    the executable's own analysis read instead — the same cost model
+    after optimization, matmul FLOPs unchanged, at the price of one
+    compile (a persistent-cache hit when the program is dispatched
+    afterwards). None when neither reports anything usable — callers
+    must treat that as "no analytics", never as zero cost."""
+
+    def usable(ca) -> dict[str, float] | None:
+        # a dict on some releases, a one-element list of dicts on others
+        if isinstance(ca, (list, tuple)):
+            ca = ca[0] if ca else None
+        if not isinstance(ca, dict):
+            return None
+        out: dict[str, float] = {}
+        flops = ca.get("flops")
+        if isinstance(flops, (int, float)) and flops > 0:
+            out["flops"] = float(flops)
+        ba = ca.get("bytes accessed")
+        if isinstance(ba, (int, float)) and ba > 0:
+            out["bytes_accessed"] = float(ba)
+        return out or None
+
     try:
-        ca = lowered.cost_analysis()
+        return usable(lowered.cost_analysis()) or usable(
+            lowered.compile().cost_analysis()
+        )
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    out: dict[str, float] = {}
-    flops = ca.get("flops")
-    if isinstance(flops, (int, float)) and flops > 0:
-        out["flops"] = float(flops)
-    ba = ca.get("bytes accessed")
-    if isinstance(ba, (int, float)) and ba > 0:
-        out["bytes_accessed"] = float(ba)
-    return out or None
 
 
 def build_cost_record(
@@ -190,10 +207,7 @@ def build_cost_record(
         rec["flops_per_token_hand"] = train_flops_per_token(
             model_cfg, seq, moe_tokens=moe_tokens
         )
-    try:
-        peak, kind = detect_peak_tflops()
-    except Exception:
-        peak, kind = None, "unknown"
+    peak, kind = detect_peak_tflops()
     if peak:
         rec["peak_tflops"] = peak
     rec["device_kind"] = kind
@@ -205,7 +219,7 @@ def analytic_mfu(
 ) -> float | None:
     """Measured global tokens/sec x the program's analytic FLOPs/token,
     against the captured per-chip peak x device count. None when the
-    record lacks a peak (CPU mesh, unknown kind) — no fake ceilings."""
+    record lacks a peak (a CPU run) — no fake ceilings."""
     fpt = cost.get("flops_per_token")
     peak = cost.get("peak_tflops")
     n_dev = cost.get("num_devices") or 1

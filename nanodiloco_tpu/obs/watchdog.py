@@ -2,11 +2,11 @@
 
 A long unattended run degrades in ways a loss curve viewed tomorrow
 cannot undo: a NaN poisons every later step, a data stall silently
-freezes the job while the accelerator claim burns, a recompile storm
+freezes the job while the accelerator sits idle, a recompile storm
 collapses throughput. The watchdog turns each of these into a
 structured ``alarm`` record in the SAME JSONL stream the metrics go to
 (one source of truth), and optionally mirrors a small ``status.json``
-to disk for external pollers (cron, chip_watch.sh, a dashboard) that
+to disk for external pollers (cron, a dashboard) that
 must not parse an unbounded JSONL to answer "is it alive".
 
 Sentinels (called in-loop by the train driver; pure host arithmetic):

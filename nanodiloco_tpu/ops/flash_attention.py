@@ -5,12 +5,16 @@ hardware:
 
 - On TPU it calls the hand-written Pallas kernel (ops/pallas/
   flash_attention.py) — Mosaic-compiled blockwise online-softmax with
-  VMEM-resident accumulators and a custom VJP.
-- Elsewhere (and under ``impl="scan"``) it runs the same algorithm as a
-  ``lax.scan`` over key/value blocks with per-block rematerialization —
-  O(S * block) live memory instead of O(S^2), differentiable through
-  the scan, XLA-fused. The scan form doubles as the executable spec the
-  Pallas kernel is tested against.
+  VMEM-resident accumulators and a custom VJP — or raises when the
+  sequence does not divide into the kernel's blocks. It never gives way
+  to another path there: a run that asked for the kernel and is not
+  getting it must say so. Under a multi-device mesh the call sits in a
+  ``shard_map`` (``_pallas_on_mesh``).
+- Elsewhere (and under ``impl="scan"``, on any backend) it runs the
+  same algorithm as a ``lax.scan`` over key/value blocks with per-block
+  rematerialization — O(S * block) live memory instead of O(S^2),
+  differentiable through the scan, XLA-fused. The scan form doubles as
+  the executable spec the Pallas kernel is tested against.
 
 Causal-only and mask-free by design: the data pipeline packs fixed-length
 sequences (data/), so padding masks are not needed on the hot path. Use
@@ -26,6 +30,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from nanodiloco_tpu.ops.online_softmax import block_update, finalize_grouped
 
@@ -112,8 +117,12 @@ def flash_attention(
     query heads in-kernel, so K/V HBM traffic stays at Hkv heads).
     Returns [B, S, H, hd].
 
-    ``impl``: "pallas" | "scan" | None (auto: pallas on TPU when the
-    sequence divides into its blocks, scan otherwise).
+    ``impl``: "pallas" | "scan" | None. None takes the backend's path:
+    the Pallas kernel on TPU — which raises when the sequence does not
+    divide into its blocks; ask for "scan" by name then — and the scan
+    everywhere else. The choice reads ``jax.default_backend()``, so a
+    program compiled for a described (unattached) TPU takes the scan
+    unless it says "pallas".
     """
     if q.shape[2] % k.shape[2]:
         raise ValueError(
@@ -134,18 +143,39 @@ def flash_attention(
         bq = env_bq or min(128, block_size)
         bk = env_bk or min(128, block_size)
     if impl is None:
-        s = q.shape[1]
-        pallas_ok = jax.default_backend() == "tpu" and (
-            s % min(bq, s) == 0 and s % min(bk, s) == 0
-        )
-        impl = "pallas" if pallas_ok else "scan"
+        impl = "pallas" if jax.default_backend() == "tpu" else "scan"
     if impl == "pallas":
-        from nanodiloco_tpu.ops.pallas.flash_attention import pallas_flash_attention
-
-        return pallas_flash_attention(
-            q, k, v, causal=causal, block_q=bq, block_k=bk
-        )
+        return _pallas_on_mesh(q, k, v, causal=causal, block_q=bq, block_k=bk)
     return _flash_attention_scan(q, k, v, causal=causal, block_size=block_size)
+
+
+def _pallas_on_mesh(q, k, v, **kernel_args) -> jax.Array:
+    """The Pallas kernel under the ambient mesh (``jax.set_mesh``).
+
+    Mosaic refuses a kernel inside an automatically partitioned program,
+    so on a multi-device mesh the call is a ``shard_map`` over every axis
+    not already manual: batch over ``fsdp`` and heads over ``tp`` — the
+    layout the batch and the projections arrive in (parallel/sharding.py)
+    — each where it divides, else whole on every device. Attention mixes
+    neither axis, so the region holds no collective. The DiLoCo worker
+    axis reaches here as a vmap named ``spmd_axis_name="diloco"``
+    (parallel/diloco.py), which shards the vmapped dimension the same
+    way."""
+    from nanodiloco_tpu.ops.pallas.flash_attention import pallas_flash_attention
+
+    kernel = partial(pallas_flash_attention, **kernel_args)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or not set(mesh.axis_names) - set(mesh.manual_axes):
+        return kernel(q, k, v)
+
+    def over(axis: str, *sizes: int) -> str | None:
+        free = axis in mesh.axis_names and axis not in mesh.manual_axes
+        return axis if free and all(n % mesh.shape[axis] == 0 for n in sizes) else None
+
+    spec = P(over("fsdp", q.shape[0]), None, over("tp", q.shape[2], k.shape[2]), None)
+    return jax.shard_map(
+        kernel, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)
 
 
 @partial(jax.jit, static_argnames=("causal", "block_size"))

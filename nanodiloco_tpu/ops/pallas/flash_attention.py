@@ -20,7 +20,8 @@ Design (standard FlashAttention-2 decomposition, shaped for the TPU):
 
 The kernels run under ``interpret=True`` on CPU — the test suite
 verifies them against dense attention on the virtual-device mesh, and
-the same code compiles to Mosaic on a real TPU.
+the same code compiles to Mosaic on a real TPU
+(tests/test_tpu_compile.py compiles it for a described v5e).
 
 The reference has no attention kernel of its own (HF eager attention,
 ref /root/reference/nanodiloco/main.py:9,98); this is the TPU-native
@@ -390,11 +391,19 @@ def pallas_flash_attention(
     fetch to its KV head via ``bh // group``, so K/V HBM traffic and
     VMEM residency stay at Hkv heads). Differentiable.
 
-    ``interpret`` defaults to True off-TPU so the same kernels run (and
-    are tested) on the CPU mesh.
+    ``interpret``: None compiles the kernels on TPU and interprets them
+    on any other backend, which is how the CPU tests run them; True on
+    TPU is refused. A compile for a described, unattached TPU runs on
+    the CPU backend and so must pass ``interpret=False`` itself.
     """
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "pallas_flash_attention: interpret mode on tpu would run the "
+            "kernel through the interpreter, not Mosaic"
+        )
     b, s, h, hd = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     if h % hkv:

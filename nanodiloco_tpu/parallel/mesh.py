@@ -29,7 +29,6 @@ import dataclasses
 import math
 
 import jax
-import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
@@ -70,11 +69,10 @@ def build_mesh(cfg: MeshConfig, devices: list | None = None) -> Mesh:
     n = cfg.num_devices
     if n > len(devices):
         raise ValueError(f"mesh needs {n} devices, only {len(devices)} available")
-    devices = devices[:n]
-    try:
-        dev_array = mesh_utils.create_device_mesh(cfg.shape, devices=devices)
-    except Exception:  # CPU/virtual devices lack topology info
-        dev_array = np.asarray(devices).reshape(cfg.shape)
+    # topology-aware on TPU, a plain reshape for devices without one
+    # (CPU); a failure on real chips must surface — a reshape there is a
+    # topology-blind mesh with no message
+    dev_array = mesh_utils.create_device_mesh(cfg.shape, devices=devices[:n])
     return Mesh(dev_array, AXES)
 
 

@@ -1499,6 +1499,16 @@ class InferenceEngine:
         }
 
     @property
+    def device(self) -> dict:
+        """The devices the weights sit on, as JAX names them: where this
+        engine's programs run. On /healthz, so that a replica that came
+        up on the CPU because its accelerator could not be initialized
+        says so."""
+        devs = sorted(jax.tree.leaves(self.params)[0].devices(), key=lambda d: d.id)
+        return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                "count": len(devs)}
+
+    @property
     def kv_layout(self) -> str:
         """The engine's program layout tag: cache storage mode plus the
         tensor-parallel degree when sharded — the string every

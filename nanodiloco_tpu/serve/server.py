@@ -24,7 +24,9 @@ Endpoints:
 - ``GET /healthz`` — LIVENESS: 200 while the tick loop is alive, 503
   after it died; body carries queue depth, slot occupancy, the KV
   block-pool free count, and the deploy generation (the fleet router's
-  routing inputs). ``?ready=1`` answers the READINESS contract instead.
+  routing inputs), and the ``device`` the engine's weights sit on
+  (platform, kind, count). ``?ready=1`` answers the READINESS contract
+  instead.
 - ``GET /readyz`` — READINESS: 200 only when the loop is alive AND the
   scheduler is not draining. A replica draining for a weight push is
   alive-but-not-ready — the router must route around it, not eject it
@@ -717,6 +719,10 @@ class ServeServer:
             doc["kv_blocks_free"] = kv["blocks_free"]
         if s.get("deploy_generation") is not None:
             doc["deploy_generation"] = s["deploy_generation"]
+        # where the engine's programs run (platform, kind, count)
+        device = getattr(self._scheduler.backend, "device", None)
+        if device is not None:
+            doc["device"] = device
         # total attributed device-seconds (all classes): the router's
         # per-replica cost gauge, riding the same one-GET probe
         dev = s.get("device_seconds_by_priority")

@@ -71,11 +71,10 @@ class CheckpointManager:
         # lose a checkpoint the run believed it had. The async mode
         # (synchronous=False) keeps orbax's background write for
         # wall-clock overlap, at the cost of deferred errors (bounded by
-        # check_async_errors at the next save) — and is NOT trustworthy
-        # on this environment's legacy jax/orbax stack: a pending
-        # background write racing the train loop reproducibly corrupts
-        # the process heap (glibc aborts under the CPU test harness) and
-        # tears checkpoint contents (the seed's non-bit-exact resume).
+        # check_async_errors at the next save). On jax 0.4.37 / orbax 0.7
+        # a pending background write racing the train loop corrupted the
+        # process heap and tore checkpoint contents; not re-tested on
+        # jax 0.9.0 / orbax 0.11 (ROADMAP C10).
         self.synchronous = synchronous
         self._mngr = ocp.CheckpointManager(
             self.directory,

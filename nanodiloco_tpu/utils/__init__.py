@@ -3,9 +3,9 @@ from nanodiloco_tpu.utils.utils import (
     create_run_name,
     device_memory_stats,
     enable_compile_cache,
-    ensure_live_backend,
     probe_backend,
     force_virtual_cpu_devices,
+    require_accelerator,
     set_seed_all,
 )
 
@@ -14,8 +14,8 @@ __all__ = [
     "create_run_name",
     "device_memory_stats",
     "enable_compile_cache",
-    "ensure_live_backend",
     "probe_backend",
     "force_virtual_cpu_devices",
+    "require_accelerator",
     "set_seed_all",
 ]

@@ -1466,10 +1466,18 @@ def run_disagg(args, cfg, params, jax) -> None:
 
 def main() -> None:
     args = build_parser().parse_args()
-    if args.force_cpu_devices:
-        from nanodiloco_tpu.utils import force_virtual_cpu_devices
+    from nanodiloco_tpu.utils import (
+        enable_compile_cache,
+        force_virtual_cpu_devices,
+        require_accelerator,
+    )
 
+    if args.force_cpu_devices:
         force_virtual_cpu_devices(args.force_cpu_devices)
+    enable_compile_cache()
+    # a measurement entry point: no accelerator and no CPU asked for by
+    # name is an error, not a CPU number under a device metric's name
+    require_accelerator("serve_bench.py")
     import jax
 
     from nanodiloco_tpu.serve import (

@@ -43,23 +43,11 @@ WARM, TIMED = 2, 6
 
 
 def worker(pid: int, nproc: int, port: str) -> None:
-    # the ONE implementation of the 2-virtual-CPU-device setup — on this
-    # jax 0.4.37 `jax_num_cpu_devices` does not exist and the XLA_FLAGS
-    # fallback (conftest's own mechanism) is the working path
     from nanodiloco_tpu.utils import force_virtual_cpu_devices
 
     force_virtual_cpu_devices(2)
     import jax
 
-    try:
-        # pre-0.5 jax creates the plain (collective-less) CPU client
-        # unless told otherwise, and the first cross-process all-reduce
-        # dies with "Multiprocess computations aren't implemented on the
-        # CPU backend"; modern jax selects gloo automatically
-        # (tests/multihost_worker.py, the working reference)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:
-        pass
     jax.distributed.initialize(
         coordinator_address=f"localhost:{port}",
         num_processes=nproc, process_id=pid,

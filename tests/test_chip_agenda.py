@@ -1,14 +1,12 @@
-"""Wedge-recovery mechanics of the on-chip evidence agenda.
+"""Deadline mechanics of scripts/chip_agenda.py (ROADMAP C1 removes
+the script and this file together).
 
-The round-5 chip wedge (PERF.md ledger, 2026-07-31) hangs a phase inside
-native plugin code where no in-process watchdog — SIGALRM included — can
-ever fire, and bench's grandchild process is the one actually holding
-the single-claimant chip. scripts/chip_agenda.py therefore runs every
-phase in its own process GROUP with a parent-enforced deadline and
-SIGTERM-first group kill. These tests drive that parent machinery end to
-end with a sleep standing in for the wedge (the signal-immunity of the
-real wedge lives below Python; the recovery path is identical), via the
-env-gated ``selftest`` phase.
+A phase can hang inside native code where no in-process watchdog —
+SIGALRM included — can fire, and a grandchild process (bench.py) is the
+one holding the chip. The agenda therefore runs every phase in its own
+process GROUP with a parent-enforced deadline and SIGTERM-first group
+kill. These tests drive that parent machinery end to end with a sleep
+standing in for the hang, via the env-gated ``selftest`` phase.
 """
 
 import json
@@ -96,9 +94,9 @@ def test_healthy_phase_completes_and_exits_zero(tmp_path):
 
 
 def test_resume_skips_succeeded_phases(tmp_path):
-    """chip_watch.sh retries with --resume: a phase whose latest record
-    is 'done' must be skipped (a short recovery window must not re-burn
-    succeeded phases), recorded via a 'skipping_done' line."""
+    """A retry with --resume: a phase whose latest record is 'done'
+    must be skipped (a retry must not re-run succeeded phases),
+    recorded via a 'skipping_done' line."""
     out = tmp_path / "agenda.jsonl"
     env = {
         **os.environ,

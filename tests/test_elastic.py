@@ -754,6 +754,10 @@ def test_supervised_scale_up_and_straggler_absorbed(tmp_path):
         "--num-workers", "2", "--straggler-factor", "2.0",
         "--checkpoint-dir", ck, "--log-dir", str(tmp_path / "runs"),
         "--run-name", "elastic", "--fault-plan", plan,
+        # the children are fresh processes: the 2 -> 4 widening needs
+        # four devices of their own (on the jax 0.4 line they inherited
+        # conftest's XLA_FLAGS fallback, which jax 0.9.0 never sets)
+        "--force-cpu-devices", "4",
     ]
     sup = subprocess.run(
         [sys.executable, "-m", "nanodiloco_tpu", "supervise",

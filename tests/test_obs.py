@@ -783,10 +783,20 @@ def test_cost_analysis_probe_matches_hand_formula():
     assert round_billed(2, 1) == round_billed(4, 2)  # loop-once pinned
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
 def test_build_cost_record_and_analytic_mfu(monkeypatch):
+    from nanodiloco_tpu.obs import costs
     from nanodiloco_tpu.obs.costs import analytic_mfu, build_cost_record
 
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "100.0")
+    # the table is keyed by the exact device_kind string jax reports
+    monkeypatch.setitem(costs.PEAK_TFLOPS_BY_KIND, "TPU test", 100.0)
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice("tpu", "TPU test")]
+    )
     rec = build_cost_record(
         program="fused_round",
         billed={"flops": 5e8, "bytes_accessed": 1e9},
@@ -800,15 +810,24 @@ def test_build_cost_record_and_analytic_mfu(monkeypatch):
     assert rec["peak_tflops"] == 100.0
     # 1e6 tok/s x 2e6 flops/tok = 2e12 flop/s over 2 chips x 100 TF = 1%
     assert analytic_mfu(rec, 1e6) == pytest.approx(0.01)
-    # no peak -> no MFU, never a fake ceiling; a probe-less record (the
-    # loss path the probe can't lower) still carries the billed numbers
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS")
+    # an accelerator the table does not know is an error — a substring
+    # match ("v5" catching anything) is how a wrong ceiling gets in
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice("tpu", "TPU v5 litest")]
+    )
+    with pytest.raises(ValueError, match="no bf16 peak known"):
+        build_cost_record(program="x", billed={"flops": 2e9})
+    # the CPU backend (a run asked for by name) has no peak and no MFU,
+    # never a fake ceiling; a probe-less record (the loss path the probe
+    # can't lower) still carries the billed numbers
+    monkeypatch.undo()
     rec_cpu = build_cost_record(
         program="x", billed={"flops": 2e9},
     )
     assert "flops_per_token" not in rec_cpu
-    if "peak_tflops" not in rec_cpu:
-        assert analytic_mfu(rec_cpu, 1e6) is None
+    assert "peak_tflops" not in rec_cpu
+    assert rec_cpu["flops_billed"] == 2e9
+    assert analytic_mfu(rec_cpu, 1e6) is None
 
 
 def _write_cost_run(path, tps, final_loss, peak=0.1):

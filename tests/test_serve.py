@@ -537,6 +537,9 @@ def test_generate_endpoint_over_real_socket(params):
         assert code == 200
         doc = json.loads(body)
         assert doc["healthy"] and doc["served"] == 3
+        # where the engine's weights sit, as JAX names it: a replica that
+        # fell back to the CPU must say so (chip_smoke.py reads this)
+        assert doc["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
 
         code, _ = _get(srv.port, "/nope")
         assert code == 404

@@ -149,13 +149,7 @@ if __name__ == "__main__":
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:  # pre-0.5 jax: conftest's XLA_FLAGS fallback
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        ).strip()
+    jax.config.update("jax_num_cpu_devices", 8)
 
     from nanodiloco_tpu.training.metrics import summarize_run
 

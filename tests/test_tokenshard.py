@@ -87,6 +87,40 @@ def test_fallback_reader_matches_native(shard_file, monkeypatch):
         ts.batch(np.asarray([1000], dtype=np.uint64))
 
 
+def test_native_library_is_keyed_by_source_and_flags(monkeypatch):
+    """The library that gets loaded is the one built from the committed
+    source with today's flags: a file from other source, other flags or
+    another machine (the old fixed ``libtokenshard.so``) has another
+    name and is never picked up."""
+    here = tokenshard._lib_path()
+    assert os.path.dirname(here) == tokenshard._CSRC
+    assert os.path.basename(here).startswith("libtokenshard-")
+    assert os.path.basename(here) != "libtokenshard.so"
+    assert "-march=native" not in tokenshard._FLAGS  # the tree is copied between machines
+    monkeypatch.setattr(tokenshard, "_FLAGS", tokenshard._FLAGS + ("-DOTHER",))
+    assert tokenshard._lib_path() != here
+
+
+def test_failed_native_build_says_so_and_numpy_reader_serves(
+    shard_file, monkeypatch, capfd
+):
+    """A build that fails is not silent: stderr names the reader in use
+    and why, once, and the numpy reader answers the same reads."""
+    path, data = shard_file
+    monkeypatch.setattr(tokenshard, "_lib", None)
+    monkeypatch.setattr(tokenshard, "_lib_failed", False)
+    monkeypatch.setattr(tokenshard, "_FLAGS", ("--no-such-compiler-flag",))
+    assert not native_available()
+    assert not native_available()  # the second ask neither rebuilds nor repeats
+    err = capfd.readouterr().err
+    assert err.count("numpy reader in use") == 1
+    assert "no-such-compiler-flag" in err
+    ts = TokenShard(path)
+    np.testing.assert_array_equal(
+        ts.batch(np.asarray([5, 0], dtype=np.uint64)), data[[5, 0]]
+    )
+
+
 def test_bad_magic(tmp_path):
     p = tmp_path / "junk.tshrd"
     p.write_bytes(b"NOTASHARD" + b"\x00" * 64)

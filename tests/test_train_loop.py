@@ -426,31 +426,70 @@ def test_cli_quarantine_flag():
     assert config_from_args(args).quarantine_nonfinite is True
 
 
-def test_compile_cache_and_memory_stats(tmp_path, monkeypatch):
-    """enable_compile_cache honors $NANODILOCO_COMPILE_CACHE (no-op when
-    unset); device_memory_stats returns {} on backends without
-    memory_stats (CPU) so no fake HBM keys ever reach the JSONL."""
+@pytest.mark.parametrize(
+    "case", ["env_set", "env_unset", "unusable_dir", "switched_off"]
+)
+def test_compile_cache_and_memory_stats(case, tmp_path, monkeypatch):
+    """The compile-cache rule (utils.enable_compile_cache): where
+    JAX_COMPILATION_CACHE_DIR is set the cache is that directory and the
+    code sets no path (JAX reads the variable itself); unset, it is the
+    fixed <checkout>/.jax_cache; a directory that cannot be made raises;
+    JAX's own off switch is honoured. And device_memory_stats returns {}
+    on backends without memory_stats (CPU), so no fake HBM keys ever
+    reach the JSONL."""
     from nanodiloco_tpu.utils import device_memory_stats, enable_compile_cache
+    from nanodiloco_tpu.utils import utils as utils_mod
 
-    monkeypatch.delenv("NANODILOCO_COMPILE_CACHE", raising=False)
-    assert enable_compile_cache() is None
-    # save the conftest-configured session cache settings; restore them
-    # even on assert failure so no later test compiles cache-disabled
+    checkout = tmp_path / "checkout"
+    checkout.mkdir()
+    monkeypatch.setattr(utils_mod, "_CHECKOUT", str(checkout))
+    # restore the session's cache settings even on assert failure, so no
+    # later test of this worker compiles against a test directory
     saved = {
         k: getattr(jax.config, k)
         for k in (
+            "jax_enable_compilation_cache",
             "jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
             "jax_persistent_cache_min_entry_size_bytes",
         )
     }
+    updated = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        updated.append(name)
+        real_update(name, value)
+
     try:
-        cache = tmp_path / "xla-cache"
-        monkeypatch.setenv("NANODILOCO_COMPILE_CACHE", str(cache))
-        assert enable_compile_cache() == str(cache)
-        assert cache.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(cache)
+        real_update("jax_enable_compilation_cache", case != "switched_off")
+        monkeypatch.setattr(jax.config, "update", spy)
+        if case == "env_set":
+            placed = tmp_path / "placed-from-outside"
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+            assert enable_compile_cache() == str(placed)
+            assert placed.is_dir()
+            assert "jax_compilation_cache_dir" not in updated
+            assert not (checkout / ".jax_cache").exists()
+        elif case == "env_unset":
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            fixed = checkout / ".jax_cache"
+            assert enable_compile_cache() == str(fixed)
+            assert fixed.is_dir()
+            assert jax.config.jax_compilation_cache_dir == str(fixed)
+        elif case == "unusable_dir":
+            blocker = tmp_path / "a-file"
+            blocker.write_text("not a directory")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(blocker))
+            with pytest.raises(OSError):
+                enable_compile_cache()
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert enable_compile_cache() is None
+            assert updated == []
+            assert not (checkout / ".jax_cache").exists()
     finally:
+        monkeypatch.undo()
         for k, v in saved.items():
             jax.config.update(k, v)
 
