@@ -98,16 +98,19 @@ def _cached_block(
     if block and s_max % block:
         raise ValueError(f"cache length {s_max} not a multiple of block {block}")
 
-    x = params["embed"].astype(cdt)[tokens]
-    cos, sin = rope_tables(cfg, t, offset=pos)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]
+    with jax.named_scope("attn_proj"):
+        cos, sin = rope_tables(cfg, t, offset=pos)
 
     qi = pos + jnp.arange(t)  # [T] global query positions
     if not block:
         # Additive mask [B, T, S]: query at global position pos+qi may see
         # cache key ki when ki <= pos+qi AND the slot holds a real token.
-        ki = jnp.arange(s_max)[None, None, :]
-        ok = (ki <= qi[None, :, None]) & (key_valid[:, None, :] > 0)
-        mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]  # [B, 1, T, S]
+        with jax.named_scope("attention"):
+            ki = jnp.arange(s_max)[None, None, :]
+            ok = (ki <= qi[None, :, None]) & (key_valid[:, None, :] > 0)
+            mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]  # [B, 1, T, S]
 
     def attn_dense(qg, ck, cv):
         # grouped GQA attention against the full cache (softmax in fp32)
@@ -148,24 +151,31 @@ def _cached_block(
     def layer_body(x, scanned):
         layer, ck, cv = scanned  # layer params + this layer's cache slices
         h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
-        k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
-        v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        ck = jax.lax.dynamic_update_slice(ck, k, (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v, (0, pos, 0, 0))
+        with jax.named_scope("attn_proj"):
+            q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
+            k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
+            v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("kv_write"):
+            ck = jax.lax.dynamic_update_slice(ck, k, (0, pos, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, v, (0, pos, 0, 0))
 
-        qg = q.reshape(b, t, nkv, g, hd)
-        attn = (attn_blockwise if block else attn_dense)(qg, ck, cv)
-        x = x + attn @ layer["wo"].astype(cdt)
+        with jax.named_scope("attention"):
+            qg = q.reshape(b, t, nkv, g, hd)
+            attn = (attn_blockwise if block else attn_dense)(qg, ck, cv)
+        with jax.named_scope("attn_proj"):
+            x = x + attn @ layer["wo"].astype(cdt)
 
         x, _aux = mlp_block(cfg, x, layer, valid=token_valid)
         return x, (ck, cv)
 
-    x, (ck, cv) = jax.lax.scan(
-        layer_body, x, (params["layers"], cache["k"], cache["v"])
-    )
+    # layer_scan: what the scan itself does to its per-layer operands
+    # (a layer's weights and cache sliced out, the cache stacked back)
+    with jax.named_scope("layer_scan"):
+        x, (ck, cv) = jax.lax.scan(
+            layer_body, x, (params["layers"], cache["k"], cache["v"])
+        )
     if last_index is None:
         xl = x[:, -1]  # [B, d]
     else:
@@ -174,11 +184,18 @@ def _cached_block(
         # token is not the chunk's last row stays on the generate() path
         xl = jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]
     x = rms_norm(xl, params["final_norm"], cfg.rms_norm_eps)  # [B, d]
+    return _head_logits(params, x, cdt), {"k": ck, "v": cv}
+
+
+@jax.named_scope("head")
+def _head_logits(params: Params, x, cdt):
+    """Final-normed hidden states -> float32 logits through the
+    vocabulary head (the tied embedding's transpose where there is no
+    ``lm_head``): the one head of every cached and serve program."""
     head = params.get("lm_head", None)
     if head is None:
         head = params["embed"].T
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv}
+    return (x @ head.astype(cdt)).astype(jnp.float32)
 
 
 def _auto_decode_block(context_len: int) -> int:
@@ -189,6 +206,7 @@ def _auto_decode_block(context_len: int) -> int:
     return 512 if context_len >= 1024 else 0
 
 
+@jax.named_scope("sample")
 def _sample(logits, key, temperature: float, top_k: int, top_p: float = 1.0):
     """[B, V] logits -> [B] int32. temperature 0 = greedy (key unused);
     ``top_k`` keeps the k best logits; ``top_p`` < 1 keeps the smallest
@@ -502,6 +520,7 @@ def _dequantize_rows(q, scale, cdt):
     return (q.astype(jnp.float32) * scale[..., None, None]).astype(cdt)
 
 
+@jax.named_scope("sample")
 def _sample_slots(logits, keys, temperature, top_k, top_p):
     """Per-slot ``_sample``: [B, V] logits with PER-ROW key / temperature /
     top_k / top_p arrays -> [B] int32. Same op sequence as ``_sample``
@@ -664,12 +683,13 @@ def prefill_chunk_fn(cfg: LlamaConfig, mesh=None):
             params = _tp_params(params, cfg, mesh)
             cache = _tp_kv(cache, mesh)
         l, _b, s_max, nkv, hd = cache["k"].shape
-        ck = jax.lax.dynamic_slice(
-            cache["k"], (0, slot, 0, 0, 0), (l, 1, s_max, nkv, hd)
-        )
-        cv = jax.lax.dynamic_slice(
-            cache["v"], (0, slot, 0, 0, 0), (l, 1, s_max, nkv, hd)
-        )
+        with jax.named_scope("kv_gather"):
+            ck = jax.lax.dynamic_slice(
+                cache["k"], (0, slot, 0, 0, 0), (l, 1, s_max, nkv, hd)
+            )
+            cv = jax.lax.dynamic_slice(
+                cache["v"], (0, slot, 0, 0, 0), (l, 1, s_max, nkv, hd)
+            )
         # every cache position reads as valid: the serve path never
         # left-pads (each request prefills its own slot from 0), and
         # positions at/after the live prefix are causally pruned
@@ -678,14 +698,15 @@ def prefill_chunk_fn(cfg: LlamaConfig, mesh=None):
             params, cfg, chunk, {"k": ck, "v": cv}, pos,
             key_valid, chunk_valid, block=0, last_index=last_idx,
         )
-        cache = {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], sub["k"], (0, slot, 0, 0, 0)
-            ),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], sub["v"], (0, slot, 0, 0, 0)
-            ),
-        }
+        with jax.named_scope("kv_write"):
+            cache = {
+                "k": jax.lax.dynamic_update_slice(
+                    cache["k"], sub["k"], (0, slot, 0, 0, 0)
+                ),
+                "v": jax.lax.dynamic_update_slice(
+                    cache["v"], sub["v"], (0, slot, 0, 0, 0)
+                ),
+            }
         if mesh is not None:
             # replicated final logits: fused sampling (and its PRNG key
             # schedule) runs exactly as on one device, per shard
@@ -725,6 +746,7 @@ def prefill_chunk_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
         l, nb, bs, nkv, hd = pool["k"].shape
         mb = table.shape[0]
 
+        @jax.named_scope("kv_gather")
         def gathered(name, sname):
             g = pool[name][:, table]  # [L, mb, bs, Hkv, hd]
             if quant:
@@ -745,21 +767,23 @@ def prefill_chunk_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
         n_touch = min(c // bs + 1, mb) if c >= bs else 1
         # both slices clamp to the same block boundary; the explicit
         # min keeps the table slice and the data slice in lockstep
-        b0 = jnp.minimum(pos // bs, mb - n_touch)
-        phys = jax.lax.dynamic_slice(table, (b0,), (n_touch,))
         new = {}
-        for name, sname in (("k", "ks"), ("v", "vs")):
-            w = jax.lax.dynamic_slice(
-                sub[name], (0, 0, b0 * bs, 0, 0), (l, 1, n_touch * bs, nkv, hd)
-            ).reshape(l, n_touch, bs, nkv, hd)
-            if quant:
-                q, sc = _quantize_rows(w)
-                new[name] = pool[name].at[:, phys].set(q, mode="drop")
-                new[sname] = pool[sname].at[:, phys].set(sc, mode="drop")
-            else:
-                new[name] = pool[name].at[:, phys].set(
-                    w.astype(pool[name].dtype), mode="drop"
-                )
+        with jax.named_scope("kv_write"):
+            b0 = jnp.minimum(pos // bs, mb - n_touch)
+            phys = jax.lax.dynamic_slice(table, (b0,), (n_touch,))
+            for name, sname in (("k", "ks"), ("v", "vs")):
+                w = jax.lax.dynamic_slice(
+                    sub[name], (0, 0, b0 * bs, 0, 0),
+                    (l, 1, n_touch * bs, nkv, hd),
+                ).reshape(l, n_touch, bs, nkv, hd)
+                if quant:
+                    q, sc = _quantize_rows(w)
+                    new[name] = pool[name].at[:, phys].set(q, mode="drop")
+                    new[sname] = pool[sname].at[:, phys].set(sc, mode="drop")
+                else:
+                    new[name] = pool[name].at[:, phys].set(
+                        w.astype(pool[name].dtype), mode="drop"
+                    )
         if mesh is not None:
             logits = _tp_replicated(logits, mesh)
             new = _tp_kv(new, mesh)
@@ -776,6 +800,7 @@ def extract_chunk_fn(cfg: LlamaConfig):
     the pool — the prefix cache's insert path. One compile per chunk
     size (the engine only extracts whole chunks)."""
 
+    @jax.named_scope("kv_gather")
     def run(cache, slot, pos, size):
         l, _b, _s, nkv, hd = cache["k"].shape
         k = jax.lax.dynamic_slice(
@@ -797,6 +822,7 @@ def insert_chunk_fn(cfg: LlamaConfig):
     same tokens at the same positions, so a hit is bit-identical to
     re-prefilling them."""
 
+    @jax.named_scope("kv_write")
     def run(cache, k, v, slot, pos):
         return {
             "k": jax.lax.dynamic_update_slice(
@@ -926,6 +952,7 @@ def _sample_slots_multi(logits, key_data, temperature, top_k, top_p):
     return flat.reshape(b, t)
 
 
+@jax.named_scope("sample")
 def _accept_prefix(tokens, sampled, draft_len):
     """Longest-accepted-prefix + emission count: drafts are
     ``tokens[:, 1:]`` (position j's draft), targets are
@@ -943,6 +970,36 @@ def _accept_prefix(tokens, sampled, draft_len):
     return m + 1
 
 
+@jax.named_scope("attn_proj")
+def _slot_rope_tables(cfg: LlamaConfig, qpos, cdt):
+    """cos/sin ``[B, T, 1, hd]`` in the rotate-half convention for
+    per-(slot, position) global positions ``qpos`` [B, T]: the serve
+    programs' ``rope_tables``, one phase a row."""
+    hd = cfg.head_dim
+    inv_freq = 1.0 / (
+        cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    )
+    freqs = qpos.astype(jnp.float32)[..., None] * inv_freq  # [B, T, hd/2]
+    emb = jnp.concatenate([freqs, freqs], axis=-1)          # [B, T, hd]
+    return (jnp.cos(emb)[:, :, None, :].astype(cdt),
+            jnp.sin(emb)[:, :, None, :].astype(cdt))
+
+
+@jax.named_scope("attention")
+def _slot_attention(q, ck, cv, mask):
+    """Grouped GQA attention of each slot's queries ``q`` [B, T, H, hd]
+    over that slot's keys and values ``ck``/``cv`` [B, S, Hkv, hd]
+    under the additive ``mask`` [B, 1, T, S], softmax in float32:
+    [B, T, H * hd]."""
+    b, t, nh, hd = q.shape
+    nkv = ck.shape[2]
+    qg = q.reshape(b, t, nkv, nh // nkv, hd)
+    scores = jnp.einsum("btkgd,bskd->bkgts", qg, ck).astype(jnp.float32)
+    scores = scores * (1.0 / math.sqrt(hd)) + mask[:, :, None]
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgts,bskd->btkgd", probs, cv).reshape(b, t, nh * hd)
+
+
 def _verify_slots_block(params, cfg: LlamaConfig, tokens, cache, pos,
                         key_valid, active):
     """``_decode_slots_block`` widened to T = k+1 positions per slot:
@@ -956,19 +1013,12 @@ def _verify_slots_block(params, cfg: LlamaConfig, tokens, cache, pos,
     b, t = tokens.shape
     s_max = cache["k"].shape[2]
     nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    g = nh // nkv
-    scale = 1.0 / math.sqrt(hd)
 
-    x = params["embed"].astype(cdt)[tokens]  # [B, T, d]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]  # [B, T, d]
 
     qpos = pos[:, None] + jnp.arange(t)[None, :]  # [B, T] global positions
-    inv_freq = 1.0 / (
-        cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    )
-    freqs = qpos.astype(jnp.float32)[..., None] * inv_freq  # [B, T, hd/2]
-    emb = jnp.concatenate([freqs, freqs], axis=-1)          # [B, T, hd]
-    cos = jnp.cos(emb)[:, :, None, :].astype(cdt)           # [B, T, 1, hd]
-    sin = jnp.sin(emb)[:, :, None, :].astype(cdt)
+    cos, sin = _slot_rope_tables(cfg, qpos, cdt)            # [B, T, 1, hd]
 
     def rope(a):  # [B, T, H, hd] rotate-half with per-(slot, position) phases
         half = a.shape[-1] // 2
@@ -976,48 +1026,47 @@ def _verify_slots_block(params, cfg: LlamaConfig, tokens, cache, pos,
         return a * cos + jnp.concatenate([-a2, a1], axis=-1) * sin
 
     ki = jnp.arange(s_max)
-    ok = (ki[None, None, :] <= qpos[:, :, None]) & (key_valid[:, None, :] > 0)
-    mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]          # [B, 1, T, S]
+    with jax.named_scope("attention"):
+        ok = (ki[None, None, :] <= qpos[:, :, None]) & (key_valid[:, None, :] > 0)
+        mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]      # [B, 1, T, S]
     token_valid = jnp.broadcast_to(active[:, None], (b, t))
 
     def layer_body(x, scanned):
         layer, ck, cv = scanned
         h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
-        k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
-        v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
-        q = rope(q)
-        k = rope(k)
+        with jax.named_scope("attn_proj"):
+            q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
+            k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
+            v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
+            q = rope(q)
+            k = rope(k)
         # per-row masked writes, one position at a time (T is small and
         # static): the exact values dynamic_update_slice would write,
         # dead slots dropped — mid-prefill neighbours must not be
         # stamped with garbage K/V (the PR-6 inactive-slot lesson)
-        for j in range(t):
-            wr = (
-                (ki[None, :] == (pos + j)[:, None]) & (active[:, None] > 0)
-            )[:, :, None, None]
-            ck = jnp.where(wr, k[:, j][:, None], ck)
-            cv = jnp.where(wr, v[:, j][:, None], cv)
+        with jax.named_scope("kv_write"):
+            for j in range(t):
+                wr = (
+                    (ki[None, :] == (pos + j)[:, None]) & (active[:, None] > 0)
+                )[:, :, None, None]
+                ck = jnp.where(wr, k[:, j][:, None], ck)
+                cv = jnp.where(wr, v[:, j][:, None], cv)
 
-        qg = q.reshape(b, t, nkv, g, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, ck).astype(jnp.float32)
-        scores = scores * scale + mask[:, :, None]
-        probs = jax.nn.softmax(scores, axis=-1).astype(cdt)
-        attn = jnp.einsum("bkgts,bskd->btkgd", probs, cv).reshape(b, t, nh * hd)
-        x = x + attn @ layer["wo"].astype(cdt)
+        attn = _slot_attention(q, ck, cv, mask)
+        with jax.named_scope("attn_proj"):
+            x = x + attn @ layer["wo"].astype(cdt)
 
         x, _aux = mlp_block(cfg, x, layer, valid=token_valid)
         return x, (ck, cv)
 
-    x, (ck, cv) = jax.lax.scan(
-        layer_body, x, (params["layers"], cache["k"], cache["v"])
-    )
+    # layer_scan: what the scan itself does to its per-layer operands
+    # (a layer's weights and cache sliced out, the cache stacked back)
+    with jax.named_scope("layer_scan"):
+        x, (ck, cv) = jax.lax.scan(
+            layer_body, x, (params["layers"], cache["k"], cache["v"])
+        )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)  # [B, T, d]
-    head = params.get("lm_head", None)
-    if head is None:
-        head = params["embed"].T
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)      # [B, T, V]
-    return logits, {"k": ck, "v": cv}
+    return _head_logits(params, x, cdt), {"k": ck, "v": cv}  # [B, T, V]
 
 
 @functools.lru_cache(maxsize=8)
@@ -1068,19 +1117,12 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
     mb = tables.shape[1]
     s_view = mb * bs
     nh = cfg.num_attention_heads
-    g = nh // nkv
-    scale = 1.0 / math.sqrt(hd)
 
-    x = params["embed"].astype(cdt)[tokens]  # [B, T, d]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]  # [B, T, d]
 
     qpos = pos[:, None] + jnp.arange(t)[None, :]
-    inv_freq = 1.0 / (
-        cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    )
-    freqs = qpos.astype(jnp.float32)[..., None] * inv_freq
-    emb = jnp.concatenate([freqs, freqs], axis=-1)
-    cos = jnp.cos(emb)[:, :, None, :].astype(cdt)
-    sin = jnp.sin(emb)[:, :, None, :].astype(cdt)
+    cos, sin = _slot_rope_tables(cfg, qpos, cdt)
 
     def rope(a):
         half = a.shape[-1] // 2
@@ -1088,14 +1130,16 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
         return a * cos + jnp.concatenate([-a2, a1], axis=-1) * sin
 
     ki = jnp.arange(s_view)
-    ok = ki[None, None, :] <= qpos[:, :, None]
-    mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]          # [B, 1, T, S]
+    with jax.named_scope("attention"):
+        ok = ki[None, None, :] <= qpos[:, :, None]
+        mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]      # [B, 1, T, S]
     # per-(slot, position) physical addresses; inactive slots redirect
     # past the arena and drop, exactly like the T=1 tick
-    bi = jnp.clip(qpos // bs, 0, mb - 1)                    # [B, T]
-    off = qpos % bs
-    phys = jnp.take_along_axis(tables, bi, axis=1)          # [B, T]
-    phys = jnp.where(active[:, None] > 0, phys, nb)
+    with jax.named_scope("kv_write"):
+        bi = jnp.clip(qpos // bs, 0, mb - 1)                # [B, T]
+        off = qpos % bs
+        phys = jnp.take_along_axis(tables, bi, axis=1)      # [B, T]
+        phys = jnp.where(active[:, None] > 0, phys, nb)
     token_valid = jnp.broadcast_to(active[:, None], (b, t))
 
     def layer_body(x, scanned):
@@ -1104,33 +1148,35 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
         else:
             layer, pk, pv = scanned
         h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
-        k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
-        v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
-        q = rope(q)
-        k = rope(k)
-        if quant:
-            qk, sk = _quantize_rows(k)                      # [B, T, ...]
-            qv, sv = _quantize_rows(v)
-            pk = pk.at[phys, off].set(qk, mode="drop")
-            pv = pv.at[phys, off].set(qv, mode="drop")
-            pks = pks.at[phys, off].set(sk, mode="drop")
-            pvs = pvs.at[phys, off].set(sv, mode="drop")
-            ck = _dequantize_rows(pk[tables], pks[tables], cdt)
-            cv = _dequantize_rows(pv[tables], pvs[tables], cdt)
-        else:
-            pk = pk.at[phys, off].set(k.astype(pk.dtype), mode="drop")
-            pv = pv.at[phys, off].set(v.astype(pv.dtype), mode="drop")
-            ck, cv = pk[tables], pv[tables]
-        ck = ck.reshape(b, s_view, nkv, hd).astype(cdt)
-        cv = cv.reshape(b, s_view, nkv, hd).astype(cdt)
+        with jax.named_scope("attn_proj"):
+            q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
+            k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
+            v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
+            q = rope(q)
+            k = rope(k)
+        with jax.named_scope("kv_write"):
+            if quant:
+                qk, sk = _quantize_rows(k)                  # [B, T, ...]
+                qv, sv = _quantize_rows(v)
+                pk = pk.at[phys, off].set(qk, mode="drop")
+                pv = pv.at[phys, off].set(qv, mode="drop")
+                pks = pks.at[phys, off].set(sk, mode="drop")
+                pvs = pvs.at[phys, off].set(sv, mode="drop")
+            else:
+                pk = pk.at[phys, off].set(k.astype(pk.dtype), mode="drop")
+                pv = pv.at[phys, off].set(v.astype(pv.dtype), mode="drop")
+        with jax.named_scope("kv_gather"):
+            if quant:
+                ck = _dequantize_rows(pk[tables], pks[tables], cdt)
+                cv = _dequantize_rows(pv[tables], pvs[tables], cdt)
+            else:
+                ck, cv = pk[tables], pv[tables]
+            ck = ck.reshape(b, s_view, nkv, hd).astype(cdt)
+            cv = cv.reshape(b, s_view, nkv, hd).astype(cdt)
 
-        qg = q.reshape(b, t, nkv, g, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, ck).astype(jnp.float32)
-        scores = scores * scale + mask[:, :, None]
-        probs = jax.nn.softmax(scores, axis=-1).astype(cdt)
-        attn = jnp.einsum("bkgts,bskd->btkgd", probs, cv).reshape(b, t, nh * hd)
-        x = x + attn @ layer["wo"].astype(cdt)
+        attn = _slot_attention(q, ck, cv, mask)
+        with jax.named_scope("attn_proj"):
+            x = x + attn @ layer["wo"].astype(cdt)
 
         x, _aux = mlp_block(cfg, x, layer, valid=token_valid)
         if quant:
@@ -1142,12 +1188,13 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
                    pool["ks"], pool["vs"])
     else:
         scanned = (params["layers"], pool["k"], pool["v"])
-    x, out = jax.lax.scan(layer_body, x, scanned)
+    # layer_scan: the scan's own slicing of a layer's weights and pool
+    # out of their stacks, and its stacking of the updated pool: the
+    # whole pool moves through here every tick
+    with jax.named_scope("layer_scan"):
+        x, out = jax.lax.scan(layer_body, x, scanned)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params.get("lm_head", None)
-    if head is None:
-        head = params["embed"].T
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
+    logits = _head_logits(params, x, cdt)
     if quant:
         pool = {"k": out[0], "v": out[1], "ks": out[2], "vs": out[3]}
     else:

@@ -90,6 +90,16 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
 
 # ---------------------------------------------------------------------------
 # Building blocks
+#
+# Each block runs under a ``jax.named_scope`` (``embed``, ``norm``,
+# ``attn_proj``, ``attention``, ``mlp``, ``head``, ``loss``, and
+# ``layer_scan`` for the layer scan's own slicing and stacking; the serve
+# programs of models/generate.py add ``kv_write``, ``kv_gather`` and
+# ``sample``): trace-time only, the scope rides inside every operation's
+# ``op_name`` through ``jvp``, ``transpose`` and ``remat``, so a device
+# trace's operations read as layers, forward, recomputed and backward
+# alike. ``attention`` is exactly what a fused kernel replaces (scores,
+# mask, softmax, values), whichever of dense, flash or ring runs.
 # ---------------------------------------------------------------------------
 
 def checkpoint_policy(cfg: LlamaConfig):
@@ -104,6 +114,7 @@ def checkpoint_policy(cfg: LlamaConfig):
     )
 
 
+@jax.named_scope("norm")
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     """RMSNorm with float32 accumulation (HF casts to fp32 for the variance)."""
     dtype = x.dtype
@@ -168,6 +179,7 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array | 
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+@jax.named_scope("attention")
 def _attention(cfg: LlamaConfig, q, k, v, mask, axis_name: str | None):
     """Dispatch on cfg.attention_impl. Ring attention requires being inside
     a shard_map with the sequence axis bound to ``axis_name``; flash ignores
@@ -210,16 +222,18 @@ def _decoder_layer(
     cdt = x.dtype
 
     h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-    q = (h @ layer["wq"].astype(cdt)).reshape(b, s, nh, hd)
-    k = (h @ layer["wk"].astype(cdt)).reshape(b, s, nkv, hd)
-    v = (h @ layer["wv"].astype(cdt)).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn_proj"):
+        q = (h @ layer["wq"].astype(cdt)).reshape(b, s, nh, hd)
+        k = (h @ layer["wk"].astype(cdt)).reshape(b, s, nkv, hd)
+        v = (h @ layer["wv"].astype(cdt)).reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     # GQA K/V stay at Hkv heads here; flash/ring are GQA-native (K/V are
     # never expanded in HBM/ICI — the bandwidth GQA exists to save) and
     # _attention expands only for its dense paths.
     attn = _attention(cfg, q, k, v, mask, sp_axis)
-    x = x + attn.reshape(b, s, nh * hd) @ layer["wo"].astype(cdt)
+    with jax.named_scope("attn_proj"):
+        x = x + attn.reshape(b, s, nh * hd) @ layer["wo"].astype(cdt)
 
     return mlp_block(cfg, x, layer, valid, sp_axis=sp_axis, with_stats=with_stats)
 
@@ -240,17 +254,20 @@ def mlp_block(
     if cfg.num_experts:
         from nanodiloco_tpu.models.moe import moe_mlp
 
-        out = moe_mlp(
-            cfg, h, layer, valid=valid, sp_axis=sp_axis, with_stats=with_stats
-        )
-        if with_stats:
-            mlp_out, aux, stats = out
-            return x + mlp_out, aux, stats
-        mlp_out, aux = out
-        return x + mlp_out, aux
-    gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
-    up = h @ layer["w_up"].astype(cdt)
-    x = x + (gate * up) @ layer["w_down"].astype(cdt)
+        with jax.named_scope("mlp"):
+            out = moe_mlp(
+                cfg, h, layer, valid=valid, sp_axis=sp_axis,
+                with_stats=with_stats,
+            )
+            if with_stats:
+                mlp_out, aux, stats = out
+                return x + mlp_out, aux, stats
+            mlp_out, aux = out
+            return x + mlp_out, aux
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
+        up = h @ layer["w_up"].astype(cdt)
+        x = x + (gate * up) @ layer["w_down"].astype(cdt)
     if with_stats:
         return x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.float32)
     return x, jnp.zeros((), jnp.float32)
@@ -284,15 +301,18 @@ def forward(
     """
     cdt = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
-    x = params["embed"].astype(cdt)[tokens]
-    cos, sin = rope_tables(cfg, s, offset=position_offset)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]
+    with jax.named_scope("attn_proj"):
+        cos, sin = rope_tables(cfg, s, offset=position_offset)
 
     # flash and ring are PACKED-sequence kernels: attn_mask only weights
     # the loss, it never restricts attention (dense honors it for the
     # reference's padded-document layout, ref nanodiloco/main.py:79-88).
     mask = None
     if attn_mask is not None and cfg.attention_impl == "dense":
-        mask = causal_mask(s, valid=attn_mask)  # [B, 1, S, S]
+        with jax.named_scope("attention"):
+            mask = causal_mask(s, valid=attn_mask)  # [B, 1, S, S]
 
     # Bind all non-array arguments (cfg, sp_axis) BEFORE jax.checkpoint so
     # only JAX types flow through the remat boundary.
@@ -309,7 +329,10 @@ def forward(
         out = layer_fn(carry, layer, cos, sin, mask, attn_mask)
         return out[0], out[1:]
 
-    x, ys = jax.lax.scan(scan_body, x, params["layers"])
+    # the scan's own work (a layer's weights sliced out of the stack,
+    # the residuals stacked for the backward pass) reads as layer_scan
+    with jax.named_scope("layer_scan"):
+        x, ys = jax.lax.scan(scan_body, x, params["layers"])
     aux = jnp.sum(ys[0])
     stats = jnp.mean(ys[1], axis=0) if collect_stats else None  # [2]
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -321,10 +344,11 @@ def forward(
 
     if return_hidden:
         return pack(x)
-    head = params.get("lm_head", None)
-    if head is None:
-        head = params["embed"].T
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        head = params.get("lm_head", None)
+        if head is None:
+            head = params["embed"].T
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)
     return pack(logits)
 
 
@@ -356,22 +380,23 @@ def causal_lm_loss(
             return_hidden=True, with_aux=True,
         )
         b, s, d = h.shape
-        head = params.get("lm_head", None)
-        if head is None:
-            head = params["embed"].T
-        m = (
-            loss_mask[:, 1:] if loss_mask is not None
-            else jnp.ones_like(targets)
-        ).astype(jnp.float32)
-        sum_loss, n_tok = chunked_softmax_xent(
-            h[:, :-1].reshape(b * (s - 1), d),
-            head.astype(h.dtype),
-            targets.reshape(-1),
-            m.reshape(-1),
-            chunk=cfg.loss_chunk,
-        )
-        n = jnp.maximum(n_tok, 1.0)
-        loss = sum_loss / n + cfg.router_aux_coef * aux
+        with jax.named_scope("loss"):
+            head = params.get("lm_head", None)
+            if head is None:
+                head = params["embed"].T
+            m = (
+                loss_mask[:, 1:] if loss_mask is not None
+                else jnp.ones_like(targets)
+            ).astype(jnp.float32)
+            sum_loss, n_tok = chunked_softmax_xent(
+                h[:, :-1].reshape(b * (s - 1), d),
+                head.astype(h.dtype),
+                targets.reshape(-1),
+                m.reshape(-1),
+                chunk=cfg.loss_chunk,
+            )
+            n = jnp.maximum(n_tok, 1.0)
+            loss = sum_loss / n + cfg.router_aux_coef * aux
         return loss, {
             "n_tokens": n_tok, "sum_loss": sum_loss, "router_aux": aux,
         }
@@ -379,16 +404,17 @@ def causal_lm_loss(
     logits, aux = forward(
         params, tokens, cfg, attn_mask=loss_mask, sp_axis=sp_axis, with_aux=True
     )
-    logits = logits[:, :-1]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]  # [B, S-1]
-    if loss_mask is not None:
-        m = loss_mask[:, 1:].astype(nll.dtype)
-    else:
-        m = jnp.ones_like(nll)
-    sum_loss = jnp.sum(nll * m)
-    n = jnp.maximum(jnp.sum(m), 1.0)
-    loss = sum_loss / n + cfg.router_aux_coef * aux
+    with jax.named_scope("loss"):
+        logits = logits[:, :-1]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]  # [B, S-1]
+        if loss_mask is not None:
+            m = loss_mask[:, 1:].astype(nll.dtype)
+        else:
+            m = jnp.ones_like(nll)
+        sum_loss = jnp.sum(nll * m)
+        n = jnp.maximum(jnp.sum(m), 1.0)
+        loss = sum_loss / n + cfg.router_aux_coef * aux
     return loss, {
         "n_tokens": jnp.sum(m), "sum_loss": sum_loss, "router_aux": aux,
     }
@@ -502,22 +528,24 @@ def sp_shard_loss(
             params, tokens, cfg, attn_mask=None, sp_axis=axis_name,
             position_offset=idx * s_loc, return_hidden=True, with_aux=True,
         )
-        head = params.get("lm_head", None)
-        if head is None:
-            head = params["embed"].T
-        sl, n = chunked_softmax_xent(
-            h.reshape(b * s_loc, h.shape[-1]),
-            head.astype(h.dtype),
-            targets.reshape(-1),
-            m.reshape(-1),
-            chunk=cfg.loss_chunk,
-        )
+        with jax.named_scope("loss"):
+            head = params.get("lm_head", None)
+            if head is None:
+                head = params["embed"].T
+            sl, n = chunked_softmax_xent(
+                h.reshape(b * s_loc, h.shape[-1]),
+                head.astype(h.dtype),
+                targets.reshape(-1),
+                m.reshape(-1),
+                chunk=cfg.loss_chunk,
+            )
         return sl, n, aux
 
     logits, aux = forward(
         params, tokens, cfg, attn_mask=None, sp_axis=axis_name,
         position_offset=idx * s_loc, with_aux=True,
     )
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.sum(nll * m), jnp.sum(m), aux
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll * m), jnp.sum(m), aux

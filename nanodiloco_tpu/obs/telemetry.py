@@ -545,6 +545,21 @@ def release_profiler_window() -> None:
     _PROFILE_LOCK.release()
 
 
+def start_profile(trace_dir: str) -> None:
+    """``jax.profiler.start_trace`` with the options every capture of
+    this program uses (``--profile-dir``, ``POST /debug/profile``, and
+    the benchmark's ``--trace 1`` sets the same): TraceMe annotations on
+    (host tracer level 2: the program's ``trace_span``s), Python's own
+    tracer off (its events would be most of the file, slow the traced
+    threads, and say nothing a span does not)."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+
 def capture_live_profile(out_dir: str, seconds: float) -> dict:
     """Capture a ``jax.profiler`` trace of THIS live process for
     ``seconds`` into a fresh subdirectory of ``out_dir`` and return
@@ -571,7 +586,7 @@ def capture_live_profile(out_dir: str, seconds: float) -> dict:
         trace_dir = os.path.join(out_dir, f"capture-{_PROFILE_SEQ[0]:03d}")
         os.makedirs(trace_dir, exist_ok=True)
         try:
-            jax.profiler.start_trace(trace_dir)
+            start_profile(trace_dir)
         except Exception as e:
             # the startup --profile-dir window (or an embedder's trace)
             # holds the global profiler — busy, not broken

@@ -24,6 +24,16 @@ The module-level current tracer makes instrumentation non-invasive:
 library code calls ``trace_span`` unconditionally; when nothing
 installed a real tracer the spans are recorded on a process-wide
 default whose memory is bounded (``max_events``, oldest dropped).
+
+``trace_span`` is also the program's one span on the PROFILER's clock:
+it enters a ``jax.profiler.TraceAnnotation`` of the same name, so a
+``jax.profiler`` capture (``--profile-dir``, ``POST /debug/profile``,
+the benchmark's ``--trace 1``) shows the program's spans on the same
+timeline as the device's operations. Each annotation carries a
+``layer`` stat (``sched``, ``engine``, ``train``, ``diloco``, ``ckpt``,
+``data``): what tells a span of this program from the runtime's own
+events. With no capture running a ``TraceMe`` costs a flag check. JAX
+is imported at the first span, never at import of this module.
 """
 
 from __future__ import annotations
@@ -32,7 +42,7 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, NamedTuple
 
 from nanodiloco_tpu.obs import flightrec
@@ -409,10 +419,10 @@ class _NullTracer(SpanTracer):
 
     def __init__(self) -> None:
         super().__init__(max_events=0)
+        self._no_span = nullcontext(self)
 
-    @contextmanager
     def span(self, name: str, **args: Any):
-        yield self
+        return self._no_span
 
     def phase_totals(self, reset: bool = True) -> dict[str, float]:
         return {}
@@ -439,13 +449,39 @@ def current_tracer() -> SpanTracer:
     return _current
 
 
-@contextmanager
-def trace_span(name: str, **args: Any):
-    """``with trace_span("outer_sync"):`` — record on the current
-    tracer. The indirection is resolved at ENTRY so an install/restore
-    race mid-span still closes the span on the tracer that opened it."""
-    with _current.span(name, **args) as t:
-        yield t
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, from the first span on
+
+
+class trace_span:
+    """``with trace_span("sched.admit"):`` — record on the current
+    tracer AND as a ``jax.profiler.TraceAnnotation`` of that name. The
+    annotation's ``layer`` stat is ``layer``, else the name's part
+    before its first dot (``sched.admit`` -> ``sched``, ``ckpt`` ->
+    ``ckpt``); ``args`` (a request's ``rid``, a ``slot``) ride on both.
+    Spans nest on a thread in both. The tracer indirection is resolved
+    at ENTRY so an install/restore race mid-span still closes the span
+    on the tracer that opened it. A class and not a generator: a dozen
+    of these run in every serving tick."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, name: str, layer: str | None = None, **args: Any) -> None:
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._annotation = _TraceAnnotation(
+            name, layer=layer or name.partition(".")[0], **args)
+        self._span = _current.span(name, **args)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return self._span.__exit__(*exc)
+        finally:
+            self._annotation.__exit__(*exc)
 
 
 def trace_shard_path(path: str, process_index: int) -> str:

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("loss")
 def chunked_softmax_xent(
     hidden: jax.Array,     # [N, d] compute-dtype rows (already label-aligned)
     head: jax.Array,       # [d, V]

@@ -129,6 +129,7 @@ def _mb_token_counts(loss_mask_mb, sp_axis: str | None):
     return jnp.sum(m, axis=(1, 2))
 
 
+@jax.named_scope("loss")
 def _hidden_ce(h, head, targets, weights, chunk: int):
     """(sum_loss, n_tokens) from final hidden states [B, S-1 rows]."""
     b, s1, d = h.shape

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from nanodiloco_tpu.models.config import LlamaConfig
 from nanodiloco_tpu.models.llama import causal_lm_loss, init_params
+from nanodiloco_tpu.obs.tracer import trace_span
 from nanodiloco_tpu.parallel.sharding import batch_spec, constrain, param_specs
 from nanodiloco_tpu.training.optim import inner_optimizer, outer_optimizer
 
@@ -446,14 +447,24 @@ class Diloco:
         _outer_jit = self._with_mesh(
             jax.jit(self._outer_step_state, donate_argnums=(0,))
         )
-        self.outer_step = lambda state, worker_mask=None: _outer_jit(
-            self._fetch(state), worker_mask, *self._hb()
-        )
+
+        # the round-level entries dispatch under a span (obs/tracer: the
+        # tracer's and the profiler's), so that a capture of a caller
+        # that drives Diloco directly shows the program's phases too
+        def _outer_step_entry(state, worker_mask=None):
+            with trace_span("diloco.outer"):
+                return _outer_jit(self._fetch(state), worker_mask, *self._hb())
+
+        self.outer_step = _outer_step_entry
         self._round_jit = jax.jit(self._round_step, donate_argnums=(0,))
         _round_call = self._with_mesh(self._round_jit)
-        self.round_step = lambda state, tokens, mask: _round_call(
-            self._fetch(state), tokens, mask, *self._hb()
-        )
+
+        def _round_step_entry(state, tokens, mask):
+            with trace_span("diloco.round"):
+                return _round_call(
+                    self._fetch(state), tokens, mask, *self._hb())
+
+        self.round_step = _round_step_entry
         # H inner steps with NO outer sync: same dispatch count as
         # round_step, so differencing the two isolates the outer
         # all-reduce's true wall clock even in fused mode (the metric the
@@ -464,7 +475,8 @@ class Diloco:
         )
 
         def _inner_round_step_entry(state, tokens, mask):
-            out = _inner_round_call(state, tokens, mask, *self._hb())
+            with trace_span("diloco.inner_round"):
+                out = _inner_round_call(state, tokens, mask, *self._hb())
             if self._h_budget is not None:
                 # record this round-scan's budget: the async fused
                 # loop's FIRST program is this inner-only scan, and the
@@ -737,9 +749,8 @@ class Diloco:
                 (w_tokens, w_mask),
             )
             accum = w_tokens.shape[0]
-            grads = jax.tree.map(lambda g: g / jnp.maximum(n_sum, 1e-9), g_sum)
-            updates, opt_state = self.inner_tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params, opt_state = self._inner_update(
+                g_sum, n_sum, opt_state, params)
             return params, opt_state, loss_sum / accum
 
         if self.pp > 1:  # handles sp>1 too (sequence-sharded pipeline)
@@ -774,6 +785,15 @@ class Diloco:
             inner_step_count=state.inner_step_count + 1,
         )
         return state, loss  # loss: [W] per-worker mean microbatch loss
+
+    @jax.named_scope("inner_opt")
+    def _inner_update(self, g_sum, n_sum, opt_state, params):
+        """One worker's update from its token-weighted gradient sum:
+        the mean, then the inner chain (clip, AdamW). Returns (params,
+        opt_state)."""
+        grads = jax.tree.map(lambda g: g / jnp.maximum(n_sum, 1e-9), g_sum)
+        updates, opt_state = self.inner_tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
 
     def _sp_inner_update(self, state: DilocoState, tokens, loss_mask):
         """Sequence-parallel inner step: ONE shard_map manual over
@@ -836,9 +856,8 @@ class Diloco:
             # aux's value is sp-uniform already; psum/size replicates its
             # manual-axis type for the out_specs
             aux_sum = jax.lax.psum(aux_sum, "sp") / jax.lax.psum(1, "sp")
-            grads = jax.tree.map(lambda g: g / jnp.maximum(n_sum, 1e-9), g_sum)
-            updates, opt_state = self.inner_tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params, opt_state = self._inner_update(
+                g_sum, n_sum, opt_state, params)
             # per-worker mean token loss (== mean of per-micro means for
             # the packed equal-length sequences this path requires) plus
             # the mean router aux, matching the vmap path's loss metric
@@ -967,26 +986,27 @@ class Diloco:
                 # every shard saw only its sequence slice of the SUM loss:
                 # grads combine over sp for ALL leaves
                 g = jax.tree.map(lambda x: jax.lax.psum(x, sp_axis), g)
-            grads = jax.tree.map(lambda x: x / jnp.maximum(n, 1e-9), g)
-            if clip is not None:
-                sq_layers = sum(
-                    jnp.sum(jnp.square(x))
-                    for x in jax.tree.leaves(grads["layers"])
-                )
-                sq_rep = sum(
-                    jnp.sum(jnp.square(x))
-                    for k, v in grads.items() if k != "layers"
-                    for x in jax.tree.leaves(v)
-                )
-                g_norm = jnp.sqrt(jax.lax.psum(sq_layers, "pp") + sq_rep)
-                # optax.clip_by_global_norm semantics: untouched below
-                # the threshold, scaled by max_norm/norm above it
-                grads = jax.tree.map(
-                    lambda t: jnp.where(g_norm < clip, t, (t / g_norm) * clip),
-                    grads,
-                )
-            updates, opt_state = self.inner_tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("inner_opt"):  # clip and AdamW
+                grads = jax.tree.map(lambda x: x / jnp.maximum(n, 1e-9), g)
+                if clip is not None:
+                    sq_layers = sum(
+                        jnp.sum(jnp.square(x))
+                        for x in jax.tree.leaves(grads["layers"])
+                    )
+                    sq_rep = sum(
+                        jnp.sum(jnp.square(x))
+                        for k, v in grads.items() if k != "layers"
+                        for x in jax.tree.leaves(v)
+                    )
+                    g_norm = jnp.sqrt(jax.lax.psum(sq_layers, "pp") + sq_rep)
+                    # optax.clip_by_global_norm semantics: untouched below
+                    # the threshold, scaled by max_norm/norm above it
+                    grads = jax.tree.map(
+                        lambda t: jnp.where(g_norm < clip, t, (t / g_norm) * clip),
+                        grads,
+                    )
+                updates, opt_state = self.inner_tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             loss = metric
             return (
                 jax.tree.map(lambda x: x[None], params),
@@ -1501,6 +1521,7 @@ class Diloco:
             "outer_update_cos": rep(cos),
         }
 
+    @jax.named_scope("outer")  # pseudo-gradient, all-reduce, Nesterov
     def _outer_step(
         self,
         state: DilocoState,
@@ -1674,6 +1695,7 @@ class Diloco:
 
     # -- async delayed-apply outer step (DilocoConfig.async_outer) -----------
 
+    @jax.named_scope("outer")
     def _async_boundary(
         self, state: AsyncDilocoState, h_budget: jax.Array | None = None
     ):

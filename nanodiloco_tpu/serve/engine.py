@@ -142,6 +142,7 @@ from nanodiloco_tpu.models.generate import (
 )
 from nanodiloco_tpu.obs.devtime import DispatchAccountant
 from nanodiloco_tpu.obs.telemetry import Histogram
+from nanodiloco_tpu.obs.tracer import trace_span
 from nanodiloco_tpu.serve import kvship
 from nanodiloco_tpu.serve.block_pool import BlockPool, BlocksExhausted
 from nanodiloco_tpu.serve.prefix_cache import PrefixCache
@@ -649,15 +650,18 @@ class InferenceEngine:
         program; returns (token scalar, logits [1, V])."""
         self._buckets.setdefault("prefill_chunk", set()).add(len(chunk))
         params = self._params_by_gen[self._slot_gen[slot]]
-        args = (
-            self._jarr([chunk], np.int32), self._jarr(valid),
-            self._jarr(pos, np.int32), self._jarr(last, np.int32),
-            self._jarr(key_data, np.uint32),
-            self._jarr(temp, np.float32), self._jarr(top_k, np.int32),
-            self._jarr(top_p, np.float32),
-        )
+        with trace_span("engine.stage_chunk", slot=slot):
+            args = (
+                self._jarr([chunk], np.int32), self._jarr(valid),
+                self._jarr(pos, np.int32), self._jarr(last, np.int32),
+                self._jarr(key_data, np.uint32),
+                self._jarr(temp, np.float32), self._jarr(top_k, np.int32),
+                self._jarr(top_p, np.float32),
+            )
         with self.accountant.section("prefill_chunk", len(chunk),
-                                     self.kv_layout):
+                                     self.kv_layout), \
+                trace_span("engine.prefill_chunk", slot=slot,
+                           bucket=len(chunk)):
             if self.paged:
                 tok, logits, self.pool = self._chunk_paged(
                     params, self.pool,
@@ -736,23 +740,25 @@ class InferenceEngine:
         top_k = min(int(req.top_k), self.vocab_size)
         top_p = float(req.top_p)
         # the one-shot generate()'s exact key schedule, replayed per slot
-        key = jax.random.key(int(req.seed))
-        karr = jax.random.split(key)  # karr[0] = rest, karr[1] = k0
+        with trace_span("engine.keys", slot=slot):
+            key = jax.random.key(int(req.seed))
+            karr = jax.random.split(key)  # karr[0] = rest, karr[1] = k0
+            k0 = np.asarray(jax.random.key_data(karr[1]), np.uint32)
         tok, logits = self._run_chunk(
-            slot, chunk, valid, lo, last,
-            np.asarray(jax.random.key_data(karr[1]), np.uint32),
-            temp, top_k, top_p,
+            slot, chunk, valid, lo, last, k0, temp, top_k, top_p,
         )
         tok0 = int(tok)
         if self.capture_prefill_logits:
             self.last_prefill_logits = np.asarray(logits)
         pf.done = p
         n = int(req.max_new_tokens)
-        self._keys[slot] = (
-            np.asarray(jax.random.key_data(jax.random.split(karr[0], n - 1)),
-                       np.uint32)
-            if n > 1 else np.zeros((0, 2), np.uint32)
-        )
+        with trace_span("engine.keys", slot=slot):
+            self._keys[slot] = (
+                np.asarray(
+                    jax.random.key_data(jax.random.split(karr[0], n - 1)),
+                    np.uint32)
+                if n > 1 else np.zeros((0, 2), np.uint32)
+            )
         self._step_idx[slot] = 0
         self._pos[slot] = p
         self._key_valid[slot] = 1
@@ -875,10 +881,11 @@ class InferenceEngine:
         prefix plus the verified bonus token (never zero: all-reject
         still makes one token of forward progress)."""
         b = self.num_slots
-        drafts, k_tick = (
-            self._collect_drafts() if self.spec_k
-            else ([[] for _ in range(b)], 0)
-        )
+        if self.spec_k:
+            with trace_span("engine.draft"):
+                drafts, k_tick = self._collect_drafts()
+        else:
+            drafts, k_tick = [[] for _ in range(b)], 0
         self.decode_ticks += 1
         if k_tick == 0:
             return self._step_plain()
@@ -908,46 +915,52 @@ class InferenceEngine:
 
     def _step_plain(self) -> list[list[int]]:
         b = self.num_slots
-        keys_now = np.empty((b, 2), np.uint32)
-        for s in range(b):
-            ks = self._keys[s]
-            if self._active[s] and ks is not None and self._step_idx[s] < len(ks):
-                keys_now[s] = ks[self._step_idx[s]]
-            else:
-                keys_now[s] = self._dummy_key
-        self._buckets.setdefault("decode", set()).add(1)
-        dev = self._stage_dev()
-        tokens = self._jarr(self._tokens)
-        pos = self._jarr(self._pos)
-        keys = self._jarr(keys_now)
-        out: list[list[int]] = [[] for _ in range(b)]
-        for params, slots, active in self._gen_dispatches(dev):
-            with self.accountant.section("decode", 1, self.kv_layout):
-                if self.paged:
-                    nxt, self.pool = self._decode_paged(
-                        params, self.pool, dev["tables"],
-                        tokens, pos, keys,
-                        dev["temp"], dev["topk"], dev["topp"], active,
-                    )
+        with trace_span("engine.stage"):
+            keys_now = np.empty((b, 2), np.uint32)
+            for s in range(b):
+                ks = self._keys[s]
+                if (self._active[s] and ks is not None
+                        and self._step_idx[s] < len(ks)):
+                    keys_now[s] = ks[self._step_idx[s]]
                 else:
-                    nxt, self.cache = self._decode(
-                        params, self.cache,
-                        tokens, pos,
-                        dev["key_valid"], keys,
-                        dev["temp"], dev["topk"], dev["topp"], active,
-                    )
+                    keys_now[s] = self._dummy_key
+            self._buckets.setdefault("decode", set()).add(1)
+            dev = self._stage_dev()
+            tokens = self._jarr(self._tokens)
+            pos = self._jarr(self._pos)
+            keys = self._jarr(keys_now)
+            dispatches = self._gen_dispatches(dev)
+        out: list[list[int]] = [[] for _ in range(b)]
+        for params, slots, active in dispatches:
+            with self.accountant.section("decode", 1, self.kv_layout):
+                with trace_span("engine.decode_dispatch"):
+                    if self.paged:
+                        nxt, self.pool = self._decode_paged(
+                            params, self.pool, dev["tables"],
+                            tokens, pos, keys,
+                            dev["temp"], dev["topk"], dev["topp"], active,
+                        )
+                    else:
+                        nxt, self.cache = self._decode(
+                            params, self.cache,
+                            tokens, pos,
+                            dev["key_valid"], keys,
+                            dev["temp"], dev["topk"], dev["topp"], active,
+                        )
                 # the host fetch below is the tick's natural fence;
                 # inside the section so the measured seconds cover the
                 # program, not just its dispatch
-                nxt = np.asarray(nxt)
-            for s in slots:
-                self._pos[s] += 1
-                self._step_idx[s] += 1
-                self._tokens[s] = nxt[s]
-                tok = int(nxt[s])
-                if self._spec_ok[s]:
-                    self.speculator.observe(s, [tok])
-                out[s] = [tok]
+                with trace_span("engine.fetch_tokens"):
+                    nxt = np.asarray(nxt)
+            with trace_span("engine.advance"):
+                for s in slots:
+                    self._pos[s] += 1
+                    self._step_idx[s] += 1
+                    self._tokens[s] = nxt[s]
+                    tok = int(nxt[s])
+                    if self._spec_ok[s]:
+                        self.speculator.observe(s, [tok])
+                    out[s] = [tok]
         return out
 
     def _step_verify(self, drafts: list[list[int]], k_tick: int) -> list[list[int]]:
@@ -963,68 +976,73 @@ class InferenceEngine:
         b = self.num_slots
         bucket = min(_ceil_pow2(k_tick), self.spec_k)
         t = bucket + 1
-        tokens = np.zeros((b, t), np.int32)
-        tokens[:, 0] = self._tokens
-        dlen = np.zeros(b, np.int32)
-        keys_now = np.empty((b, t, 2), np.uint32)
-        keys_now[:] = self._dummy_key
-        for s in range(b):
-            d = drafts[s][:bucket]
-            if d:
-                tokens[s, 1:1 + len(d)] = d
-                dlen[s] = len(d)
-            ks = self._keys[s]
-            if self._active[s] and ks is not None:
-                lo = self._step_idx[s]
-                n = min(t, len(ks) - lo)
-                if n > 0:
-                    keys_now[s, :n] = ks[lo:lo + n]
-        self._buckets.setdefault("verify", set()).add(t)
-        dev = self._stage_dev()
-        jtokens = self._jarr(tokens)
-        jpos = self._jarr(self._pos)
-        jdlen = self._jarr(dlen)
-        jkeys = self._jarr(keys_now)
+        with trace_span("engine.stage"):
+            tokens = np.zeros((b, t), np.int32)
+            tokens[:, 0] = self._tokens
+            dlen = np.zeros(b, np.int32)
+            keys_now = np.empty((b, t, 2), np.uint32)
+            keys_now[:] = self._dummy_key
+            for s in range(b):
+                d = drafts[s][:bucket]
+                if d:
+                    tokens[s, 1:1 + len(d)] = d
+                    dlen[s] = len(d)
+                ks = self._keys[s]
+                if self._active[s] and ks is not None:
+                    lo = self._step_idx[s]
+                    n = min(t, len(ks) - lo)
+                    if n > 0:
+                        keys_now[s, :n] = ks[lo:lo + n]
+            self._buckets.setdefault("verify", set()).add(t)
+            dev = self._stage_dev()
+            jtokens = self._jarr(tokens)
+            jpos = self._jarr(self._pos)
+            jdlen = self._jarr(dlen)
+            jkeys = self._jarr(keys_now)
+            dispatches = self._gen_dispatches(dev)
         out: list[list[int]] = [[] for _ in range(b)]
-        for params, slots, active in self._gen_dispatches(dev):
+        for params, slots, active in dispatches:
             with self.accountant.section("verify", t, self.kv_layout):
-                if self.paged:
-                    sampled, counts, self.pool = self._verify(
-                        params, self.pool, dev["tables"],
-                        jtokens, jpos, jdlen, jkeys,
-                        dev["temp"], dev["topk"], dev["topp"], active,
-                    )
-                else:
-                    sampled, counts, self.cache = self._verify(
-                        params, self.cache, jtokens, jpos, jdlen,
-                        dev["key_valid"], jkeys,
-                        dev["temp"], dev["topk"], dev["topp"], active,
-                    )
-                sampled = np.asarray(sampled)
-                counts = np.asarray(counts)
-            for s in slots:
-                c = int(counts[s])
-                emitted = [int(v) for v in sampled[s, :c]]
-                self._pos[s] += c
-                self._step_idx[s] += c
-                self._tokens[s] = emitted[-1]
-                proposed = int(dlen[s])
-                accepted = c - 1
-                self.spec_draft_tokens += proposed
-                self.spec_accepted_tokens += accepted
-                self.spec_rejected_tokens += proposed - accepted
-                if proposed:
-                    # drafting slots only: a no-draft neighbour riding
-                    # the verify tick emits 1 by construction, and
-                    # counting it would make the gated tokens-per-tick
-                    # economics measure batch composition instead of
-                    # speculation quality
-                    self.hist_spec_tokens_per_tick.observe(c)
-                if self._spec_ok[s]:
+                with trace_span("engine.decode_dispatch"):
+                    if self.paged:
+                        sampled, counts, self.pool = self._verify(
+                            params, self.pool, dev["tables"],
+                            jtokens, jpos, jdlen, jkeys,
+                            dev["temp"], dev["topk"], dev["topp"], active,
+                        )
+                    else:
+                        sampled, counts, self.cache = self._verify(
+                            params, self.cache, jtokens, jpos, jdlen,
+                            dev["key_valid"], jkeys,
+                            dev["temp"], dev["topk"], dev["topp"], active,
+                        )
+                with trace_span("engine.fetch_tokens"):
+                    sampled = np.asarray(sampled)
+                    counts = np.asarray(counts)
+            with trace_span("engine.advance"):
+                for s in slots:
+                    c = int(counts[s])
+                    emitted = [int(v) for v in sampled[s, :c]]
+                    self._pos[s] += c
+                    self._step_idx[s] += c
+                    self._tokens[s] = emitted[-1]
+                    proposed = int(dlen[s])
+                    accepted = c - 1
+                    self.spec_draft_tokens += proposed
+                    self.spec_accepted_tokens += accepted
+                    self.spec_rejected_tokens += proposed - accepted
                     if proposed:
-                        self.speculator.feedback(s, proposed, accepted)
-                    self.speculator.observe(s, emitted)
-                out[s] = emitted
+                        # drafting slots only: a no-draft neighbour riding
+                        # the verify tick emits 1 by construction, and
+                        # counting it would make the gated tokens-per-tick
+                        # economics measure batch composition instead of
+                        # speculation quality
+                        self.hist_spec_tokens_per_tick.observe(c)
+                    if self._spec_ok[s]:
+                        if proposed:
+                            self.speculator.feedback(s, proposed, accepted)
+                        self.speculator.observe(s, emitted)
+                    out[s] = emitted
         self.spec_ticks += 1
         return out
 

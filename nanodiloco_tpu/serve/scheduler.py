@@ -73,7 +73,7 @@ import numpy as np
 
 from nanodiloco_tpu.obs import flightrec
 from nanodiloco_tpu.obs.telemetry import Histogram, nearest_rank_percentile
-from nanodiloco_tpu.obs.tracer import TraceContext
+from nanodiloco_tpu.obs.tracer import TraceContext, trace_span
 from nanodiloco_tpu.serve.block_pool import BlocksExhausted
 
 
@@ -256,6 +256,10 @@ class _Running:
     admitted_at: float
     first_token_at: float
     tokens: list[int]
+    # the scheduler's clock at each token's delivery, one entry a token
+    # (the first at ``first_token_at``, every token of one tick's vector
+    # at that tick's end): the result's ``token_s``
+    token_at: list[float]
     # device-time attribution: prefill seconds carried over from the
     # _Prefilling phase; decode seconds are this slot's share of each
     # measured tick (split over the slots it advanced, weighted by
@@ -548,9 +552,10 @@ class Scheduler:
             now + request.deadline_s
             if request.deadline_s is not None else None
         )
+        emitted = [int(t) for t in shipped.emitted]
         run = _Running(
             ticket, request, now, deadline, now, now,
-            [int(t) for t in shipped.emitted],
+            emitted, [now] * len(emitted),
             blocks_held=int(held(slot)) if held is not None else 0,
         )
         # a ship can arrive already satisfied (stop token in the emitted
@@ -558,8 +563,7 @@ class Scheduler:
         # than decode a finished stream
         reason = self._finish_reason(run, now)
         if reason is not None:
-            self._backend_release(slot)
-            self._retire(run, reason, now)
+            self._retire(slot, run, reason, now)
         else:
             self._slots[slot] = run
         return ticket
@@ -570,7 +574,36 @@ class Scheduler:
         """One deterministic scheduling round (see module docstring).
         Returns the number of occupied slots (prefilling or decoding)
         after the tick, so a serving loop can idle when there is no
-        work."""
+        work.
+
+        Every step runs inside a ``trace_span`` under one ``sched.tick``
+        (the engine's own ``engine.*`` spans nest under the step that
+        calls it), so a profiler capture shows which step the host
+        spent the time between two device programs in."""
+        with trace_span("sched.tick"):
+            with trace_span("sched.control"):
+                self._run_control()
+            now = self._clock()
+            with trace_span("sched.expire"):
+                self._expire(now)
+            with trace_span("sched.admit"):
+                self._admit()
+            pf_slots = [
+                s for s, r in enumerate(self._slots)
+                if isinstance(r, _Prefilling)
+            ]
+            if pf_slots:
+                with trace_span("sched.prefill"):
+                    self._prefill_chunk(pf_slots)
+            live = [
+                s for s in range(len(self._slots))
+                if isinstance(self._slots[s], _Running)
+            ]
+            if live:
+                self._decode(live)
+            return sum(1 for s in self._slots if s is not None)
+
+    def _run_control(self) -> None:
         # 0. run control functions handed over from other threads (a
         # weight hot-swap): they mutate the backend, which belongs to
         # this thread; an error is the CALLER's to read, never fatal to
@@ -585,7 +618,8 @@ class Scheduler:
             except Exception as e:
                 handle.error = f"{type(e).__name__}: {e}"
             handle._event.set()
-        now = self._clock()
+
+    def _expire(self, now: float) -> None:
         # 1. drop queued requests whose deadline passed or whose client
         # cancelled (they never held a slot)
         dropped: list[tuple[_Queued, str]] = []
@@ -653,6 +687,7 @@ class Scheduler:
                 self._slots[s] = None
                 self._park_expired += 1
 
+    def _admit(self) -> None:
         # 3. admit into free slots in SLO order (priority class, EDF
         # within it, starvation bound on top) — staging only; the model
         # work happens one chunk per tick in step 4. A cancelled or
@@ -690,7 +725,8 @@ class Scheduler:
             rid_str = self._req_id(q.ticket, q.request)
             t_admit = self._clock()
             try:
-                chunks = int(self.backend.start_prefill(slot, q.request))
+                with trace_span("engine.start_prefill", rid=rid_str, slot=slot):
+                    chunks = int(self.backend.start_prefill(slot, q.request))
             except BlocksExhausted:
                 # nothing was allocated (the pool's alloc is
                 # all-or-nothing) and the request stays exactly where
@@ -738,6 +774,7 @@ class Scheduler:
                 and all(s is not None for s in self._slots)):
             self._blocked_no_slot += 1
 
+    def _prefill_chunk(self, pf_slots: list[int]) -> None:
         # 4. ONE prefill chunk, to the fewest-chunks-remaining slot
         # (shortest-remaining-first bounds short-request TTFT while a
         # long prefill is in flight), priority then admission order as
@@ -747,95 +784,90 @@ class Scheduler:
         # one-chunk shorts would starve a long prefill forever (the
         # admission-level starvation bound stops at the queue pop; this
         # is its in-slot counterpart).
-        pf_slots = [
-            s for s, r in enumerate(self._slots)
-            if isinstance(r, _Prefilling)
-        ]
-        if pf_slots:
-            aged = [s for s in pf_slots
-                    if self._slots[s].bypassed >= self.prefill_aging_ticks]
-            if aged:
-                s = max(aged, key=lambda i: (self._slots[i].bypassed,
-                                             -self._slots[i].ticket.rid))
+        aged = [s for s in pf_slots
+                if self._slots[s].bypassed >= self.prefill_aging_ticks]
+        if aged:
+            s = max(aged, key=lambda i: (self._slots[i].bypassed,
+                                         -self._slots[i].ticket.rid))
+        else:
+            s = min(pf_slots, key=lambda i: (
+                self._slots[i].chunks_left,
+                self._slots[i].request.priority,
+                self._slots[i].ticket.rid,
+            ))
+        for other in pf_slots:
+            if other != s:
+                self._slots[other].bypassed += 1
+        run = self._slots[s]
+        run.bypassed = 0
+        # the chunk's measured seconds bill WHOLLY to this request
+        # (one chunk advances exactly one prefill) — the scheduler's
+        # own clock, so scripted backends and injected clocks in
+        # tests attribute the same way the engine path does
+        t_pf0 = self._clock()
+        tok0 = self.backend.prefill_step(s)
+        pf_dt = self._clock() - t_pf0
+        self._prefill_s += pf_dt
+        run.prefill_device_s += pf_dt
+        self._prefill_chunks += 1
+        run.chunks_run += 1
+        run.chunks_left = max(0, run.chunks_left - 1)
+        if tok0 is not None:
+            t_first = self._clock()
+            rid_str = self._req_id(run.ticket, run.request)
+            self.hist_ttft.observe(t_first - run.submitted_at,
+                                   exemplar=self._trace_id(run.request))
+            self._span("prefill", run.admitted_at, t_first, rid_str,
+                       ctx=self._ctx(run.request),
+                       slot=s, prompt_tokens=len(run.request.prompt),
+                       chunks=run.chunks_run)
+            with self._lock:  # stats() sorts this deque from HTTP threads
+                self._ttft.append(t_first - run.submitted_at)
+                dq = self._ttft_by_priority.setdefault(
+                    int(run.request.priority),
+                    collections.deque(maxlen=256),
+                )
+                dq.append(t_first - run.submitted_at)
+            self._tokens_out += 1
+            live = _Running(run.ticket, run.request, run.submitted_at,
+                            run.deadline_at, run.admitted_at, t_first,
+                            [int(tok0)], [t_first],
+                            prefill_device_s=run.prefill_device_s,
+                            blocks_held=run.blocks_held)
+            reason = self._finish_reason(live, t_first)
+            if reason is not None:
+                # prefill already activated the slot in the backend;
+                # an unreleased instant-finish would decode as a
+                # zombie
+                self._retire(s, live, reason, t_first)
+            elif run.request.prefill_only:
+                # disaggregated admission: the stream finishes HERE
+                # with its first token; the slot parks — cache rows
+                # intact, not decoding — until /admin/kv/export
+                # ships them (or the TTL/deadline sweep reclaims an
+                # abandoned handoff). Billing settles now: block
+                # residency DURING the park is the handoff's cost,
+                # billed at export/expiry, not to the request.
+                self._slots[s] = _Parked(
+                    run.request, rid_str, [int(tok0)],
+                    run.submitted_at, run.deadline_at,
+                    run.admitted_at, t_first,
+                    prefill_device_s=run.prefill_device_s,
+                    blocks_held=run.blocks_held,
+                )
+                self._served += 1
+                self._finish(
+                    run.ticket, run.request, [int(tok0)], "prefilled",
+                    run.submitted_at, run.admitted_at, t_first, t_first,
+                    prefill_device_s=run.prefill_device_s,
+                    kv_block_seconds=(
+                        run.blocks_held * (t_first - run.admitted_at)),
+                    token_at=[t_first],
+                )
             else:
-                s = min(pf_slots, key=lambda i: (
-                    self._slots[i].chunks_left,
-                    self._slots[i].request.priority,
-                    self._slots[i].ticket.rid,
-                ))
-            for other in pf_slots:
-                if other != s:
-                    self._slots[other].bypassed += 1
-            run = self._slots[s]
-            run.bypassed = 0
-            # the chunk's measured seconds bill WHOLLY to this request
-            # (one chunk advances exactly one prefill) — the scheduler's
-            # own clock, so scripted backends and injected clocks in
-            # tests attribute the same way the engine path does
-            t_pf0 = self._clock()
-            tok0 = self.backend.prefill_step(s)
-            pf_dt = self._clock() - t_pf0
-            self._prefill_s += pf_dt
-            run.prefill_device_s += pf_dt
-            self._prefill_chunks += 1
-            run.chunks_run += 1
-            run.chunks_left = max(0, run.chunks_left - 1)
-            if tok0 is not None:
-                t_first = self._clock()
-                rid_str = self._req_id(run.ticket, run.request)
-                self.hist_ttft.observe(t_first - run.submitted_at,
-                                       exemplar=self._trace_id(run.request))
-                self._span("prefill", run.admitted_at, t_first, rid_str,
-                           ctx=self._ctx(run.request),
-                           slot=s, prompt_tokens=len(run.request.prompt),
-                           chunks=run.chunks_run)
-                with self._lock:  # stats() sorts this deque from HTTP threads
-                    self._ttft.append(t_first - run.submitted_at)
-                    dq = self._ttft_by_priority.setdefault(
-                        int(run.request.priority),
-                        collections.deque(maxlen=256),
-                    )
-                    dq.append(t_first - run.submitted_at)
-                self._tokens_out += 1
-                live = _Running(run.ticket, run.request, run.submitted_at,
-                                run.deadline_at, run.admitted_at, t_first,
-                                [int(tok0)],
-                                prefill_device_s=run.prefill_device_s,
-                                blocks_held=run.blocks_held)
-                reason = self._finish_reason(live, t_first)
-                if reason is not None:
-                    # prefill already activated the slot in the backend;
-                    # an unreleased instant-finish would decode as a
-                    # zombie
-                    self._backend_release(s)
-                    self._slots[s] = None
-                    self._retire(live, reason, t_first)
-                elif run.request.prefill_only:
-                    # disaggregated admission: the stream finishes HERE
-                    # with its first token; the slot parks — cache rows
-                    # intact, not decoding — until /admin/kv/export
-                    # ships them (or the TTL/deadline sweep reclaims an
-                    # abandoned handoff). Billing settles now: block
-                    # residency DURING the park is the handoff's cost,
-                    # billed at export/expiry, not to the request.
-                    self._slots[s] = _Parked(
-                        run.request, rid_str, [int(tok0)],
-                        run.submitted_at, run.deadline_at,
-                        run.admitted_at, t_first,
-                        prefill_device_s=run.prefill_device_s,
-                        blocks_held=run.blocks_held,
-                    )
-                    self._served += 1
-                    self._finish(
-                        run.ticket, run.request, [int(tok0)], "prefilled",
-                        run.submitted_at, run.admitted_at, t_first, t_first,
-                        prefill_device_s=run.prefill_device_s,
-                        kv_block_seconds=(
-                            run.blocks_held * (t_first - run.admitted_at)),
-                    )
-                else:
-                    self._slots[s] = live
+                self._slots[s] = live
 
+    def _decode(self, live: list[int]) -> None:
         # 5. one decode step for everyone live. The backend emits a
         # token VECTOR per slot (1..k+1 under speculative decoding;
         # legacy/fake backends may still return one scalar per slot):
@@ -845,14 +877,10 @@ class Scheduler:
         # result. Decode stats count EMITTED tokens, not ticks: at one
         # token per tick the two were equal, so the old tick count was
         # latently wrong the moment multi-token emission landed.
-        live = [
-            s for s in range(len(self._slots))
-            if isinstance(self._slots[s], _Running)
-        ]
-        if live:
-            t0 = self._clock()
-            toks = self.backend.step()
-            t1 = self._clock()
+        t0 = self._clock()
+        toks = self.backend.step()
+        t1 = self._clock()
+        with trace_span("sched.deliver"):
             tick_dt = t1 - t0
             self._decode_s += tick_dt
             self.hist_decode_tick.observe(tick_dt)
@@ -898,19 +926,19 @@ class Scheduler:
                     if len(run.tokens) >= req.max_new_tokens:
                         reason = "length"
                         break
+                # one stamp for the tick's whole vector: its tokens
+                # reach the caller together
+                run.token_at.extend([t1] * emitted)
                 self._tokens_out += emitted
                 self._decode_tokens += emitted
                 if reason is None:
                     reason = self._finish_reason(run, t1)
                 if reason is not None:
-                    self._backend_release(s)
-                    self._slots[s] = None
                     self._span("decode", run.first_token_at, t1,
                                self._req_id(run.ticket, run.request),
                                ctx=self._ctx(run.request),
                                tokens=len(run.tokens), outcome=reason)
-                    self._retire(run, reason, t1)
-        return sum(1 for s in self._slots if s is not None)
+                    self._retire(s, run, reason, t1)
 
     def _peek_queued(self) -> _Queued | None:
         """The next request to admit, WITHOUT removing it (removal is
@@ -1023,20 +1051,29 @@ class Scheduler:
             return "deadline"
         return None
 
-    def _retire(self, run: _Running, reason: str, now: float) -> None:
-        if reason == "cancelled":
-            self._cancelled += 1
-        else:
-            self._served += 1
-        self._finish(run.ticket, run.request, run.tokens, reason,
-                     run.submitted_at, run.admitted_at, run.first_token_at,
-                     now,
-                     prefill_device_s=run.prefill_device_s,
-                     decode_device_s=run.decode_device_s,
-                     # blocks are allocated all-or-nothing at admission
-                     # and constant until release — the block-seconds
-                     # bill settles exactly here, at release time
-                     kv_block_seconds=run.blocks_held * (now - run.admitted_at))
+    def _retire(self, slot: int, run: _Running, reason: str,
+                now: float) -> None:
+        """A stream's end: free ``slot`` in the backend and here, count
+        the outcome, hand the result to the waiting caller."""
+        with trace_span("sched.retire",
+                        rid=self._req_id(run.ticket, run.request), slot=slot):
+            self._backend_release(slot)
+            self._slots[slot] = None
+            if reason == "cancelled":
+                self._cancelled += 1
+            else:
+                self._served += 1
+            self._finish(run.ticket, run.request, run.tokens, reason,
+                         run.submitted_at, run.admitted_at,
+                         run.first_token_at, now,
+                         prefill_device_s=run.prefill_device_s,
+                         decode_device_s=run.decode_device_s,
+                         # blocks are allocated all-or-nothing at admission
+                         # and constant until release — the block-seconds
+                         # bill settles exactly here, at release time
+                         kv_block_seconds=(
+                             run.blocks_held * (now - run.admitted_at)),
+                         token_at=run.token_at)
 
     def _finish(self, ticket: Ticket, request: GenRequest, tokens: list[int],
                 reason: str, submitted_at: float, admitted_at: float | None,
@@ -1044,11 +1081,17 @@ class Scheduler:
                 error: str | None = None,
                 prefill_device_s: float = 0.0,
                 decode_device_s: float = 0.0,
-                kv_block_seconds: float = 0.0) -> None:
+                kv_block_seconds: float = 0.0,
+                token_at: list[float] | tuple = ()) -> None:
         result = {
             "rid": ticket.rid,
             "request_id": self._req_id(ticket, request),
             "tokens": list(tokens),
+            # when each token was delivered: seconds from submission, to
+            # 10 us, one entry a token ([0] is ttft_s; the tokens of one
+            # tick share a stamp). The true gap between tokens and its
+            # tail are differences of this list
+            "token_s": [round(t - submitted_at, 5) for t in token_at],
             "finish_reason": reason,
             # time spent WAITING for a slot (a never-admitted request
             # waited its whole life); ttft additionally includes prefill

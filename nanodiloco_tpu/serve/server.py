@@ -72,7 +72,7 @@ from nanodiloco_tpu.obs.telemetry import (
     handle_profile_request,
     render_exposition,
 )
-from nanodiloco_tpu.obs.tracer import TraceContext
+from nanodiloco_tpu.obs.tracer import TraceContext, trace_span
 from nanodiloco_tpu.serve import kvship
 from nanodiloco_tpu.serve.scheduler import (
     ClassShed,
@@ -290,8 +290,11 @@ class ServeServer:
                 or getattr(self._scheduler, "draining", False)
             ):
                 # a draining scheduler admits nothing: spinning on a
-                # non-empty queue would be a busy loop going nowhere
-                time.sleep(self._idle_sleep_s)
+                # non-empty queue would be a busy loop going nowhere.
+                # The span keeps a profile from reading this wait as
+                # host work between two device programs
+                with trace_span("sched.idle"):
+                    time.sleep(self._idle_sleep_s)
 
     def loop_alive(self) -> bool:
         t = self._loop_thread
@@ -373,6 +376,9 @@ class ServeServer:
                 "ttft_s": result["ttft_s"],
                 "decode_s": result["decode_s"],
                 "total_s": result["total_s"],
+                # seconds from submission at which each returned token
+                # was delivered ([0] is ttft_s): the gaps between tokens
+                "token_s": result.get("token_s", [])[:len(tokens)],
                 # attribution: this request's apportioned share of
                 # dispatch seconds and its KV residency bill — the
                 # per-request cost line, summable against the engine's

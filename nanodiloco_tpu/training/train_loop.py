@@ -29,7 +29,7 @@ import signal
 import sys
 import threading
 import time
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -286,11 +286,12 @@ def _profiler_start(profile_dir: str) -> None:
     from nanodiloco_tpu.obs.telemetry import (
         acquire_profiler_window,
         release_profiler_window,
+        start_profile,
     )
 
     acquire_profiler_window()
     try:
-        jax.profiler.start_trace(profile_dir)
+        start_profile(profile_dir)
     except BaseException:
         release_profiler_window()
         raise
@@ -305,6 +306,28 @@ def _profiler_stop() -> None:
         jax.profiler.stop_trace()
     finally:
         release_profiler_window()
+
+
+def _round_annotated(units, round_of: Callable[[int], int]):
+    """Yield the loop's dispatch units (fused rounds, or single inner
+    steps), each round of them inside one
+    ``jax.profiler.StepTraceAnnotation("round", step_num=<round>)``: a
+    profiler capture groups the host's spans and the device's
+    operations by DiLoCo round. The annotation closes when the next
+    round's first unit is asked for, or when the loop is left."""
+    ann, current = None, None
+    try:
+        for unit in units:
+            if round_of(unit) != current:
+                if ann is not None:
+                    ann.__exit__(None, None, None)
+                current = round_of(unit)
+                ann = jax.profiler.StepTraceAnnotation("round", step_num=current)
+                ann.__enter__()
+            yield unit
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 def _host_dynamics(dyn: dict) -> dict:
@@ -1408,7 +1431,8 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                 min(first_round + 1, last_round) if cfg.profile_dir else None
             )
             try:
-                for rnd in range(first_round, last_round + 1):
+                for rnd in _round_annotated(
+                        range(first_round, last_round + 1), lambda r: r):
                     # fault hook at the round's dispatch boundary: the
                     # whole round is ONE program, so a fault scheduled
                     # for any step it covers fires here, before dispatch
@@ -1421,7 +1445,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         # lowering — host-side, no second XLA compile,
                         # state untouched), BEFORE the dispatch below
                         # donates the state buffers
-                        with trace_span("cost_analysis"):
+                        with trace_span("cost_analysis", layer="train"):
                             log_cost(
                                 dl.async_round_cost_analysis(state, toks, masks)
                                 if async_on
@@ -1439,7 +1463,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         # this span is inner compute + sync as ONE phase;
                         # the JSONL's t_inner/t_sync split comes from the
                         # differenced measure_comm estimate below
-                        with trace_span("inner", round=rnd):
+                        with trace_span("inner", layer="train", round=rnd):
                             t0 = time.perf_counter()
                             boundary_auxes: list[dict] = []
                             if async_on:
@@ -1502,7 +1526,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         # as noise/recompiles wash out); only a single-round run
                         # pays one extra probe round for it.
                         if est_inner_s is None:
-                            with trace_span("comm_probe"):
+                            with trace_span("comm_probe", layer="train"):
                                 est_inner_s = dl.measure_inner_round_time(
                                     state, toks, masks, repeats=1
                                 )
@@ -1539,7 +1563,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         # evaluated snapshot must carry every completed
                         # outer update (and a resume of the finished run
                         # must find no boundary owed)
-                        with trace_span("sync"):
+                        with trace_span("sync", layer="train"):
                             state, flush_aux = dl.async_flush(state)
                             jax.block_until_ready(state.snapshot)
                         boundary_auxes.append(flush_aux)
@@ -1560,7 +1584,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         # forwards need device-resident weights — two
                         # independent fetches would pay the H2D transfer
                         # twice per eval round
-                        with trace_span("eval"):
+                        with trace_span("eval", layer="train"):
                             snap_dev = dl._fetch(state).snapshot
                             if eval_due:
                                 eval_metrics = evaluator(snap_dev, eval_set)
@@ -1667,7 +1691,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         # sync, executed at the top of this program.
                         _log_async_boundary(baux)
                     tps = (real_step - start_step) * tokens_per_step / compute_time
-                    with trace_span("log"):
+                    with trace_span("log", layer="train"):
                         for i in range(cfg.inner_steps):
                             step = real_step - cfg.inner_steps + 1 + i
                             step_loss = float(losses_h[i])
@@ -1753,7 +1777,9 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
             # the start_step%H guard alone could not see)
             state, pending_baux = dl.async_boundary(state)
         round_t0 = time.perf_counter()  # sync-to-sync wall-clock (watchdog)
-        for real_step in ([] if fused else range(start_step + 1, cfg.total_steps + 1)):
+        for real_step in _round_annotated(
+                [] if fused else range(start_step + 1, cfg.total_steps + 1),
+                lambda step: (step - 1) // cfg.inner_steps):
             # fault hook per dispatch unit (one inner step here): a
             # scheduled fault fires at exactly its step
             state = _pump_faults(real_step, state)
@@ -1772,7 +1798,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                 # sync's FLOPs are a rounding error next to H of these);
                 # streaming's fragment-fused step program isn't lowered
                 # standalone — its runs rely on the fused-round capture
-                with trace_span("cost_analysis"):
+                with trace_span("cost_analysis", layer="train"):
                     log_cost(
                         dl.inner_cost_analysis(
                             state, dl.feed(tokens), dl.feed(mask)
@@ -1784,7 +1810,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                 # fragment launches/applies are fused into the jitted step and
                 # overlap the inner compute — there is no separate sync phase
                 # to time (that's the point, arXiv:2501.18512).
-                with trace_span("inner"):
+                with trace_span("inner", layer="train"):
                     state, loss = dl.step(
                         state, dl.feed(tokens), dl.feed(mask), real_step
                     )
@@ -1806,7 +1832,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                     ) % cfg.checkpoint_every == 0:
                         _guarded_save(real_step, state)
             else:
-                with trace_span("inner"):
+                with trace_span("inner", layer="train"):
                     state, loss = dl.inner_step(state, dl.feed(tokens), dl.feed(mask))
                     if cfg.quarantine_nonfinite:
                         # accumulate ON DEVICE ([W] stays diloco-sharded; a
@@ -1842,7 +1868,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         pending_baux = None
                     step_dyn = None
                     t_b0 = time.perf_counter()
-                    with trace_span("sync"), sync_timer:
+                    with trace_span("sync", layer="train"), sync_timer:
                         # the explicit fence of the async contract sits
                         # at the APPLY: wait (only) for the merge
                         # launched outer_delay rounds ago — the residual,
@@ -1889,7 +1915,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                             cfg.num_workers - eff.sum()
                         )
                     t_b0 = time.perf_counter()
-                    with trace_span("sync"), sync_timer:
+                    with trace_span("sync", layer="train"), sync_timer:
                         if dynamics_on:
                             state, step_dyn = dl.outer_step(state, round_ok)
                         else:
@@ -1921,7 +1947,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                 # H2D transfer, not two), gated on a consumer actually
                 # running THIS round (ADVICE r5 medium) and dropped after so
                 # no device snapshot copy survives into the next dispatch
-                with trace_span("eval"):
+                with trace_span("eval", layer="train"):
                     snap_dev = dl._fetch(state).snapshot
                     if eval_due:
                         eval_metrics = evaluator(snap_dev, eval_set)
@@ -2029,7 +2055,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
             # not as an unattributed gap (its seconds land in the NEXT
             # round's t_log, as in fused mode — the span is still open
             # when phase_totals snapshots above)
-            with trace_span("log"):
+            with trace_span("log", layer="train"):
                 logger.log(
                     {
                         **eval_metrics,

@@ -231,6 +231,15 @@ SPAN_NAME_ALLOWLIST = {
     # training round phases (training/, parallel/)
     "outer_sync", "ckpt", "data", "cost_analysis", "inner",
     "comm_probe", "sync", "eval", "log",
+    # the serving thread's tick, step by step, and the dispatch entries
+    # of Diloco (PR 24: trace_span is also a profiler annotation; README
+    # "What a capture shows", PERF.md section 3)
+    "sched.tick", "sched.control", "sched.expire", "sched.admit",
+    "sched.prefill", "sched.deliver", "sched.retire", "sched.idle",
+    "engine.start_prefill", "engine.keys", "engine.stage_chunk",
+    "engine.prefill_chunk", "engine.draft", "engine.stage",
+    "engine.decode_dispatch", "engine.fetch_tokens", "engine.advance",
+    "diloco.round", "diloco.outer", "diloco.inner_round",
     # the synthetic root stitch_trace mints for request_id-joined shards
     "trace",
 }

@@ -678,7 +678,7 @@ def test_profiler_window_released_when_start_trace_fails(monkeypatch, tmp_path):
     from nanodiloco_tpu.obs import telemetry as tmod
     from nanodiloco_tpu.training import train_loop as tl
 
-    def boom(_dir):
+    def boom(_dir, **_options):
         raise RuntimeError("profiler broken")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
