@@ -155,37 +155,91 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def causal_mask(s: int, valid: jax.Array | None = None) -> jax.Array:
-    """Additive [B|1, 1, S, S] float32 mask: causal, optionally restricted to
+def causal_mask(s: int, valid: jax.Array | None = None, start: int = 0) -> jax.Array:
+    """Additive [B|1, 1, S - start, S] float32 mask for query rows
+    ``start..S`` over keys ``0..S``: causal, optionally restricted to
     ``valid`` [B, S] key positions (1 = real token)."""
-    qi = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-    ki = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-    ok = (qi >= ki)[None]                      # [1, S, S]
+    qi = jax.lax.broadcasted_iota(jnp.int32, (s - start, s), 0) + start
+    ki = jax.lax.broadcasted_iota(jnp.int32, (s - start, s), 1)
+    ok = (qi >= ki)[None]                      # [1, S - start, S]
     if valid is not None:
-        ok = ok & (valid[:, None, :] > 0)      # [B, S, S]
+        ok = ok & (valid[:, None, :] > 0)      # [B, S - start, S]
     return jnp.where(ok, 0.0, MASK_VALUE)[:, None]
 
 
-def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array | None) -> jax.Array:
+# Causal dense attention runs over blocks of query rows, each against the
+# keys at or before its last row only: the score area falls from S*S to
+# (n + 1) / 2n of it over n blocks, with every row's softmax still whole.
+# DENSE_BLOCK_Q rows a block, from one chip sweep of 128, 256 and 512 at
+# S 2048 on a v5e (PERF.md, PR 25): 128 runs the step 5% faster still,
+# but every block is unrolled into the program, and tracing and lowering
+# sixteen of them add 10 s to a process's start, eight add 5 s. For the
+# same reason a sequence longer than DENSE_MAX_BLOCKS blocks takes
+# longer ones: the rule is a function of S alone.
+DENSE_BLOCK_Q = 256
+DENSE_MAX_BLOCKS = 16
+
+
+def dense_block_rows(s: int, bq: int | None = None) -> int:
+    """Rows of a query block of ``dense_attention`` at sequence length
+    ``s``; ``s`` itself (one block: full scores, full mask) where ``s``
+    is no longer than a block or is not a whole number of them."""
+    if bq is None:
+        bq = max(DENSE_BLOCK_Q, -(-s // DENSE_MAX_BLOCKS))
+    return bq if s > bq and s % bq == 0 else s
+
+
+def dense_score_share(s: int, bq: int | None = None) -> float:
+    """Score entries ``dense_attention`` computes under a causal mask,
+    over ``s * s``: 0.5625 at 2048 in blocks of 256, 1.0 in one block."""
+    n = s // dense_block_rows(s, bq)
+    return (n + 1) / (2 * n)
+
+
+def dense_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array | None = None,
+    *, bq: int | None = None,
+) -> jax.Array:
     """Reference attention: q,k,v [B, S, H, hd] (k/v already GQA-expanded),
-    mask [B?, 1, S, S] additive or None -> causal. Softmax in float32."""
+    softmax in float32. ``mask`` is None (causal), a [B, S] 0/1 validity
+    mask (causal over the valid keys) or an explicit additive [B?, 1, S, S]
+    mask, taken as given.
+
+    Causal attention runs in query blocks of ``dense_block_rows(S, bq)``
+    rows (``bq`` is for tests; the program's size follows from S): block
+    ``i`` meets keys ``0..(i+1)*rows`` alone, and every row still softmaxes
+    over all of its allowed keys at once, so the result is the one-block
+    form's up to float32 reassociation. An explicit mask may allow any
+    key, so it runs in one block. A row with no valid key at all (left
+    padding) softmaxes to uniform over its block's keys, not over S:
+    finite either way, and loss-masked."""
     b, s, h, hd = q.shape
     scale = 1.0 / math.sqrt(hd)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if mask is None:
-        mask = causal_mask(s)
-    scores = scores + mask.astype(jnp.float32)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    explicit = mask is not None and mask.ndim == 4
+    rows = s if explicit else dense_block_rows(s, bq)
+    out = []
+    for start in range(0, s, rows):
+        end = start + rows
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q[:, start:end], k[:, :end]
+        ).astype(jnp.float32) * scale
+        block = mask if explicit else causal_mask(
+            end, None if mask is None else mask[:, :end], start
+        )
+        scores = scores + block.astype(jnp.float32)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out.append(jnp.einsum("bhqk,bkhd->bqhd", probs, v[:, :end]))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
 @jax.named_scope("attention")
-def _attention(cfg: LlamaConfig, q, k, v, mask, axis_name: str | None):
+def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None):
     """Dispatch on cfg.attention_impl. Ring attention requires being inside
-    a shard_map with the sequence axis bound to ``axis_name``; flash ignores
-    padding masks (packed fixed-length sequences don't need one). flash and
+    a shard_map with the sequence axis bound to ``axis_name``; flash and
+    ring ignore ``valid``, the [B, S] padding mask (packed fixed-length
+    sequences don't need one), which dense attention honors. flash and
     ring take k/v at Hkv heads (GQA un-expanded); dense gets them
-    pre-expanded by the caller."""
+    pre-expanded here."""
     if cfg.attention_impl not in ("dense", "flash", "ring"):
         raise ValueError(f"unknown attention_impl: {cfg.attention_impl!r}")
     if cfg.attention_impl == "flash":
@@ -202,7 +256,7 @@ def _attention(cfg: LlamaConfig, q, k, v, mask, axis_name: str | None):
         g = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
-    return dense_attention(q, k, v, mask)
+    return dense_attention(q, k, v, valid if cfg.attention_impl == "dense" else None)
 
 
 # ---------------------------------------------------------------------------
@@ -210,13 +264,14 @@ def _attention(cfg: LlamaConfig, q, k, v, mask, axis_name: str | None):
 # ---------------------------------------------------------------------------
 
 def _decoder_layer(
-    cfg: LlamaConfig, x, layer: Params, cos, sin, mask, sp_axis, valid=None,
+    cfg: LlamaConfig, x, layer: Params, cos, sin, attn_valid, sp_axis, valid=None,
     with_stats: bool = False,
 ):
     """Returns (x, aux_loss) — aux is the router load-balance term for
-    MoE layers, 0.0 for dense. ``valid`` [B, S] marks real tokens so MoE
-    routing never spends expert capacity on padding. ``with_stats`` adds
-    the router observability vector (see moe_mlp)."""
+    MoE layers, 0.0 for dense. ``attn_valid`` [B, S] marks the keys dense
+    attention may see (None: causal alone); ``valid`` [B, S] marks real
+    tokens so MoE routing never spends expert capacity on padding.
+    ``with_stats`` adds the router observability vector (see moe_mlp)."""
     b, s, d = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     cdt = x.dtype
@@ -231,7 +286,7 @@ def _decoder_layer(
     # GQA K/V stay at Hkv heads here; flash/ring are GQA-native (K/V are
     # never expanded in HBM/ICI — the bandwidth GQA exists to save) and
     # _attention expands only for its dense paths.
-    attn = _attention(cfg, q, k, v, mask, sp_axis)
+    attn = _attention(cfg, q, k, v, attn_valid, sp_axis)
     with jax.named_scope("attn_proj"):
         x = x + attn.reshape(b, s, nh * hd) @ layer["wo"].astype(cdt)
 
@@ -309,16 +364,14 @@ def forward(
     # flash and ring are PACKED-sequence kernels: attn_mask only weights
     # the loss, it never restricts attention (dense honors it for the
     # reference's padded-document layout, ref nanodiloco/main.py:79-88).
-    mask = None
-    if attn_mask is not None and cfg.attention_impl == "dense":
-        with jax.named_scope("attention"):
-            mask = causal_mask(s, valid=attn_mask)  # [B, 1, S, S]
+    # Dense attention builds its mask from attn_mask inside the layer,
+    # where it fuses into the scores: no [B, 1, S, S] array crosses the scan.
 
     # Bind all non-array arguments (cfg, sp_axis) BEFORE jax.checkpoint so
     # only JAX types flow through the remat boundary.
-    def layer_fn(x, layer, cos, sin, mask, valid):
+    def layer_fn(x, layer, cos, sin, valid):
         return _decoder_layer(
-            cfg, x, layer, cos, sin, mask, sp_axis, valid,
+            cfg, x, layer, cos, sin, valid, sp_axis, valid,
             with_stats=collect_stats,
         )
 
@@ -326,7 +379,7 @@ def forward(
         layer_fn = jax.checkpoint(layer_fn, policy=checkpoint_policy(cfg))
 
     def scan_body(carry, layer):
-        out = layer_fn(carry, layer, cos, sin, mask, attn_mask)
+        out = layer_fn(carry, layer, cos, sin, attn_mask)
         return out[0], out[1:]
 
     # the scan's own work (a layer's weights sliced out of the stack,

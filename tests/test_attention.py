@@ -7,7 +7,12 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from nanodiloco_tpu.models.llama import dense_attention
+from nanodiloco_tpu.models.llama import (
+    causal_mask,
+    dense_attention,
+    dense_block_rows,
+    dense_score_share,
+)
 from nanodiloco_tpu.ops.flash_attention import flash_attention
 from nanodiloco_tpu.ops.ring_attention import ring_attention
 
@@ -54,6 +59,126 @@ def test_flash_gradients_match_dense():
         gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# dense attention in causal query blocks: block i of bq rows meets keys
+# 0..(i+1)*bq only; one block (S <= bq, or S not a multiple) is the
+# full-score arithmetic
+# ---------------------------------------------------------------------------
+
+def _right_padded(b, s):
+    """[B, S] validity with right padding: row 0 ends a third early, the
+    last row is whole."""
+    valid = np.ones((b, s), np.int32)
+    valid[0, s - s // 3:] = 0
+    return jnp.asarray(valid)
+
+
+def _full_scores_attention(q, k, v, valid=None):
+    """Attention over all S x S scores, written out: the formula
+    ``dense_attention`` had before it ran in blocks."""
+    s, hd = q.shape[1], q.shape[3]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / np.sqrt(hd)
+    probs = jax.nn.softmax(scores + causal_mask(s, valid), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _score_entries(fn, q, k, v):
+    """Score entries a call computes over B*H, read from the jaxpr: the
+    output sizes [B, H, rows, keys] of its matmuls that contract over the
+    head size (the callers keep that off any block's key count, which
+    the other matmul contracts over)."""
+    hd = q.shape[3]
+    n = 0
+    for e in jax.make_jaxpr(fn)(q, k, v).eqns:
+        if e.primitive.name == "dot_general":
+            (contract, _), _ = e.params["dimension_numbers"]
+            if e.invars[0].aval.shape[contract[0]] == hd:
+                n += e.outvars[0].aval.shape[2] * e.outvars[0].aval.shape[3]
+    return n
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["causal", "right_padded"])
+@pytest.mark.parametrize("s", [64, 128])
+@pytest.mark.parametrize("bq", [16, 32])
+def test_blocked_dense_matches_one_block(bq, s, padded):
+    """Forward and under grad for q, k and v; with right padding every
+    row keeps a valid key (its first), so every row is compared."""
+    q, k, v = qkv(jax.random.key(30), s=s, hd=8)
+    valid = _right_padded(2, s) if padded else None
+    assert dense_block_rows(s, bq) == bq and dense_block_rows(s, s) == s
+
+    def blocked(q, k, v):
+        return dense_attention(q, k, v, valid, bq=bq)
+
+    def one_block(q, k, v):
+        return dense_attention(q, k, v, valid, bq=s)
+
+    # the share is of what the call computes, not a number beside it
+    assert _score_entries(blocked, q, k, v) == dense_score_share(s, bq) * s * s
+    assert _score_entries(one_block, q, k, v) == s * s
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            np.asarray(blocked(q, k, v)), np.asarray(one_block(q, k, v)),
+            rtol=0, atol=2e-5,
+        )
+        gb = jax.grad(lambda *a: jnp.sum(blocked(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+        go = jax.grad(lambda *a: jnp.sum(one_block(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gb, go):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-4)
+
+
+def test_blocked_dense_left_padding_is_finite():
+    """Rows before a sequence's first valid key softmax over MASK_VALUE
+    alone: uniform over their block's keys, finite, forward and under
+    grad; rows with a valid key equal the one-block form's."""
+    q, k, v = qkv(jax.random.key(31), s=64)
+    valid = jnp.ones((2, 64), jnp.int32).at[0, :24].set(0)
+    with jax.default_matmul_precision("highest"):
+        out = dense_attention(q, k, v, valid, bq=16)
+        ref = dense_attention(q, k, v, valid, bq=64)
+        g = jax.grad(
+            lambda *a: jnp.sum(dense_attention(*a, valid, bq=16) ** 2), argnums=(0, 1, 2)
+        )(q, k, v)
+    assert np.isfinite(np.asarray(out)).all()
+    assert all(np.isfinite(np.asarray(x)).all() for x in g)
+    has_key = np.asarray(jnp.cumsum(valid, axis=1) > 0)
+    np.testing.assert_allclose(
+        np.asarray(out)[has_key], np.asarray(ref)[has_key], rtol=0, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "s,bq", [pytest.param(72, 16, id="not_a_multiple"), pytest.param(32, 32, id="one_block_long"),
+             pytest.param(64, None, id="under_the_constant")]
+)
+def test_dense_one_block_is_the_full_score_formula(s, bq):
+    """Where the sequence is no longer than a block or not a whole number
+    of them, one block runs: all S x S scores, and the arithmetic of the
+    written-out formula, with and without a validity mask."""
+    q, k, v = qkv(jax.random.key(32), s=s, hd=8)
+    assert dense_block_rows(s, bq) == s and dense_score_share(s, bq) == 1.0
+    assert _score_entries(lambda *a: dense_attention(*a, None, bq=bq), q, k, v) == s * s
+    for valid in (None, _right_padded(2, s)):
+        with jax.default_matmul_precision("highest"):
+            out = dense_attention(q, k, v, valid, bq=bq)
+            ref = _full_scores_attention(q, k, v, valid)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=0, atol=1e-6)
+
+
+def test_dense_block_rule_is_a_function_of_the_sequence():
+    """The program's block follows from S alone: the constant up to 16
+    blocks, longer blocks beyond (compile time grows with the count)."""
+    from nanodiloco_tpu.models.llama import DENSE_BLOCK_Q, DENSE_MAX_BLOCKS
+
+    assert [dense_score_share(2048, b) for b in (128, 256, 512)] == [0.53125, 0.5625, 0.625]
+    for s in (512, 1024, 2048, 4096, 8192, 16384):
+        rows = dense_block_rows(s)
+        assert rows >= DENSE_BLOCK_Q and s % rows == 0
+        assert s // rows == min(s // DENSE_BLOCK_Q, DENSE_MAX_BLOCKS)
+    assert dense_block_rows(DENSE_BLOCK_Q) == DENSE_BLOCK_Q  # one block
+    assert dense_block_rows(3 * DENSE_BLOCK_Q + 8) == 3 * DENSE_BLOCK_Q + 8
 
 
 @pytest.mark.parametrize("sp", [2, 4])
@@ -351,6 +476,59 @@ def test_gqa_full_model_flash_matches_dense():
     with jax.default_matmul_precision("highest"):
         out_f = forward(params, tokens, cfg_f)
         out_d = forward(params, tokens, cfg_d)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
+                               rtol=2e-5, atol=2e-5)
+
+
+_GQA_MODEL = dict(vocab_size=64, hidden_size=64, num_attention_heads=8,
+                  num_key_value_heads=2, num_hidden_layers=2, intermediate_size=128)
+
+
+def test_gqa_full_model_blocked_dense_matches_one_block(monkeypatch):
+    """causal_lm_loss and its gradient under a padded loss_mask, dense
+    attention in four blocks (of 16 rows: what the program does from two
+    blocks' tokens on, at a test's size) against one."""
+    import nanodiloco_tpu.models.llama as llama
+    from nanodiloco_tpu.models import LlamaConfig, causal_lm_loss, init_params
+
+    cfg = LlamaConfig(**_GQA_MODEL)
+    params = init_params(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (2, 64), 0, 64)
+    mask = _right_padded(2, 64)
+
+    def loss_and_grad():
+        return jax.value_and_grad(
+            lambda p: causal_lm_loss(p, tokens, cfg, loss_mask=mask)[0]
+        )(params)
+
+    with jax.default_matmul_precision("highest"):
+        assert llama.dense_score_share(64) == 1.0
+        loss_one, grad_one = loss_and_grad()
+        monkeypatch.setattr(llama, "DENSE_BLOCK_Q", 16)
+        assert llama.dense_score_share(64) == 0.625
+        loss_four, grad_four = loss_and_grad()
+    np.testing.assert_allclose(float(loss_four), float(loss_one), rtol=0, atol=2e-5)
+    for a, b in zip(jax.tree.leaves(grad_four), jax.tree.leaves(grad_one)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-4)
+
+
+def test_gqa_full_model_flash_matches_blocked_dense(monkeypatch):
+    """test_gqa_full_model_flash_matches_dense above one block: the flash
+    scan against dense attention in four blocks, on a mask of ones (the
+    kernels are packed-sequence kernels; dense honors the mask)."""
+    import nanodiloco_tpu.models.llama as llama
+    from nanodiloco_tpu.models import LlamaConfig, forward, init_params
+
+    monkeypatch.setattr(llama, "DENSE_BLOCK_Q", 16)
+    assert llama.dense_score_share(64) == 0.625
+    cfg_f = LlamaConfig(**_GQA_MODEL, attention_impl="flash")
+    cfg_d = LlamaConfig(**_GQA_MODEL, attention_impl="dense")
+    params = init_params(jax.random.key(0), cfg_f)
+    tokens = jax.random.randint(jax.random.key(1), (2, 64), 0, 64)
+    ones = jnp.ones((2, 64), jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        out_f = forward(params, tokens, cfg_f, attn_mask=ones)
+        out_d = forward(params, tokens, cfg_d, attn_mask=ones)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
                                rtol=2e-5, atol=2e-5)
 
