@@ -15,6 +15,9 @@ import json
 from typing import Any
 
 
+LAYER_KINDS = ("sliding_attention", "full_attention")
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -76,10 +79,80 @@ class LlamaConfig:
     # permutation is sequence-global, and sharding experts over ep would
     # need the all-to-all a megablocks-style kernel provides.
     moe_dispatch: str = "dense"
+    # -- mixed layer stacks (window and full attention layers, leading
+    # dense layers before sparse ones, a bias-corrected gate, a shared
+    # expert, the chip's share of the experts). Any of these set makes
+    # the configuration ``mixed``: models/llama.py then runs its layers
+    # as leading dense layers and scanned periods, models/generate.py
+    # keeps two kinds of cache, and the paths that do not carry them
+    # refuse the configuration by name. All default to the dense
+    # decoder above, whose programs they leave untouched.
+    # a head size given apart from hidden_size / num_attention_heads
+    # (``head_dim`` in an HF config); None derives it
+    explicit_head_dim: int | None = None
+    # one of LAYER_KINDS for each layer; None = every layer full
+    layer_types: tuple[str, ...] | None = None
+    # keys a sliding layer's row i sees: i - window < j <= i
+    sliding_window: int | None = None
+    # RMSNorm over each head's values of q and k, before RoPE
+    qk_norm: bool = False
+    # "all": rotary embedding in every layer; "sliding": in sliding
+    # layers only (full layers see no position but the causal order)
+    rope_layers: str = "all"
+    # layers [0, first_k_dense_replace) keep the dense SwiGLU of width
+    # ``intermediate_size``; the rest are sparse, each expert of width
+    # ``moe_intermediate_size`` (None: ``intermediate_size``)
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int | None = None
+    # SwiGLUs of the expert width that every token passes, summed with
+    # the routed experts' output
+    num_shared_experts: int = 0
+    # "softmax" | "sigmoid" router scores; with "sigmoid" each sparse
+    # layer carries a selection bias (``router_bias`` [E] float32) that
+    # is added to the scores for the top-k choice and not to the weights
+    scoring_func: str = "softmax"
+    # weights of the chosen experts normalised to sum to 1 (over ALL k
+    # chosen, held on this chip or not), then scaled
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the chip's share of the routed experts as (first, count): the
+    # router keeps its ``num_experts`` outputs and its k, the layer
+    # holds weights for, and adds the outputs of, experts
+    # first..first+count-1 alone. None = all of them
+    experts_held: tuple[int, int] | None = None
 
     @property
     def head_dim(self) -> int:
+        if self.explicit_head_dim is not None:
+            return self.explicit_head_dim
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def mixed(self) -> bool:
+        """Whether any mechanism of the mixed layer stack is set."""
+        return bool(
+            (self.layer_types is not None and "sliding_attention" in self.layer_types)
+            or self.qk_norm or self.rope_layers != "all"
+            or self.first_k_dense_replace or self.num_shared_experts
+            or self.scoring_func != "softmax" or not self.norm_topk_prob
+            or self.routed_scaling_factor != 1.0
+            or self.experts_held is not None
+            or self.moe_intermediate_size is not None
+        )
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this chip holds."""
+        return self.experts_held or (0, self.num_experts)
+
+    def layer_kind(self, i: int) -> tuple[str, bool]:
+        """(attention kind, sparse feed-forward?) of layer ``i``."""
+        kind = self.layer_types[i] if self.layer_types else "full_attention"
+        return kind, bool(self.num_experts) and i >= self.first_k_dense_replace
 
     @property
     def kv_heads(self) -> int:
@@ -88,8 +161,10 @@ class LlamaConfig:
         return self.num_key_value_heads
 
     def __post_init__(self) -> None:
-        if self.hidden_size % self.num_attention_heads:
+        if self.explicit_head_dim is None and self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size must divide evenly by num_attention_heads")
+        if self.mixed:
+            self._check_mixed()
         if self.num_key_value_heads is not None and self.num_key_value_heads < 1:
             raise ValueError("num_key_value_heads must be >= 1 (or None for MHA)")
         if self.num_attention_heads % self.kv_heads:
@@ -123,16 +198,75 @@ class LlamaConfig:
                 "group sizes) only exists for data-dependent group sizes"
             )
 
+    def _check_mixed(self) -> None:
+        """A mixed configuration's own constraints, and the paths that
+        do not carry it: each refusal names the feature."""
+        n = self.num_hidden_layers
+        if self.layer_types is not None:
+            if len(self.layer_types) != n:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_hidden_layers is {n}")
+            bad = set(self.layer_types) - set(LAYER_KINDS)
+            if bad:
+                raise ValueError(f"layer_types must be of {LAYER_KINDS}; got {sorted(bad)}")
+            if "sliding_attention" in self.layer_types and not self.sliding_window:
+                raise ValueError("sliding_attention layers need sliding_window >= 1")
+        if self.rope_layers not in ("all", "sliding"):
+            raise ValueError(f"rope_layers must be 'all' or 'sliding'; got {self.rope_layers!r}")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"scoring_func must be 'softmax' or 'sigmoid'; got {self.scoring_func!r}")
+        if not 0 <= self.first_k_dense_replace <= n:
+            raise ValueError("first_k_dense_replace must lie in [0, num_hidden_layers]")
+        if self.attention_impl != "dense":
+            raise ValueError(
+                f"attention_impl={self.attention_impl!r} does not carry a mixed layer "
+                "stack (sliding-window layers, per-head q/k norms, layers without "
+                "RoPE): the flash and ring kernels know one causal mask; use 'dense'")
+        if not self.num_experts:
+            if (self.num_shared_experts or self.experts_held is not None
+                    or self.first_k_dense_replace):
+                raise ValueError(
+                    "shared experts, experts_held and first_k_dense_replace "
+                    "need num_experts > 0")
+            return
+        if self.router_type != "tokens_choose":
+            raise ValueError(
+                "router_type='experts_choose' does not carry the sigmoid / "
+                "bias-corrected gate, shared experts or a held share of the "
+                "experts: use 'tokens_choose'")
+        if self.moe_dispatch != "ragged":
+            raise ValueError(
+                "moe_dispatch='dense' ([T, E, C] capacity dispatch) does not "
+                "carry the sigmoid / bias-corrected gate, shared experts or a "
+                "held share of the experts: use moe_dispatch='ragged' (no "
+                "capacity, no dropped token)")
+        first, count = self.held_experts
+        if not (0 <= first and 1 <= count and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held {self.experts_held} must lie inside the "
+                f"router's {self.num_experts} experts")
+
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "LlamaConfig":
         """Build from an HF-style config dict, ignoring unknown keys.
 
         The reference feeds its JSON straight into ``LlamaConfig(**cfg)``
         (ref nanodiloco/main.py:97); we accept the same files, including
-        keys we don't model (``architectures``, ``use_cache``).
+        keys we don't model (``architectures``, ``use_cache``). HF's
+        ``head_dim`` is ``explicit_head_dim`` here, ``rope_theta`` may
+        stand inside ``rope_parameters``, and lists become tuples (the
+        configuration is a hashable jit-static argument).
         """
         names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
+        d = dict(d)
+        if "head_dim" in d and "explicit_head_dim" not in d:
+            d["explicit_head_dim"] = d["head_dim"]
+        if "rope_theta" not in d and "rope_theta" in (d.get("rope_parameters") or {}):
+            d["rope_theta"] = float(d["rope_parameters"]["rope_theta"])
+        return cls(**{k: tuple(v) if isinstance(v, list) else v
+                      for k, v in d.items() if k in names})
 
     @classmethod
     def from_json(cls, path: str) -> "LlamaConfig":
@@ -146,17 +280,22 @@ class LlamaConfig:
         """Exact parameter count (embedding + layers + final norm + head)."""
         d, f, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_hidden_layers
         hd, nh, nkv = self.head_dim, self.num_attention_heads, self.kv_heads
+        attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 2 * d  # q, k, v, o, norms
+        if self.qk_norm:
+            attn += 2 * hd
         if self.num_experts:
-            mlp = d * self.num_experts + 3 * self.num_experts * d * f  # router + E experts
+            fe = self.expert_width
+            held = self.held_experts[1]
+            # router (+ selection bias), the experts HELD, the shared ones
+            sparse = d * self.num_experts + 3 * (held + self.num_shared_experts) * d * fe
+            if self.scoring_func == "sigmoid":
+                sparse += self.num_experts
+            k = self.first_k_dense_replace
+            mlp_all = k * 3 * d * f + (l - k) * sparse
         else:
-            mlp = 3 * d * f  # gate, up, down
-        per_layer = (
-            d * nh * hd + 2 * d * nkv * hd + nh * hd * d  # q, k, v, o
-            + mlp
-            + 2 * d      # two rmsnorm scales
-        )
+            mlp_all = l * 3 * d * f  # gate, up, down
         head = 0 if self.tie_word_embeddings else d * v
-        return v * d + l * per_layer + d + head
+        return v * d + l * attn + mlp_all + d + head
 
 
 # The reference's inline default config (ref nanodiloco/main.py:16-27).

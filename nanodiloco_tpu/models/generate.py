@@ -43,20 +43,109 @@ from nanodiloco_tpu.models.llama import (
     MASK_VALUE,
     Params,
     apply_rope,
+    layer_plan,
+    mixed_mlp_block,
     mlp_block,
+    qkv_proj,
     rms_norm,
     rope_tables,
+    run_layers,
 )
 from nanodiloco_tpu.ops.online_softmax import block_update, finalize_grouped
 
 
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_length: int) -> dict:
-    """Preallocated cache: k/v [L, B, S_max, Hkv, hd] in compute dtype."""
+    """Preallocated cache: k/v [L, B, S_max, Hkv, hd] in compute dtype.
+    A mixed configuration's cache follows its ``layer_plan``
+    (``_plan_cache``): one k/v [B, S_max, Hkv, hd] a layer, every layer
+    at full length here (the window is in the mask; the serve engine's
+    rings are ``init_mixed_serve_cache``)."""
+    cdt = jnp.dtype(cfg.dtype)
+    if cfg.mixed:
+        shape = (batch, max_length, cfg.kv_heads, cfg.head_dim)
+        return _plan_cache(cfg, lambda kind: {"k": jnp.zeros(shape, cdt),
+                                              "v": jnp.zeros(shape, cdt)})
     shape = (
         cfg.num_hidden_layers, batch, max_length, cfg.kv_heads, cfg.head_dim,
     )
-    cdt = jnp.dtype(cfg.dtype)
     return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
+
+
+def _plan_cache(cfg: LlamaConfig, entry) -> dict:
+    """The cache ``run_layers`` takes: ``entry(attention kind)`` for each
+    leading layer, and for each layer of the period stacked over the
+    periods."""
+    plan = layer_plan(cfg)
+    lead, period = _plan_kinds(cfg)
+    return {
+        "lead": tuple(entry(kind) for kind in lead),
+        "period": tuple(
+            jax.tree.map(lambda a: jnp.broadcast_to(a, (plan.periods,) + a.shape),
+                         entry(kind)) for kind in period),
+    }
+
+
+def _plan_kinds(cfg: LlamaConfig) -> tuple[list, list]:
+    """Attention kinds of the leading layers and of one period's layers:
+    the order of a plan cache's entries."""
+    plan = layer_plan(cfg)
+    kinds = [kind for kind, _ in plan.kinds]
+    n = plan.period if plan.periods else 0
+    return kinds[:plan.lead], kinds[plan.lead:plan.lead + n]
+
+
+def _mixed_layer(cfg: LlamaConfig, x, layer, kind, rope, attend, token_valid):
+    """One cached layer of a mixed configuration: the projections
+    (``qkv_proj``), ``attend(q, k, v) -> (attention [B, T, H * hd], the
+    layer's updated cache entry)``, the output projection and the dense
+    or sparse feed-forward. Returns ``run_layers``' (x, entry, counters,
+    chosen experts)."""
+    cdt = x.dtype
+    h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn_proj"):
+        q, k, v = qkv_proj(cfg, h, layer, rope, kind[0])
+    attn, entry = attend(q, k, v)
+    with jax.named_scope("attn_proj"):
+        x = x + attn @ layer["wo"].astype(cdt)
+    x, counters, chosen = mixed_mlp_block(cfg, x, layer, token_valid)
+    return x, entry, counters, chosen
+
+
+def _cached_block_mixed(params, cfg: LlamaConfig, tokens, cache, pos, key_valid,
+                        token_valid, last_index):
+    """``_cached_block`` for a mixed configuration: the same contract
+    over ``init_kv_cache``'s per-layer cache, dense scores over the
+    whole cache with the sliding layers' window in their mask."""
+    cdt = jnp.dtype(cfg.dtype)
+    b, t = tokens.shape
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]
+    with jax.named_scope("attn_proj"):
+        cos, sin = rope_tables(cfg, t, offset=pos)
+    s_max = key_valid.shape[1]
+    qi = pos + jnp.arange(t)
+    with jax.named_scope("attention"):
+        ki = jnp.arange(s_max)[None, None, :]
+        ok = (ki <= qi[None, :, None]) & (key_valid[:, None, :] > 0)
+        near = ok & (qi[None, :, None] - ki < (cfg.sliding_window or 0))
+        masks = {"full_attention": jnp.where(ok, 0.0, MASK_VALUE)[:, None],
+                 "sliding_attention": jnp.where(near, 0.0, MASK_VALUE)[:, None]}
+
+    def body(x, layer, kind, c):
+        def attend(q, k, v):
+            with jax.named_scope("kv_write"):
+                ck = jax.lax.dynamic_update_slice(c["k"], k, (0, pos, 0, 0))
+                cv = jax.lax.dynamic_update_slice(c["v"], v, (0, pos, 0, 0))
+            return _slot_attention(q, ck, cv, masks[kind[0]]), {"k": ck, "v": cv}
+
+        return _mixed_layer(cfg, x, layer, kind, lambda a: apply_rope(a, cos, sin),
+                            attend, token_valid)
+
+    x, cache, _, _ = run_layers(cfg, params, x, body, cache)
+    xl = x[:, -1] if last_index is None else \
+        jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]
+    x = rms_norm(xl, params["final_norm"], cfg.rms_norm_eps)
+    return _head_logits(params, x, cdt), cache
 
 
 def _cached_block(
@@ -89,6 +178,13 @@ def _cached_block(
     at the long contexts the training side supports (VERDICT r2 weak #5)
     — and the block loop's upper bound is the live prefix ``pos + T``,
     so early decode steps never touch the untouched cache tail."""
+    if cfg.mixed:
+        if block:
+            raise ValueError(
+                "blockwise (online-softmax) cached attention does not carry a "
+                "mixed layer stack's sliding-window layers; use decode_block=0")
+        return _cached_block_mixed(params, cfg, tokens, cache, pos, key_valid,
+                                   token_valid, last_index)
     cdt = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
     s_max = cache["k"].shape[2]
@@ -383,7 +479,8 @@ def generate(
     if prompt_valid is None:
         prompt_valid = jnp.ones((b, p), jnp.int32)
     if decode_block is None:
-        decode_block = _auto_decode_block(p + max_new_tokens)
+        # a mixed layer stack's window layers run dense scores only
+        decode_block = 0 if cfg.mixed else _auto_decode_block(p + max_new_tokens)
     elif decode_block < 0:
         raise ValueError(f"decode_block must be >= 0; got {decode_block}")
     fn = _build_generate(
@@ -1228,5 +1325,181 @@ def verify_slots_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
         )
         counts = _accept_prefix(tokens, sampled, draft_len)
         return sampled, counts, pool
+
+    return jax.jit(run, donate_argnums=_serve_donate())
+
+
+# ---------------------------------------------------------------------------
+# Two kinds of cache side by side (a mixed configuration's serve programs)
+#
+# Full-attention layers keep the paged pool and the block tables, one
+# pool ``[num_blocks, block_size, Hkv, hd]`` a layer: every token of a
+# request stays. Sliding-window layers hold a RING a slot,
+# ``[slots, R, Hkv, hd]`` with ``R = window + chunk_size`` rows that no
+# block table addresses: position ``p`` lives in row ``p mod R``. Both
+# programs write first and attend after. Once a call has written up to
+# position ``last`` of a slot, row ``r`` of its ring holds position
+# ``last - ((last - r) mod R)``: the mask is rebuilt from the slot's
+# position alone, so a ring is never cleared (a row of an earlier
+# request reads as a negative or too old position) and the rows a
+# right-padded final chunk writes past the prompt are older than any
+# window by the time a query could meet them (``R`` is a chunk wider
+# than the window). A chunk of C rows so attends to the ring's last
+# ``window - 1`` rows and to itself.
+#
+# The cache is ``run_layers``' ``{"lead", "period"}`` (llama.py): one
+# entry a layer, of its own kind and shape; the block tables are the
+# full layers' alone and address every full layer's pool alike. With
+# one period the scan over periods has one trip and no pool moves
+# through a loop's operands.
+# ---------------------------------------------------------------------------
+
+
+def init_mixed_serve_cache(cfg: LlamaConfig, slots: int, ring_rows: int,
+                           num_blocks: int, block_size: int) -> dict:
+    """The serve cache of a mixed configuration: a pool
+    ``[num_blocks, block_size, Hkv, hd]`` for each full layer, a ring
+    ``[slots, ring_rows, Hkv, hd]`` for each sliding layer, k and v, in
+    the compute dtype."""
+    cdt = jnp.dtype(cfg.dtype)
+    shapes = {
+        "full_attention": (num_blocks, block_size, cfg.kv_heads, cfg.head_dim),
+        "sliding_attention": (slots, ring_rows, cfg.kv_heads, cfg.head_dim),
+    }
+    return _plan_cache(cfg, lambda kind: {"k": jnp.zeros(shapes[kind], cdt),
+                                          "v": jnp.zeros(shapes[kind], cdt)})
+
+
+def mixed_cache_bytes(cfg: LlamaConfig, cache: dict) -> dict:
+    """Bytes the serve cache holds, by kind of layer."""
+    lead, period = _plan_kinds(cfg)
+    out = {"full_attention": 0, "sliding_attention": 0}
+    for kind, entry in zip(lead + period, cache["lead"] + cache["period"]):
+        out[kind] += sum(a.nbytes for a in jax.tree.leaves(entry))
+    return out
+
+
+def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slot,
+                       pos, active, token_valid):
+    """The decoder over ``tokens`` [B, T] at per-slot positions
+    ``pos..pos+T-1`` through both kinds of cache. ``tables`` [B, mb] are
+    the rows' block tables; ``ring_slot`` is None where row b IS ring
+    slot b (the tick: B = slots) or the traced ring slot of the one row
+    (a prefill chunk: B = 1); ``active`` [B] drops dead rows' writes.
+    Returns (final-normed hidden [B, T, d], cache, counters int32[3],
+    chosen experts [L_sparse, B, T, k])."""
+    cdt = jnp.dtype(cfg.dtype)
+    b, t = tokens.shape
+    window = cfg.sliding_window or 0
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]
+    qpos = pos[:, None] + jnp.arange(t)[None, :]             # [B, T]
+    cos, sin = _slot_rope_tables(cfg, qpos, cdt)
+
+    def rope(a):
+        half = a.shape[-1] // 2
+        a1, a2 = a[..., :half], a[..., half:]
+        return a * cos + jnp.concatenate([-a2, a1], axis=-1) * sin
+
+    def attend_full(entry):
+        nb, bs = entry["k"].shape[:2]
+        mb = tables.shape[1]
+        with jax.named_scope("attention"):
+            ok = jnp.arange(mb * bs)[None, None, :] <= qpos[:, :, None]
+            mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]   # [B, 1, T, S]
+        with jax.named_scope("kv_write"):
+            phys = jnp.take_along_axis(tables, jnp.clip(qpos // bs, 0, mb - 1), axis=1)
+            phys = jnp.where(active[:, None] > 0, phys, nb)  # dead rows drop
+            off = qpos % bs
+
+        def attend(q, k, v):
+            with jax.named_scope("kv_write"):
+                pk = entry["k"].at[phys, off].set(k.astype(cdt), mode="drop")
+                pv = entry["v"].at[phys, off].set(v.astype(cdt), mode="drop")
+            with jax.named_scope("kv_gather"):
+                ck = pk[tables].reshape(b, mb * bs, *pk.shape[2:])
+                cv = pv[tables].reshape(b, mb * bs, *pv.shape[2:])
+            return _slot_attention(q, ck, cv, mask), {"k": pk, "v": pv}
+
+        return attend
+
+    def attend_ring(entry):
+        slots, rows = entry["k"].shape[:2]
+        with jax.named_scope("attention"):
+            # what each row holds once this call has written up to `last`
+            last = qpos[:, -1]
+            held = last[:, None] - (last[:, None] - jnp.arange(rows)[None, :]) % rows
+            held = held[:, None, :]                          # [B, 1, R]
+            ok = (held >= 0) & (held <= qpos[:, :, None]) \
+                & (qpos[:, :, None] - held < window)
+            mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]   # [B, 1, T, R]
+        with jax.named_scope("kv_write"):
+            at = jnp.arange(b)[:, None] if ring_slot is None else ring_slot[None, None]
+            at = jnp.broadcast_to(jnp.where(active[:, None] > 0, at, slots), (b, t))
+            row = qpos % rows
+
+        def attend(q, k, v):
+            with jax.named_scope("kv_write"):
+                rk = entry["k"].at[at, row].set(k.astype(cdt), mode="drop")
+                rv = entry["v"].at[at, row].set(v.astype(cdt), mode="drop")
+            if ring_slot is None:
+                ck, cv = rk, rv
+            else:
+                with jax.named_scope("kv_gather"):
+                    ck = jax.lax.dynamic_index_in_dim(rk, ring_slot, 0, keepdims=True)
+                    cv = jax.lax.dynamic_index_in_dim(rv, ring_slot, 0, keepdims=True)
+            return _slot_attention(q, ck, cv, mask), {"k": rk, "v": rv}
+
+        return attend
+
+    def body(x, layer, kind, c):
+        attend = (attend_ring if kind[0] == "sliding_attention" else attend_full)(c)
+        return _mixed_layer(cfg, x, layer, kind, rope, attend, token_valid)
+
+    x, cache, counters, recs = run_layers(cfg, params, x, body, cache)
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    chosen = [r for r in recs if r is not None]
+    chosen = jnp.stack(chosen) if chosen else jnp.zeros((0, b, t, 1), jnp.int32)
+    return x, cache, counters, chosen
+
+
+@functools.lru_cache(maxsize=8)
+def prefill_chunk_mixed_fn(cfg: LlamaConfig):
+    """``prefill_chunk_paged_fn`` over both kinds of cache: jitted
+    ``(params, cache, table [max_blocks] i32, slot, chunk [1,C],
+    chunk_valid [1,C], pos, last_idx, key_data, temperature, top_k,
+    top_p) -> (token, logits [1,V], cache, counters int32[3], chosen
+    experts [L_sparse, 1, C, k])``."""
+
+    def run(params, cache, table, slot, chunk, chunk_valid, pos, last_idx,
+            key_data, temperature, top_k, top_p):
+        x, cache, counters, chosen = _serve_block_mixed(
+            params, cfg, chunk, cache, table[None], slot, pos[None],
+            jnp.ones((1,), jnp.int32), chunk_valid)
+        xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)[:, 0]
+        logits = _head_logits(params, xl, jnp.dtype(cfg.dtype))
+        tok = _sample_one(logits, key_data, temperature, top_k, top_p)
+        return tok, logits, cache, counters, chosen
+
+    return jax.jit(run, donate_argnums=_serve_donate())
+
+
+@functools.lru_cache(maxsize=8)
+def decode_slots_mixed_fn(cfg: LlamaConfig):
+    """``decode_slots_paged_fn`` over both kinds of cache: jitted
+    ``(params, cache, tables [B, max_blocks] i32, tokens [B], pos [B],
+    key_data [B,2] u32, temperature [B], top_k [B], top_p [B],
+    active [B]) -> (next_tokens [B], cache, counters int32[3], chosen
+    experts [L_sparse, B, 1, k])``: one tick advancing every slot."""
+
+    def run(params, cache, tables, tokens, pos, key_data,
+            temperature, top_k, top_p, active):
+        x, cache, counters, chosen = _serve_block_mixed(
+            params, cfg, tokens[:, None], cache, tables, None, pos, active,
+            active[:, None])
+        logits = _head_logits(params, x[:, 0], jnp.dtype(cfg.dtype))
+        keys = jax.random.wrap_key_data(key_data)
+        nxt = _sample_slots(logits, keys, temperature, top_k, top_p)
+        return nxt, cache, counters, chosen
 
     return jax.jit(run, donate_argnums=_serve_donate())

@@ -52,6 +52,12 @@ _LAYER_MAP: dict[str, tuple[str, bool]] = {
 
 
 def _check_dense(cfg: LlamaConfig) -> None:
+    if cfg.mixed:
+        raise ValueError(
+            "HF interop supports dense Llama only: a mixed layer stack "
+            "(sliding-window layers, q/k norms, a bias-corrected gate, shared "
+            "experts) has no LlamaForCausalLM layout"
+        )
     if cfg.num_experts:
         raise ValueError(
             "HF interop supports dense Llama only (transformers' "

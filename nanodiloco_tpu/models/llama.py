@@ -28,6 +28,8 @@ are masked out of the loss instead of being trained on.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -60,6 +62,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
     def normal(key, shape):
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(pdt)
 
+    if cfg.mixed:
+        return _init_mixed_params(cfg, keys, normal)
     layers = {
         "attn_norm": jnp.ones((l, d), pdt),
         "wq": normal(keys[0], (l, d, nh * hd)),
@@ -86,6 +90,153 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(keys[8], (d, v))
     return params
+
+
+def _init_mixed_params(cfg: LlamaConfig, keys, normal) -> Params:
+    """A mixed configuration's tree follows its ``layer_plan``:
+    ``lead_layers`` is one dict a leading layer (the dense layers, then
+    any remainder of the period), unstacked; ``layers`` is one dict a
+    layer OF THE PERIOD, each leaf stacked over the periods ``[n, ...]``
+    (what ``run_layers`` scans). No leaf holds two layers of one
+    program step, so no program slices a layer's weights out of a larger
+    array: a grouped-product kernel takes its operand as a buffer of its
+    own, and a slice of a stack would be copied for it at every call
+    (PERF.md, PR 26: 4.8 GB of expert weights a tick). Sparse layers
+    hold the router at its full width, the weights of the experts HELD
+    here and the shared experts' one fused SwiGLU. A sigmoid gate's
+    selection bias is drawn like a weight and not left at zero, so that
+    seeded weights exercise it."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    d, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    plan = layer_plan(cfg)
+
+    def layer(tag: int, n: tuple, sparse: bool) -> Params:
+        """One layer's weights, each leaf with the leading shape ``n``."""
+        ks = iter(jax.random.split(jax.random.fold_in(keys[0], tag), 12))
+        g = {
+            "attn_norm": jnp.ones(n + (d,), pdt),
+            "wq": normal(next(ks), n + (d, nh * hd)),
+            "wk": normal(next(ks), n + (d, nkv * hd)),
+            "wv": normal(next(ks), n + (d, nkv * hd)),
+            "wo": normal(next(ks), n + (nh * hd, d)),
+            "mlp_norm": jnp.ones(n + (d,), pdt),
+        }
+        if cfg.qk_norm:
+            g["q_norm"] = jnp.ones(n + (hd,), pdt)
+            g["k_norm"] = jnp.ones(n + (hd,), pdt)
+        if not sparse:
+            g["w_gate"] = normal(next(ks), n + (d, f))
+            g["w_up"] = normal(next(ks), n + (d, f))
+            g["w_down"] = normal(next(ks), n + (f, d))
+            return g
+        fe, held = cfg.expert_width, cfg.held_experts[1]
+        g["router"] = normal(next(ks), n + (d, cfg.num_experts))
+        if cfg.scoring_func == "sigmoid":
+            g["router_bias"] = cfg.initializer_range * jax.random.normal(
+                next(ks), n + (cfg.num_experts,), jnp.float32)
+        g["w_gate"] = normal(next(ks), n + (held, d, fe))
+        g["w_up"] = normal(next(ks), n + (held, d, fe))
+        g["w_down"] = normal(next(ks), n + (held, fe, d))
+        if cfg.num_shared_experts:
+            fs = cfg.num_shared_experts * fe
+            g["shared_gate"] = normal(next(ks), n + (d, fs))
+            g["shared_up"] = normal(next(ks), n + (d, fs))
+            g["shared_down"] = normal(next(ks), n + (fs, d))
+        return g
+
+    params: Params = {
+        "embed": normal(keys[7], (v, d)),
+        "lead_layers": tuple(layer(i, (), plan.kinds[i][1]) for i in range(plan.lead)),
+        "layers": tuple(layer(plan.lead + j, (plan.periods,), plan.kinds[plan.lead + j][1])
+                        for j in range(plan.period if plan.periods else 0)),
+        "final_norm": jnp.ones((d,), pdt),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = normal(keys[8], (d, v))
+    return params
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerPlan:
+    """How a mixed configuration's layers run. Layers [0, lead) run one
+    by one (``params["lead_layers"]``: the leading dense layers and any
+    remainder of the period), the rest as ``periods`` repeats of
+    ``period`` layers, scanned (``params["layers"]``): one period is
+    traced and compiled, whatever the depth. ``kinds[i]`` is layer i's
+    (attention kind, sparse?)."""
+
+    kinds: tuple[tuple[str, bool], ...]
+    lead: int
+    period: int
+    periods: int
+
+
+@functools.lru_cache(maxsize=32)
+def layer_plan(cfg: LlamaConfig) -> LayerPlan:
+    """The shortest period that the layers after the leading dense ones
+    repeat, a remainder run first: 48 layers ``LLLG`` x 12 with one
+    leading dense layer are 1 + 3 layers and 11 periods ``LLLG``."""
+    n = cfg.num_hidden_layers
+    kinds = tuple(cfg.layer_kind(i) for i in range(n))
+    dense = cfg.first_k_dense_replace if cfg.num_experts else 0
+    rest = kinds[dense:]
+    for p in range(1, len(rest) + 1):
+        r = len(rest) % p
+        if all(rest[i] == rest[i + p] for i in range(r, len(rest) - p)):
+            if len(rest) // p == 1:  # nothing repeats: one whole period, no remainder
+                return LayerPlan(kinds, dense, len(rest), 1)
+            return LayerPlan(kinds, dense + r, p, len(rest) // p)
+    return LayerPlan(kinds, dense, 1, 0)  # no layer after the dense ones
+
+
+def run_layers(cfg: LlamaConfig, params: Params, x, body, cache=None):
+    """Run a mixed configuration's layers over ``x`` by its
+    ``layer_plan``. ``body(x, layer, kind, c) -> (x, c, counters, rec)``
+    is one layer: ``layer`` its weights, ``kind`` its static (attention
+    kind, sparse?), ``c`` its own cache entry or None, ``counters`` the
+    int32[3] of ``moe.sparse_mlp`` (zeros for a dense layer), summed
+    here over the layers, ``rec`` an array the layer hands out (the
+    experts it chose) or None. ``cache`` is None or ``{"lead": one entry
+    a leading layer, "period": one entry a layer of the period, each
+    stacked over the periods}`` (``models/generate.py`` builds it: the
+    entries of two kinds of layer need not have one shape). Returns
+    (x, cache, summed counters, the layers' recs in layer order)."""
+    plan = layer_plan(cfg)
+    lead_c = list(cache["lead"]) if cache is not None else [None] * plan.lead
+    counters = jnp.zeros((3,), jnp.int32)
+    recs = []
+    for i in range(plan.lead):
+        x, lead_c[i], n, rec = body(x, params["lead_layers"][i], plan.kinds[i], lead_c[i])
+        counters = counters + n
+        recs.append(rec)
+    period_c = ()
+    if plan.periods:
+        p = plan.period
+        kinds = plan.kinds[plan.lead:plan.lead + p]
+
+        def period(carry, scanned):
+            x, counters = carry
+            layers, cs = scanned
+            out, rec = [], []
+            for j in range(p):
+                x, c, n, r_j = body(x, layers[j], kinds[j], None if cs is None else cs[j])
+                out.append(c)
+                rec.append(r_j)
+                counters = counters + n
+            return (x, counters), (None if cs is None else tuple(out), tuple(rec))
+
+        cs = None if cache is None else tuple(cache["period"])
+        # layer_scan: the scan's own slicing and stacking of a period's
+        # weights and cache entries
+        with jax.named_scope("layer_scan"):
+            (x, counters), (period_c, rec) = jax.lax.scan(
+                period, (x, counters), (tuple(params["layers"]), cs))
+        recs += [None if rec[j] is None else rec[j][i]
+                 for i in range(plan.periods) for j in range(p)]
+    if cache is None:
+        return x, None, counters, recs
+    return x, {"lead": tuple(lead_c), "period": period_c}, counters, recs
 
 
 # ---------------------------------------------------------------------------
@@ -155,13 +306,18 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def causal_mask(s: int, valid: jax.Array | None = None, start: int = 0) -> jax.Array:
+def causal_mask(s: int, valid: jax.Array | None = None, start: int = 0,
+                window: int | None = None) -> jax.Array:
     """Additive [B|1, 1, S - start, S] float32 mask for query rows
     ``start..S`` over keys ``0..S``: causal, optionally restricted to
-    ``valid`` [B, S] key positions (1 = real token)."""
+    ``valid`` [B, S] key positions (1 = real token) and to the last
+    ``window`` keys of each row (a sliding layer: i - window < j <= i)."""
     qi = jax.lax.broadcasted_iota(jnp.int32, (s - start, s), 0) + start
     ki = jax.lax.broadcasted_iota(jnp.int32, (s - start, s), 1)
-    ok = (qi >= ki)[None]                      # [1, S - start, S]
+    ok = qi >= ki
+    if window is not None:
+        ok = ok & (qi - ki < window)
+    ok = ok[None]                              # [1, S - start, S]
     if valid is not None:
         ok = ok & (valid[:, None, :] > 0)      # [B, S - start, S]
     return jnp.where(ok, 0.0, MASK_VALUE)[:, None]
@@ -198,7 +354,7 @@ def dense_score_share(s: int, bq: int | None = None) -> float:
 
 def dense_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array | None = None,
-    *, bq: int | None = None,
+    *, bq: int | None = None, window: int | None = None,
 ) -> jax.Array:
     """Reference attention: q,k,v [B, S, H, hd] (k/v already GQA-expanded),
     softmax in float32. ``mask`` is None (causal), a [B, S] 0/1 validity
@@ -212,7 +368,9 @@ def dense_attention(
     form's up to float32 reassociation. An explicit mask may allow any
     key, so it runs in one block. A row with no valid key at all (left
     padding) softmaxes to uniform over its block's keys, not over S:
-    finite either way, and loss-masked."""
+    finite either way, and loss-masked. ``window`` (a sliding layer) is
+    one more term of the causal mask; the blocks still meet every key at
+    or before their last row."""
     b, s, h, hd = q.shape
     scale = 1.0 / math.sqrt(hd)
     explicit = mask is not None and mask.ndim == 4
@@ -224,7 +382,7 @@ def dense_attention(
             "bqhd,bkhd->bhqk", q[:, start:end], k[:, :end]
         ).astype(jnp.float32) * scale
         block = mask if explicit else causal_mask(
-            end, None if mask is None else mask[:, :end], start
+            end, None if mask is None else mask[:, :end], start, window
         )
         scores = scores + block.astype(jnp.float32)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -233,7 +391,8 @@ def dense_attention(
 
 
 @jax.named_scope("attention")
-def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None):
+def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None,
+               window: int | None = None):
     """Dispatch on cfg.attention_impl. Ring attention requires being inside
     a shard_map with the sequence axis bound to ``axis_name``; flash and
     ring ignore ``valid``, the [B, S] padding mask (packed fixed-length
@@ -256,12 +415,50 @@ def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None):
         g = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
-    return dense_attention(q, k, v, valid if cfg.attention_impl == "dense" else None)
+    return dense_attention(q, k, v, valid if cfg.attention_impl == "dense" else None,
+                           window=window)
 
 
 # ---------------------------------------------------------------------------
 # Decoder
 # ---------------------------------------------------------------------------
+
+def qkv_proj(cfg: LlamaConfig, h, layer: Params, rope, attn_kind: str = "full_attention"):
+    """q [B, T, H, hd], k and v [B, T, Hkv, hd] of normed ``h``: the
+    projections, the per-head RMSNorm of q and k where the configuration
+    has one, and ``rope`` (a function of one array) on q and k in the
+    layers that rotate (``cfg.rope_layers``). The one projection of the
+    training forward and of every cached program. Under ``attn_proj``."""
+    b, t, _ = h.shape
+    nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    cdt = h.dtype
+    q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
+    k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
+    v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+    if cfg.rope_layers == "all" or attn_kind == "sliding_attention":
+        q, k = rope(q), rope(k)
+    return q, k, v
+
+
+def _attn_block(cfg: LlamaConfig, x, layer: Params, cos, sin, attn_valid, sp_axis,
+                attn_kind: str = "full_attention"):
+    """The attention half of a decoder layer: x + Wo attention(...)."""
+    b, s, _ = x.shape
+    cdt = x.dtype
+    h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn_proj"):
+        q, k, v = qkv_proj(cfg, h, layer, lambda a: apply_rope(a, cos, sin), attn_kind)
+    # GQA K/V stay at Hkv heads here; flash/ring are GQA-native (K/V are
+    # never expanded in HBM/ICI — the bandwidth GQA exists to save) and
+    # _attention expands only for its dense paths.
+    window = cfg.sliding_window if attn_kind == "sliding_attention" else None
+    attn = _attention(cfg, q, k, v, attn_valid, sp_axis, window)
+    with jax.named_scope("attn_proj"):
+        return x + attn.reshape(b, s, -1) @ layer["wo"].astype(cdt)
+
 
 def _decoder_layer(
     cfg: LlamaConfig, x, layer: Params, cos, sin, attn_valid, sp_axis, valid=None,
@@ -272,25 +469,28 @@ def _decoder_layer(
     attention may see (None: causal alone); ``valid`` [B, S] marks real
     tokens so MoE routing never spends expert capacity on padding.
     ``with_stats`` adds the router observability vector (see moe_mlp)."""
-    b, s, d = x.shape
-    nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    cdt = x.dtype
-
-    h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-    with jax.named_scope("attn_proj"):
-        q = (h @ layer["wq"].astype(cdt)).reshape(b, s, nh, hd)
-        k = (h @ layer["wk"].astype(cdt)).reshape(b, s, nkv, hd)
-        v = (h @ layer["wv"].astype(cdt)).reshape(b, s, nkv, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-    # GQA K/V stay at Hkv heads here; flash/ring are GQA-native (K/V are
-    # never expanded in HBM/ICI — the bandwidth GQA exists to save) and
-    # _attention expands only for its dense paths.
-    attn = _attention(cfg, q, k, v, attn_valid, sp_axis)
-    with jax.named_scope("attn_proj"):
-        x = x + attn.reshape(b, s, nh * hd) @ layer["wo"].astype(cdt)
-
+    x = _attn_block(cfg, x, layer, cos, sin, attn_valid, sp_axis)
     return mlp_block(cfg, x, layer, valid, sp_axis=sp_axis, with_stats=with_stats)
+
+
+def mixed_mlp_block(cfg: LlamaConfig, x, layer: Params, valid=None):
+    """``mlp_block`` of a mixed configuration's layer, dense or sparse by
+    the weights it holds: (x, counters int32[3], chosen experts
+    [B, S, k] or None) with the sparse layer's counters and choice
+    (``moe.sparse_mlp``), zeros and None for a dense one. Shared by the
+    training forward and the cached programs."""
+    cdt = x.dtype
+    h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("mlp"):
+        if "router" in layer:
+            from nanodiloco_tpu.models.moe import sparse_mlp
+
+            out, counters, chosen = sparse_mlp(cfg, h, layer, valid)
+            return x + out, counters, chosen
+        gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
+        up = h @ layer["w_up"].astype(cdt)
+        return (x + (gate * up) @ layer["w_down"].astype(cdt),
+                jnp.zeros((3,), jnp.int32), None)
 
 
 def mlp_block(
@@ -382,12 +582,29 @@ def forward(
         out = layer_fn(carry, layer, cos, sin, attn_mask)
         return out[0], out[1:]
 
-    # the scan's own work (a layer's weights sliced out of the stack,
-    # the residuals stacked for the backward pass) reads as layer_scan
-    with jax.named_scope("layer_scan"):
-        x, ys = jax.lax.scan(scan_body, x, params["layers"])
-    aux = jnp.sum(ys[0])
-    stats = jnp.mean(ys[1], axis=0) if collect_stats else None  # [2]
+    if cfg.mixed:
+        # leading layers and scanned periods (run_layers). The gate of a
+        # mixed configuration has no auxiliary loss and no capacity:
+        # aux and the probe's [dropped_frac, router_entropy] read zero
+        def mixed_layer(x, layer, kind, _):
+            def fn(x, layer):
+                x = _attn_block(cfg, x, layer, cos, sin, attn_mask, sp_axis, kind[0])
+                return mixed_mlp_block(cfg, x, layer, attn_mask)[:2]
+
+            if cfg.remat:
+                fn = jax.checkpoint(fn, policy=checkpoint_policy(cfg))
+            x, counters = fn(x, layer)
+            return x, None, counters, None
+
+        x = run_layers(cfg, params, x, mixed_layer)[0]
+        aux, stats = jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.float32)
+    else:
+        # the scan's own work (a layer's weights sliced out of the stack,
+        # the residuals stacked for the backward pass) reads as layer_scan
+        with jax.named_scope("layer_scan"):
+            x, ys = jax.lax.scan(scan_body, x, params["layers"])
+        aux = jnp.sum(ys[0])
+        stats = jnp.mean(ys[1], axis=0) if collect_stats else None  # [2]
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
     def pack(out):
@@ -563,6 +780,11 @@ def sp_shard_loss(
 
     MoE composes via token-choice routing with per-shard capacity — see
     moe_mlp for the exact-when-capacity-is-ample semantics."""
+    if cfg.mixed:
+        raise ValueError(
+            "the sequence-parallel loss does not carry a mixed layer stack "
+            "(sliding-window layers, a held share of the experts): ring "
+            "attention knows one causal mask")
     if cfg.attention_impl != "ring":
         raise ValueError(
             "sequence-parallel loss requires attention_impl='ring'; "

@@ -160,7 +160,7 @@ def _experts_choose(
 def _ragged_mlp(
     cfg: LlamaConfig, x: jax.Array, topk_p: jax.Array, topk_e: jax.Array,
     layer: dict, valid_t: jax.Array | None,
-) -> jax.Array:
+) -> tuple[jax.Array, jax.Array]:
     """Sorted/ragged token-choice dispatch (the Mixtral/megablocks shape;
     implements the large-E alternative the module docstring previously
     only design-documented). Flatten the [T, k] (token, slot) routing
@@ -171,43 +171,106 @@ def _ragged_mlp(
     FLOPs. All shapes stay static ([k·T, ...]); the data dependence is
     confined to the gather/scatter indices and the group-size vector,
     which is what keeps it XLA-compilable. x: [T, d]; topk_p/topk_e:
-    [T, k] normalized weights / expert ids. Returns y [T, d].
+    [T, k] combine weights / expert ids over the ROUTER's experts.
+    Returns (y [T, d], group sizes [count] int32).
 
-    Padding tokens (valid_t = 0) keep their expert assignment — they
-    ride through the grouped matmuls as wasted-but-correct rows — and
-    are zeroed in the combine weight, identical to dense dispatch's
-    treatment. Numerics vs dense dispatch at non-binding capacity:
-    IDENTICAL routing and weights; summation order within an expert
-    differs (contiguous run vs one-hot einsum), so outputs agree to
-    dtype tolerance, not bit-exactly.
+    The chip's share (``cfg.held_experts`` = (first, count); all of them
+    by default): ``layer`` holds weights for experts first..first+count-1
+    alone, a pair routed elsewhere sorts behind the last held group and
+    lies in no group (``ragged_dot`` leaves rows past its groups alone;
+    their output is dropped), and ``y`` is the held experts' part of the
+    layer's output. ``topk_p`` is used as given: the caller normalised
+    it over all k chosen, held or not.
+
+    Padding tokens (valid_t = 0) are treated as routed elsewhere: no
+    group, no output. Numerics vs dense dispatch at non-binding
+    capacity: IDENTICAL routing and weights; summation order within an
+    expert differs (contiguous run vs one-hot einsum), so outputs agree
+    to dtype tolerance, not bit-exactly.
     """
     t, d = x.shape
     k = topk_e.shape[1]
-    e = cfg.num_experts
+    first, count = cfg.held_experts
     cdt = x.dtype
 
-    e_flat = topk_e.reshape(t * k)                       # [kT] expert ids
-    w_flat = topk_p.reshape(t * k)                       # [kT] combine wts
-    tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)  # [kT]
-    if valid_t is not None:
-        w_flat = w_flat * valid_t.astype(w_flat.dtype)[tok_flat]
+    with jax.named_scope("moe_route"):
+        e_flat = topk_e.reshape(t * k) - first               # [kT] held-local ids
+        tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)  # [kT]
+        held = (e_flat >= 0) & (e_flat < count)
+        if valid_t is not None:
+            held = held & (valid_t[tok_flat] > 0)
+        e_flat = jnp.where(held, e_flat, count)              # the rest sort last
+        order = jnp.argsort(e_flat, stable=True)             # expert-contiguous
+        group_sizes = jnp.bincount(e_flat, length=count + 1)[:count].astype(jnp.int32)
+        w_sorted = topk_p.reshape(t * k)[order]
+        held_sorted = held[order]
+        rows = tok_flat[order]
 
-    order = jnp.argsort(e_flat, stable=True)             # expert-contiguous
-    xg = x[tok_flat[order]]                              # [kT, d] gather
-    group_sizes = jnp.bincount(e_flat, length=e).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        xg = x[rows]                                         # [kT, d] gather
+        gate = jax.nn.silu(
+            jax.lax.ragged_dot(xg, layer["w_gate"].astype(cdt), group_sizes)
+        )
+        up = jax.lax.ragged_dot(xg, layer["w_up"].astype(cdt), group_sizes)
+        out = jax.lax.ragged_dot(
+            gate * up, layer["w_down"].astype(cdt), group_sizes
+        )                                                    # [kT, d]
+        # a select, not a product by 0: a row in no group holds whatever
+        # the grouped product left there
+        out = jnp.where(held_sorted[:, None], out * w_sorted.astype(cdt)[:, None], 0)
+        y = jnp.zeros((t, d), cdt).at[rows].add(out)
+    return y, group_sizes
 
-    gate = jax.nn.silu(
-        jax.lax.ragged_dot(xg, layer["w_gate"].astype(cdt), group_sizes)
-    )
-    up = jax.lax.ragged_dot(xg, layer["w_up"].astype(cdt), group_sizes)
-    out = jax.lax.ragged_dot(
-        gate * up, layer["w_down"].astype(cdt), group_sizes
-    )                                                    # [kT, d]
 
-    out = out * w_flat[order].astype(cdt)[:, None]
-    return (
-        jnp.zeros((t, d), cdt).at[tok_flat[order]].add(out)
-    )
+def route(cfg: LlamaConfig, x: jax.Array, layer: dict):
+    """The gate of a mixed configuration's sparse layer. x [T, d] ->
+    (weights [T, k] float32, experts [T, k] int32). Scores are softmax
+    or sigmoid of the router's float32
+    logits; the k experts are those with the largest score plus the
+    layer's selection bias (``router_bias``, where the layer has one:
+    it picks and does not weigh); the weights are the chosen experts'
+    own scores, normalised over ALL k chosen where ``norm_topk_prob``,
+    times ``routed_scaling_factor``."""
+    logits = jnp.dot(x, layer["router"].astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    scores = (jax.nn.sigmoid(logits) if cfg.scoring_func == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    choose = scores + layer["router_bias"].astype(jnp.float32) \
+        if "router_bias" in layer else scores
+    _, topk_e = jax.lax.top_k(choose, cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(scores, topk_e, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w * cfg.routed_scaling_factor, topk_e
+
+
+def sparse_mlp(cfg: LlamaConfig, h: jax.Array, layer: dict,
+               valid: jax.Array | None = None):
+    """The sparse feed-forward of a mixed configuration: ``route``, the
+    held experts' grouped products (``_ragged_mlp``: no capacity, no
+    dropped token) and the shared experts (one SwiGLU of width
+    ``num_shared_experts * expert_width`` that every token passes).
+    h [B, S, d] normed hidden states; ``valid`` [B, S] marks real
+    tokens. Returns (out [B, S, d], counters int32[3], the chosen
+    experts [B, S, k] int32); the counters: token-expert pairs routed to
+    held experts, held experts at least one token chose, all pairs (k a
+    real token)."""
+    b, s, d = h.shape
+    x = h.reshape(b * s, d)
+    valid_t = None if valid is None else valid.reshape(b * s)
+    with jax.named_scope("moe_route"):
+        w, topk_e = route(cfg, x, layer)
+    y, group_sizes = _ragged_mlp(cfg, x, w, topk_e, layer, valid_t)
+    if "shared_gate" in layer:
+        with jax.named_scope("moe_shared"):
+            cdt = x.dtype
+            gate = jax.nn.silu(x @ layer["shared_gate"].astype(cdt))
+            up = x @ layer["shared_up"].astype(cdt)
+            y = y + (gate * up) @ layer["shared_down"].astype(cdt)
+    n_tok = jnp.int32(b * s) if valid_t is None else jnp.sum(valid_t > 0).astype(jnp.int32)
+    counters = jnp.stack([jnp.sum(group_sizes), jnp.sum(group_sizes > 0).astype(jnp.int32),
+                          n_tok * cfg.num_experts_per_tok])
+    return y.reshape(b, s, d), counters, topk_e.reshape(b, s, -1)
 
 
 def _expert_ffn(expert_in: jax.Array, layer: dict) -> jax.Array:
@@ -271,6 +334,11 @@ def moe_mlp(
     weak #4: silent capacity-bound dropping and router collapse must be
     visible). Off the training path (the diagnostics probe sets it), so
     the training program is unchanged."""
+    if cfg.mixed:
+        raise ValueError(
+            "moe_mlp is the softmax top-k layer; a mixed configuration "
+            "(sigmoid / bias-corrected gate, shared experts, a held share "
+            "of the experts) runs models.moe.sparse_mlp")
     b, s, d = h.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     cdt = h.dtype
@@ -310,7 +378,7 @@ def moe_mlp(
     if cfg.moe_dispatch == "ragged":
         # exact-sized grouped matmuls, no capacity, nothing dropped;
         # `keep` stays the full assignment for the shared stats below
-        y = _ragged_mlp(
+        y, _ = _ragged_mlp(
             cfg, x, topk_p, topk_e, layer,
             None if valid is None else valid.reshape(t),
         )

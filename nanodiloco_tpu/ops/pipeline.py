@@ -61,6 +61,11 @@ def _pipeline_setup(cfg: LlamaConfig, S: int, sp_axis: str | None):
     validated sp setup, rope tables (shard-global offsets under sp), and
     the (possibly rematerialized) per-layer function. One copy, so a
     semantics change can never diverge the two schedules silently."""
+    if cfg.mixed:
+        raise ValueError(
+            "the pipeline stages do not carry a mixed layer stack (window and "
+            "full attention layers, leading dense layers, a held share of the "
+            "experts): a stage scans one kind of layer")
     if sp_axis is not None:
         if cfg.attention_impl != "ring":
             raise ValueError(

@@ -625,7 +625,18 @@ class Diloco:
             )
             stacked = self._constrain(stacked, worker_axis=True)
             inner_state = jax.vmap(self.inner_tx.init)(stacked)
+            if self.mesh.size > 1:
+                # zeros take no sharding from ``stacked``: left alone, every
+                # chip holds all workers' moments, and the first round_step
+                # (which returns them over the worker axis) compiles twice.
+                # The moments lie as the workers' parameters do (over fsdp
+                # and tp too), the counters over the worker axis
+                inner_state = constrain(inner_state, self.mesh, self._opt_state_spec(
+                    inner_state, self._wspec, self._pspec_struct))
             outer_state = self.outer_tx.init(p)
+            if self.mesh.size > 1:  # the outer momentum lies as the snapshot does
+                outer_state = constrain(outer_state, self.mesh, self._opt_state_spec(
+                    outer_state, self._pspec, self._pspec_struct, other=P()))
             return DilocoState(
                 params=stacked,
                 inner_opt_state=inner_state,
@@ -895,9 +906,10 @@ class Diloco:
             for k, v in params.items()
         }
 
-    def _pp_state_spec(self, tree: Any, param_spec: Any, pstruct):
+    def _opt_state_spec(self, tree: Any, param_spec: Any, pstruct, other=P("diloco")):
         """Spec tree for an optimizer state: param-structured subtrees
-        (mu/nu) get ``param_spec``; other leaves P('diloco')."""
+        (mu/nu, the outer trace) get ``param_spec``; other leaves ``other``
+        (the workers' step counters lie over the worker axis)."""
 
         def is_param_tree(x):
             try:
@@ -906,7 +918,7 @@ class Diloco:
                 return False
 
         return jax.tree.map(
-            lambda sub: param_spec if is_param_tree(sub) else P("diloco"),
+            lambda sub: param_spec if is_param_tree(sub) else other,
             tree,
             is_leaf=is_param_tree,
         )
@@ -1016,7 +1028,7 @@ class Diloco:
 
         pstruct = jax.tree.structure(state.snapshot)
         param_spec = self._pp_param_spec(state.params)
-        opt_spec = self._pp_state_spec(
+        opt_spec = self._opt_state_spec(
             state.inner_opt_state, param_spec, pstruct
         )
         # [W, M, B, S]: sequence over sp when present, B/fsdp/tp left auto

@@ -36,6 +36,8 @@ def param_specs(
     With ``pp`` the stacked LAYER axis shards over the pipeline stages
     (ops/pipeline.py) — embed/head/norms stay replicated across pp."""
     lax0 = "pp" if pp else None  # the leading (layer) axis of layer leaves
+    if cfg.mixed:
+        return _mixed_param_specs(cfg, worker_axis, pp)
     if cfg.num_experts:
         # MoE: expert axis over ep; per-expert FFN dims over fsdp/tp
         mlp_specs = {
@@ -71,6 +73,55 @@ def param_specs(
             "mlp_norm": P(lax0, None),
             **mlp_specs,
         },
+    }
+    if not cfg.tie_word_embeddings:
+        specs["lm_head"] = P("fsdp", "tp")
+    if worker_axis:
+        specs = jax.tree.map(
+            lambda s: P("diloco", *s), specs, is_leaf=lambda x: isinstance(x, P)
+        )
+    return specs
+
+
+def _mixed_param_specs(cfg: LlamaConfig, worker_axis: bool, pp: bool) -> dict[str, Any]:
+    """Specs for a mixed configuration's tree (``lead_layers`` unstacked,
+    ``layers`` stacked over the periods, models/llama.py): the dense
+    rules by name, the held
+    experts' leading axis left whole (``ragged_dot`` dispatch needs
+    replicated experts: ``ep > 1`` is refused where the mesh is built),
+    the gate's bias and the q/k norms replicated. No pipeline: the
+    stages scan one kind of layer."""
+    if pp:
+        raise ValueError(
+            "pipeline parallelism (pp > 1) does not carry a mixed layer stack: "
+            "ops/pipeline.py scans one kind of layer")
+    attn = {
+        "attn_norm": P(None, None), "mlp_norm": P(None, None),
+        "wq": P(None, "fsdp", "tp"), "wk": P(None, "fsdp", "tp"),
+        "wv": P(None, "fsdp", "tp"), "wo": P(None, "tp", "fsdp"),
+    }
+    if cfg.qk_norm:
+        attn.update(q_norm=P(None, None), k_norm=P(None, None))
+    dense = {**attn, "w_gate": P(None, "fsdp", "tp"), "w_up": P(None, "fsdp", "tp"),
+             "w_down": P(None, "tp", "fsdp")}
+    sparse = {**attn, "router": P(None, None, None),
+              "w_gate": P(None, None, "fsdp", "tp"), "w_up": P(None, None, "fsdp", "tp"),
+              "w_down": P(None, None, "tp", "fsdp")}
+    if cfg.scoring_func == "sigmoid":
+        sparse["router_bias"] = P(None, None)
+    if cfg.num_shared_experts:
+        sparse.update(shared_gate=P(None, "fsdp", "tp"), shared_up=P(None, "fsdp", "tp"),
+                      shared_down=P(None, "tp", "fsdp"))
+    from nanodiloco_tpu.models.llama import layer_plan
+
+    plan = layer_plan(cfg)
+    unstacked = lambda g: {k: P(*v[1:]) for k, v in g.items()}
+    specs: dict[str, Any] = {
+        "embed": P("fsdp", None), "final_norm": P(),
+        "lead_layers": tuple(unstacked(sparse if plan.kinds[i][1] else dense)
+                             for i in range(plan.lead)),
+        "layers": tuple(dict(sparse if plan.kinds[plan.lead + j][1] else dense)
+                        for j in range(plan.period if plan.periods else 0)),
     }
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P("fsdp", "tp")

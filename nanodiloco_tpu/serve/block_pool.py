@@ -22,6 +22,11 @@ never leak a partial allocation (the scheduler leaves the request
 queued and retries when blocks free up). Single-threaded by design
 (the engine tick thread); ``stats`` reads plain ints and is safe from
 HTTP threads.
+
+Under a mixed configuration (window and full attention layers) the
+blocks are the FULL layers' alone: every full layer's pool is addressed
+by the same block ids, and a sliding layer's ring of ``window + chunk``
+rows a slot is no block and is never allocated here (serve/engine.py).
 """
 
 from __future__ import annotations

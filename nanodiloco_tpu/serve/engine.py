@@ -108,6 +108,21 @@ the new ones. The prefix cache is invalidated at the swap: its K/V was
 computed under the old params. No recompile: the programs are keyed by
 config/shape, and a swap changes neither.
 
+Two kinds of cache (a MIXED configuration, ``cfg.mixed``: window and
+full attention layers, a held share of the experts): full layers keep
+the paged pool and the block tables, one pool a layer, and every
+sliding-window layer holds a ring of ``window + chunk_size`` rows a
+slot that no block table addresses (models/generate.py,
+``init_mixed_serve_cache``); ``blocks_for`` and the block pool count
+the full layers alone, ``kv_stats()`` gives bytes by kind, and the tick
+and chunk programs return the expert layer's counters with the tokens
+(``moe_stats()``). Carried over: the paged bf16 pool, chunked prefill,
+the plain decode tick, release and reuse of a slot (a ring is never
+cleared: its mask is rebuilt from the slot's position). NOT carried
+over, and refused at construction by name: speculation, the prefix
+cache, int8 rows, the unpaged cache, a serving mesh; ``export_kv`` and
+``import_kv`` refuse at the call.
+
 Known divergence, inherited from ``generate`` and narrowed here: dense-
 dispatch token-choice MoE sizes expert capacity from the tokens in the
 call, so a decode tick routes over B slots where ``generate`` routes
@@ -129,13 +144,17 @@ import numpy as np
 from nanodiloco_tpu.models.config import LlamaConfig
 from nanodiloco_tpu.models.generate import (
     decode_slots_fn,
+    decode_slots_mixed_fn,
     decode_slots_paged_fn,
     extract_chunk_fn,
     init_kv_cache,
     init_kv_pool,
+    init_mixed_serve_cache,
     insert_chunk_fn,
     kv_bytes_per_token,
+    mixed_cache_bytes,
     prefill_chunk_fn,
+    prefill_chunk_mixed_fn,
     prefill_chunk_paged_fn,
     verify_slots_fn,
     verify_slots_paged_fn,
@@ -216,6 +235,25 @@ class InferenceEngine:
                 f"kv_dtype must be 'model' or 'int8'; got {kv_dtype!r}"
             )
         self.kv_dtype = None if kv_dtype == "model" else kv_dtype
+        self.mixed = cfg.mixed
+        if self.mixed:
+            # a mixed layer stack's two kinds of cache carry the paged
+            # bf16 pool, chunked prefill and the plain tick; the rest
+            # refuses here, by name, and takes no silent other path
+            for on, what in (
+                (spec_k, "speculation (spec_k > 0: the verify programs)"),
+                (prefix_cache_tokens, "the prefix cache (prefix_cache_tokens > 0)"),
+                (self.kv_dtype == "int8", "kv_dtype='int8' (quantized rows)"),
+                (not kv_block_size, "the unpaged cache (kv_block_size=0)"),
+                (int(tp) > 1, "a serving mesh (tp > 1)"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} is not carried over to a mixed layer stack "
+                        "(window layers in per-slot rings beside the paged "
+                        "pool): serve this configuration with a paged "
+                        "model-dtype cache, no speculation, no prefix cache, "
+                        "tp=1")
         if self.kv_dtype == "int8" and not kv_block_size:
             raise ValueError(
                 "int8 KV storage requires the paged cache; pass "
@@ -306,14 +344,24 @@ class InferenceEngine:
             # serves short requests and validate() rejects the long
             # ones outright (they could never be admitted)
             self.block_pool = BlockPool(nb, bs)
-            self.pool = self._shard_kv(init_kv_pool(cfg, nb, bs, self.kv_dtype))
             self.cache = None
-            self._chunk_paged = prefill_chunk_paged_fn(
-                cfg, self.kv_dtype, self.mesh
-            )
-            self._decode_paged = decode_slots_paged_fn(
-                cfg, self.kv_dtype, self.mesh
-            )
+            if self.mixed:
+                # full layers in the pool, sliding layers in rings a
+                # chunk wider than the window (generate.py says why)
+                self.ring_rows = int(cfg.sliding_window or 0) + self.chunk_size
+                self.pool = init_mixed_serve_cache(
+                    cfg, self.num_slots, self.ring_rows, nb, bs)
+                self._chunk_paged = prefill_chunk_mixed_fn(cfg)
+                self._decode_paged = decode_slots_mixed_fn(cfg)
+            else:
+                self.pool = self._shard_kv(
+                    init_kv_pool(cfg, nb, bs, self.kv_dtype))
+                self._chunk_paged = prefill_chunk_paged_fn(
+                    cfg, self.kv_dtype, self.mesh
+                )
+                self._decode_paged = decode_slots_paged_fn(
+                    cfg, self.kv_dtype, self.mesh
+                )
             # per-slot block tables; the sentinel nb is out of range:
             # reads clamp to causally-dead garbage, writes drop
             self._tables = np.full((b, self.table_blocks), nb, np.int32)
@@ -397,6 +445,16 @@ class InferenceEngine:
         # at production vocab sizes
         self.capture_prefill_logits = False
         self.last_prefill_logits: np.ndarray | None = None
+        # a mixed configuration's expert counters, summed over every
+        # tick and chunk: [held pairs, held experts hit, all pairs]
+        # (moe.sparse_mlp). A second debug probe, OFF by default: with
+        # ``capture_routing`` set, the experts each slot's tokens chose
+        # land in ``routing_log[slot]`` as [L_sparse, tokens, k] pieces,
+        # in position order (the benchmark's check reads them)
+        self.moe_counts = {"prefill_chunk": np.zeros(3, np.int64),
+                           "decode": np.zeros(3, np.int64)}
+        self.capture_routing = False
+        self.routing_log: dict[int, list[np.ndarray]] = {}
         # device-resident copies of the slot state that only changes at
         # admit/release (key_valid alone is [B, S_max] — re-uploading it
         # every tick would put an H2D transfer on the per-token path)
@@ -662,7 +720,17 @@ class InferenceEngine:
                                      self.kv_layout), \
                 trace_span("engine.prefill_chunk", slot=slot,
                            bucket=len(chunk)):
-            if self.paged:
+            if self.mixed:
+                tok, logits, self.pool, counts, chosen = self._chunk_paged(
+                    params, self.pool, self._jarr(self._tables[slot]),
+                    self._jarr(slot, np.int32), *args,
+                )
+                self.moe_counts["prefill_chunk"] += np.asarray(counts, np.int64)
+                if self.capture_routing:
+                    real = int(np.asarray(valid).sum())
+                    self.routing_log.setdefault(slot, []).append(
+                        np.asarray(chosen)[:, 0, :real])
+            elif self.paged:
                 tok, logits, self.pool = self._chunk_paged(
                     params, self.pool,
                     self._jarr(self._tables[slot]), *args,
@@ -933,8 +1001,15 @@ class InferenceEngine:
         out: list[list[int]] = [[] for _ in range(b)]
         for params, slots, active in dispatches:
             with self.accountant.section("decode", 1, self.kv_layout):
+                counts = chosen = None
                 with trace_span("engine.decode_dispatch"):
-                    if self.paged:
+                    if self.mixed:
+                        nxt, self.pool, counts, chosen = self._decode_paged(
+                            params, self.pool, dev["tables"],
+                            tokens, pos, keys,
+                            dev["temp"], dev["topk"], dev["topp"], active,
+                        )
+                    elif self.paged:
                         nxt, self.pool = self._decode_paged(
                             params, self.pool, dev["tables"],
                             tokens, pos, keys,
@@ -952,6 +1027,13 @@ class InferenceEngine:
                 # program, not just its dispatch
                 with trace_span("engine.fetch_tokens"):
                     nxt = np.asarray(nxt)
+                    if counts is not None:
+                        self.moe_counts["decode"] += np.asarray(counts, np.int64)
+                        if self.capture_routing:
+                            chosen = np.asarray(chosen)
+                            for s in slots:
+                                self.routing_log.setdefault(s, []).append(
+                                    chosen[:, s])
             with trace_span("engine.advance"):
                 for s in slots:
                     self._pos[s] += 1
@@ -1130,6 +1212,21 @@ class InferenceEngine:
         self.decode_ticks = 0
         self.hist_spec_tokens_per_tick = Histogram(_SPEC_BUCKETS)
 
+    def moe_stats(self) -> dict | None:
+        """The expert layer's counters over the engine's life (None for
+        a configuration without sparse layers of a mixed stack):
+        token-expert pairs routed to experts held here, held experts
+        (summed over layers, ticks and chunks) that at least one token
+        chose, and all pairs (k a token a layer); the sums, and
+        ``by_program`` the same for chunks and ticks apart."""
+        if not (self.mixed and self.cfg.num_experts):
+            return None
+        names = ("moe_held_pairs", "moe_experts_hit", "moe_pairs")
+        by = {kind: dict(zip(names, (int(x) for x in c)))
+              for kind, c in self.moe_counts.items()}
+        return {**{n: sum(c[n] for c in by.values()) for n in names},
+                "by_program": by}
+
     def release(self, slot: int) -> None:
         self._active[slot] = 0
         self._key_valid[slot] = 0
@@ -1182,6 +1279,10 @@ class InferenceEngine:
         device-side and transfers those, never the slot's whole
         allocation. Read-only: the slot stays live (release is the
         scheduler's call, after the export is in hand)."""
+        if self.mixed:
+            raise ValueError(
+                "export_kv is not carried over to a mixed layer stack: a "
+                "window layer's ring is no block the wire format knows")
         if not self._active[slot]:
             raise ValueError(f"slot {slot} has no live stream to export")
         t0 = time.perf_counter()
@@ -1317,6 +1418,10 @@ class InferenceEngine:
         have run. The prefix cache is NOT populated from shipped rows
         (a requantized payload would hand non-parity rows to unrelated
         local requests)."""
+        if self.mixed:
+            raise ValueError(
+                "import_kv is not carried over to a mixed layer stack: a "
+                "window layer's ring is no block the wire format knows")
         if self._active[slot] or self._prefills[slot] is not None:
             raise ValueError(f"slot {slot} is busy")
         t0 = time.perf_counter()
@@ -1448,14 +1553,28 @@ class InferenceEngine:
         if not self.paged:
             return None
         ps = self.block_pool.stats()
+        if self.mixed:
+            # by kind: the pool's blocks are the full layers' alone, a
+            # sliding layer holds ``ring_rows`` rows a slot
+            by_kind = mixed_cache_bytes(self.cfg, self.pool)
+            kinds = [k for k, _ in (self.cfg.layer_kind(i)
+                                    for i in range(self.cfg.num_hidden_layers))]
+            extra = {
+                "kv_bytes": int(sum(by_kind.values())),
+                "kv_bytes_by_kind": {k: int(v) for k, v in by_kind.items()},
+                "layers_by_kind": {k: kinds.count(k) for k in by_kind},
+                "ring_rows_per_slot": self.ring_rows,
+            }
+        else:
+            extra = {"kv_bytes": int(
+                self.block_pool.num_blocks * self.kv_block_size
+                * kv_bytes_per_token(self.cfg, self.kv_dtype)
+            )}
         out = {
             **ps,
             "kv_dtype": self.kv_dtype or str(self.cfg.dtype),
             "block_evictions": self.kv_block_evictions,
-            "kv_bytes": int(
-                self.block_pool.num_blocks * self.kv_block_size
-                * kv_bytes_per_token(self.cfg, self.kv_dtype)
-            ),
+            **extra,
             "hist_blocks_per_request": self.hist_blocks_per_request.snapshot(),
         }
         if self.tp > 1:
@@ -1535,6 +1654,8 @@ class InferenceEngine:
             base = "dense"
         elif self.kv_dtype == "int8":
             base = "paged-int8"
+        elif self.mixed:
+            base = "paged-rings"  # full layers paged, sliding layers in rings
         else:
             base = "paged"
         return base if self.tp == 1 else f"{base}-tp{self.tp}"

@@ -1308,6 +1308,12 @@ class Scheduler:
             spec = spec_stats()
             if spec is not None:
                 out["spec"] = spec
+        # the expert layer's counters (a held share of sparse layers)
+        moe_stats = getattr(self.backend, "moe_stats", None)
+        if moe_stats is not None:
+            moe = moe_stats()
+            if moe is not None:
+                out["moe"] = moe
         # KV ship traffic (export/import requests, bytes, blocks,
         # seconds) — present only once a replica has actually shipped,
         # so non-disagg stats JSONLs are unchanged

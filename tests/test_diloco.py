@@ -47,6 +47,38 @@ def test_init_workers_identical(diloco4):
         assert tree_max_diff(worker, state.snapshot) == 0.0
 
 
+def test_init_state_lays_the_optimizer_states_as_the_params(diloco4):
+    """From ``init_state`` on, the inner optimizer's moments take the
+    workers' layout (over ``diloco`` and, inside a worker, ``fsdp``) and
+    the outer momentum the snapshot's: zeros inherit no sharding, and left
+    replicated they would stand whole on every device and make the first
+    round compile twice."""
+    state = diloco4.init_state(jax.random.key(0))
+    (adam,) = [s for s in jax.tree.leaves(
+        state.inner_opt_state, is_leaf=lambda x: hasattr(x, "mu")) if hasattr(s, "mu")]
+    (trace,) = [s.trace for s in jax.tree.leaves(
+        state.outer_opt_state, is_leaf=lambda x: hasattr(x, "trace")) if hasattr(s, "trace")]
+    for tree, like in ((adam.mu, state.params), (adam.nu, state.params),
+                       (trace, state.snapshot)):
+        for m, p in zip(jax.tree.leaves(tree), jax.tree.leaves(like), strict=True):
+            assert m.sharding.is_equivalent_to(p.sharding, p.ndim), (m.sharding, p.sharding)
+    assert any("fsdp" in str(p.sharding.spec) for p in jax.tree.leaves(state.snapshot))
+
+
+def test_round_hands_the_state_back_as_init_state_laid_it():
+    """One worker a device: the second call of the fused round compiles
+    nothing (the benchmark's four-chip cell times that executable)."""
+    dl = Diloco(TINY, DilocoConfig(num_workers=4, inner_steps=2, warmup_steps=2,
+                                   total_steps=20, lr=1e-3, grad_accum=2),
+                build_mesh(MeshConfig(diloco=4)))
+    state = dl.init_state(jax.random.key(0))
+    tokens, mask = make_batch(jax.random.key(1), TINY, W=4, accum=2)
+    tokens, mask = jnp.stack([tokens] * 2), jnp.stack([mask] * 2)
+    for _ in range(2):
+        state, _, _ = dl.round_step(state, tokens, mask)
+    assert dl._round_jit._cache_size() == 1
+
+
 def test_inner_steps_diverge_outer_resyncs(diloco4):
     state = diloco4.init_state(jax.random.key(0))
     tokens, mask = make_batch(jax.random.key(1), TINY, W=4, accum=2)

@@ -1,0 +1,251 @@
+"""A mixed layer stack (models/config.py ``mixed``) against the plain
+reference ``benchmark/reference/exaone_moe_ref.py`` on seeded weights:
+a tiny preset with every mechanism of K-EXAONE-236B-A23B (1 dense + 4
+sparse layers, sliding and full attention with a window of 8, 16
+experts top-4 behind a sigmoid gate whose selection bias changes the
+choice, 1 shared expert, q/k norms, RoPE on sliding layers only, a head
+size that is not hidden / heads).
+"""
+
+import dataclasses
+import hashlib
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import correctness_sparse as cs
+from benchmark.reference import exaone_moe_ref as ref
+from nanodiloco_tpu.models import LlamaConfig, init_params
+from nanodiloco_tpu.models.generate import generate
+from nanodiloco_tpu.models.llama import causal_lm_loss, forward, layer_plan, sp_shard_loss
+from nanodiloco_tpu.models.moe import sparse_mlp
+from nanodiloco_tpu.ops.pipeline import _pipeline_setup
+from nanodiloco_tpu.parallel import Diloco, DilocoConfig, MeshConfig, build_mesh
+from nanodiloco_tpu.serve import InferenceEngine
+from nanodiloco_tpu.serve.scheduler import GenRequest
+
+L, G = "sliding_attention", "full_attention"
+TINY = LlamaConfig(
+    vocab_size=128, hidden_size=48, intermediate_size=96, num_hidden_layers=5,
+    num_attention_heads=4, num_key_value_heads=2, explicit_head_dim=16,
+    layer_types=(L, L, L, G, L), sliding_window=8, qk_norm=True, rope_layers="sliding",
+    first_k_dense_replace=1, moe_intermediate_size=32, num_experts=16,
+    num_experts_per_tok=4, num_shared_experts=1, scoring_func="sigmoid",
+    routed_scaling_factor=2.5, moe_dispatch="ragged", loss_chunk=0,
+    initializer_range=0.1)
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = init_params(jax.random.key(0), TINY)
+    # a selection bias large enough to change the top-4 of most tokens
+    p["layers"] = tuple(
+        {**layer, "router_bias": 0.3 * jax.random.normal(
+            jax.random.key(5 + j), layer["router_bias"].shape)}
+        for j, layer in enumerate(p["layers"]))
+    return p
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return jax.random.randint(jax.random.key(1), (2, 40), 0, TINY.vocab_size)
+
+
+@pytest.fixture(autouse=True)
+def _highest(request):
+    if "lowers" in request.node.name:  # the recorded text is the default's
+        yield
+        return
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _ref_forward(cfg, **kw):
+    return jax.jit(lambda w, t: ref.forward(w, t, cs.hyper(cfg), **kw))
+
+
+def test_head_dim_is_given_and_the_bias_changes_the_choice(params, tokens):
+    assert TINY.head_dim == 16 != TINY.hidden_size // TINY.num_attention_heads
+    # a checkpoint's model_config.json sidecar: through JSON and back
+    assert LlamaConfig.from_dict(json.loads(json.dumps(TINY.to_dict()))) == TINY
+    hf = {"head_dim": 16, "hidden_size": 48, "num_attention_heads": 4,
+          "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"}}
+    assert LlamaConfig.from_dict(hf).head_dim == 16
+    assert LlamaConfig.from_dict(hf).rope_theta == 1e6
+    w = cs.reference_weights(params)
+    _, scores = _ref_forward(TINY, with_scores=True)(w, tokens)
+    bias = w["layers"][1]["router_bias"]
+    with_b = jax.lax.top_k(scores[0], 4)[1]
+    without = jax.lax.top_k(scores[0] - bias, 4)[1]
+    assert float(jnp.mean(jnp.sort(with_b) != jnp.sort(without))) > 0.2
+
+
+def test_forward_logits_match_the_reference(params, tokens):
+    got = forward(params, tokens, TINY)
+    want = _ref_forward(TINY)(cs.reference_weights(params), tokens)
+    assert float(jnp.max(jnp.abs(want))) > 1.0
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("loss_chunk,remat", [(0, False), (16, True)])
+def test_loss_gradients_match_the_reference(params, tokens, loss_chunk, remat):
+    cfg = dataclasses.replace(TINY, loss_chunk=loss_chunk, remat=remat)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: causal_lm_loss(p, tokens, cfg)[0]))(params)
+    w = cs.reference_weights(params)
+    want, gref = jax.jit(jax.value_and_grad(
+        lambda w: ref.loss(w, tokens, cs.hyper(cfg))))(w)
+    assert abs(float(loss) - float(want)) < 1e-5
+    for a, b in zip(jax.tree.leaves(cs.reference_weights(grads)), jax.tree.leaves(gref)):
+        np.testing.assert_allclose(a, b, atol=1e-6)
+    assert max(float(jnp.max(jnp.abs(x))) for x in jax.tree.leaves(gref)) > 0.05
+
+
+def test_generate_decodes_as_the_reference_does(params, tokens):
+    prompt = tokens[:, :12]
+    got = generate(params, prompt, TINY, 20)  # positions 12..31: past the window
+    seq = jnp.concatenate([prompt, got], axis=1)
+    logits = _ref_forward(TINY)(cs.reference_weights(params), seq)
+    np.testing.assert_array_equal(got, jnp.argmax(logits[:, 11:-1], -1))
+
+
+def test_engine_chunks_then_ticks_match_the_reference_full_pass(params):
+    """Prefill in chunks of 8, then 40 decode ticks: the ring of 16 rows
+    wraps several times; slot 0 is released and reused without clearing;
+    the chip's share (experts 4..11) is the reference's."""
+    cfg = dataclasses.replace(TINY, experts_held=(4, 8))
+    held = dict(params)
+    held["layers"] = tuple({k: (v[:, 4:12] if k in ("w_gate", "w_up", "w_down") else v)
+                            for k, v in layer.items()} for layer in params["layers"])
+    eng = InferenceEngine(held, cfg, num_slots=2, max_len=128, chunk_size=8,
+                          kv_block_size=4)
+    eng.capture_prefill_logits = True
+    rng = np.random.default_rng(0)
+    w = cs.reference_weights(held)
+    for slot, n_prompt, n_new in ((0, 21, 41), (0, 13, 30), (1, 5, 10)):
+        prompt = rng.integers(0, cfg.vocab_size, n_prompt).tolist()
+        out = [eng.prefill(slot, GenRequest(prompt=tuple(prompt), max_new_tokens=n_new))]
+        served = np.array(eng.last_prefill_logits[0])
+        while len(out) < n_new:
+            out.append(eng.step()[slot][0])
+        want = np.asarray(_ref_forward(cfg, held=(4, 8))(w, jnp.asarray([prompt + out])))
+        np.testing.assert_allclose(served, want[0, n_prompt - 1], atol=2e-5)
+        assert out == [int(t) for t in want[0, n_prompt - 1:-1].argmax(-1)]
+        eng.release(slot)
+    kv = eng.kv_stats()
+    assert kv["ring_rows_per_slot"] == 16 and kv["layers_by_kind"] == {G: 1, L: 4}
+    row = 2 * 2 * 16 * 4  # k and v, 2 KV heads of 16, float32
+    assert kv["kv_bytes_by_kind"] == {G: 2 * 32 * 4 * row, L: 4 * 2 * 16 * row}
+    moe = eng.moe_stats()
+    tokens_run = (21 + 40) + (13 + 29) + (5 + 9)
+    assert moe["moe_pairs"] == 4 * 4 * tokens_run  # k a token a sparse layer
+    assert 0 < moe["moe_experts_hit"] <= moe["moe_held_pairs"] < moe["moe_pairs"]
+    assert eng.compile_counts()["prefill_chunk:paged-rings"] == 1
+
+
+def test_the_shares_add_up(params, tokens):
+    """Over 4 shares of 4 experts, the routed parts summed and the shared
+    expert counted once equal the uncut reference's layer output."""
+    layer = {k: v[0] for k, v in params["layers"][1].items()}  # layer 2
+    h = jax.random.normal(jax.random.key(3), (2, 24, TINY.hidden_size))
+    w = cs.reference_weights(params)["layers"][2]
+    hp = cs.hyper(TINY)
+    mm = lambda x, y: x @ y
+    weights, _ = ref.gate(h, w, hp, mm)
+    want = ref._swiglu(mm, h, w["shared_gate"], w["shared_up"], w["shared_down"])
+    for e in range(16):
+        want = want + weights[..., e, None] * ref._swiglu(
+            mm, h, w["experts_gate"][e], w["experts_up"][e], w["experts_down"][e])
+    shared = {k: v for k, v in layer.items() if k.startswith("shared_")}
+    only_shared = sparse_mlp(
+        dataclasses.replace(TINY, experts_held=(0, 1)),
+        h, {**{k: v for k, v in layer.items() if k not in shared},
+            **{k: jnp.zeros_like(v[:1]) for k, v in layer.items()
+               if k in ("w_gate", "w_up", "w_down")}, **shared})[0]
+    total = only_shared  # the shared expert, counted once
+    pairs = 0
+    for first in range(0, 16, 4):
+        cfg = dataclasses.replace(TINY, experts_held=(first, 4))
+        part = {k: (v[first:first + 4] if k in ("w_gate", "w_up", "w_down") else v)
+                for k, v in layer.items() if k not in shared}
+        y, counters, _ = sparse_mlp(cfg, h, part)
+        total = total + y
+        pairs += int(counters[0])
+    assert pairs == 4 * 2 * 24  # every pair is held by exactly one share
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+def test_48_layers_compile_as_one_period_does():
+    kinds = tuple(G if i % 4 == 3 else L for i in range(48))
+    plan = layer_plan(dataclasses.replace(TINY, num_hidden_layers=48, layer_types=kinds))
+    assert (plan.lead, plan.period, plan.periods) == (4, 4, 11)
+    assert layer_plan(TINY).lead == 1 and layer_plan(TINY).period == 4
+
+
+def test_the_trainer_steps_a_mixed_configuration(params):
+    cfg = dataclasses.replace(TINY, remat=True, loss_chunk=16)
+    dl = Diloco(cfg, DilocoConfig(num_workers=2, inner_steps=2, lr=3e-3, warmup_steps=1,
+                                  total_steps=50),
+                build_mesh(MeshConfig(diloco=2), devices=jax.devices()[:2]))
+    state = dl.init_state(jax.random.key(0), params=params)
+    tok = jnp.broadcast_to(jax.random.randint(jax.random.key(2), (1, 1, 2, 32), 0, 128),
+                           (2, 1, 2, 32))
+    losses = []
+    for _ in range(4):
+        state, loss = dl.inner_step(state, tok, jnp.ones_like(tok))
+        losses.append(float(loss[0]))
+    state = dl.outer_step(state)
+    assert losses[-1] < losses[0] and np.isfinite(losses).all()
+
+
+def _engine(cfg=TINY, **kw):
+    kw = {"num_slots": 2, "max_len": 64, "chunk_size": 8, "kv_block_size": 4, **kw}
+    return InferenceEngine(init_params(jax.random.key(0), cfg), cfg, **kw)
+
+
+REFUSED = {
+    "moe_dispatch_dense": (lambda: dataclasses.replace(TINY, moe_dispatch="dense"),
+                           "moe_dispatch='dense'"),
+    "experts_choose": (lambda: dataclasses.replace(TINY, router_type="experts_choose"),
+                       "experts_choose"),
+    "flash": (lambda: dataclasses.replace(TINY, attention_impl="flash"), "flash"),
+    "ring": (lambda: dataclasses.replace(TINY, attention_impl="ring"), "ring"),
+    "pipeline": (lambda: _pipeline_setup(TINY, 16, None), "pipeline stages"),
+    "sp_loss": (lambda: sp_shard_loss(None, None, TINY, None, "sp"),
+                "sequence-parallel loss"),
+    "ep": (lambda: Diloco(TINY, DilocoConfig(num_workers=1),
+                          build_mesh(MeshConfig(ep=2), devices=jax.devices()[:2])),
+           "ep=1"),
+    "speculation": (lambda: _engine(spec_k=2), "speculation"),
+    "prefix_cache": (lambda: _engine(prefix_cache_tokens=64), "prefix cache"),
+    "int8": (lambda: _engine(kv_dtype="int8"), "int8"),
+    "unpaged": (lambda: _engine(kv_block_size=0), "unpaged cache"),
+    "serving_mesh": (lambda: _engine(tp=2), "serving mesh"),
+    "export_kv": (lambda: _engine().export_kv(0), "export_kv"),
+    "import_kv": (lambda: _engine().import_kv(0, None, None), "import_kv"),
+}
+
+
+@pytest.mark.parametrize("path", sorted(REFUSED))
+def test_a_path_that_is_not_carried_over_refuses_by_name(path):
+    call, named = REFUSED[path]
+    with pytest.raises(ValueError, match=named):
+        call()
+
+
+def test_a_dense_configuration_lowers_to_the_program_it_had():
+    """sha256 of ``forward``'s lowered text for a dense toy, taken on the
+    commit before the mixed stack (b70a8d0) under jax 0.9.0: the fields
+    of a mixed configuration leave a dense one's program alone."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded text is jax 0.9.0's")
+    cfg = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
+                      num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2)
+    assert not cfg.mixed
+    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    text = jax.jit(lambda p, t: forward(p, t, cfg)).lower(
+        shapes, jax.ShapeDtypeStruct((2, 32), jnp.int32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest().startswith("4cc0a5e8fbc000fa")
