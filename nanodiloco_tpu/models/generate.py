@@ -1386,7 +1386,7 @@ def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slo
     the rows' block tables; ``ring_slot`` is None where row b IS ring
     slot b (the tick: B = slots) or the traced ring slot of the one row
     (a prefill chunk: B = 1); ``active`` [B] drops dead rows' writes.
-    Returns (final-normed hidden [B, T, d], cache, counters int32[3],
+    Returns (final-normed hidden [B, T, d], cache, counters int32[4],
     chosen experts [L_sparse, B, T, k])."""
     cdt = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
@@ -1468,7 +1468,7 @@ def prefill_chunk_mixed_fn(cfg: LlamaConfig):
     """``prefill_chunk_paged_fn`` over both kinds of cache: jitted
     ``(params, cache, table [max_blocks] i32, slot, chunk [1,C],
     chunk_valid [1,C], pos, last_idx, key_data, temperature, top_k,
-    top_p) -> (token, logits [1,V], cache, counters int32[3], chosen
+    top_p) -> (token, logits [1,V], cache, counters int32[4], chosen
     experts [L_sparse, 1, C, k])``."""
 
     def run(params, cache, table, slot, chunk, chunk_valid, pos, last_idx,
@@ -1489,7 +1489,7 @@ def decode_slots_mixed_fn(cfg: LlamaConfig):
     """``decode_slots_paged_fn`` over both kinds of cache: jitted
     ``(params, cache, tables [B, max_blocks] i32, tokens [B], pos [B],
     key_data [B,2] u32, temperature [B], top_k [B], top_p [B],
-    active [B]) -> (next_tokens [B], cache, counters int32[3], chosen
+    active [B]) -> (next_tokens [B], cache, counters int32[4], chosen
     experts [L_sparse, B, 1, k])``: one tick advancing every slot."""
 
     def run(params, cache, tables, tokens, pos, key_data,

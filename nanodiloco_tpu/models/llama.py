@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from nanodiloco_tpu.models.config import LlamaConfig
+from nanodiloco_tpu.models.moe import COUNTERS
 
 Params = dict[str, Any]
 
@@ -195,7 +196,7 @@ def run_layers(cfg: LlamaConfig, params: Params, x, body, cache=None):
     ``layer_plan``. ``body(x, layer, kind, c) -> (x, c, counters, rec)``
     is one layer: ``layer`` its weights, ``kind`` its static (attention
     kind, sparse?), ``c`` its own cache entry or None, ``counters`` the
-    int32[3] of ``moe.sparse_mlp`` (zeros for a dense layer), summed
+    int32[4] of ``moe.sparse_mlp`` (zeros for a dense layer), summed
     here over the layers, ``rec`` an array the layer hands out (the
     experts it chose) or None. ``cache`` is None or ``{"lead": one entry
     a leading layer, "period": one entry a layer of the period, each
@@ -204,7 +205,7 @@ def run_layers(cfg: LlamaConfig, params: Params, x, body, cache=None):
     (x, cache, summed counters, the layers' recs in layer order)."""
     plan = layer_plan(cfg)
     lead_c = list(cache["lead"]) if cache is not None else [None] * plan.lead
-    counters = jnp.zeros((3,), jnp.int32)
+    counters = jnp.zeros((len(COUNTERS),), jnp.int32)
     recs = []
     for i in range(plan.lead):
         x, lead_c[i], n, rec = body(x, params["lead_layers"][i], plan.kinds[i], lead_c[i])
@@ -475,7 +476,7 @@ def _decoder_layer(
 
 def mixed_mlp_block(cfg: LlamaConfig, x, layer: Params, valid=None):
     """``mlp_block`` of a mixed configuration's layer, dense or sparse by
-    the weights it holds: (x, counters int32[3], chosen experts
+    the weights it holds: (x, counters int32[4], chosen experts
     [B, S, k] or None) with the sparse layer's counters and choice
     (``moe.sparse_mlp``), zeros and None for a dense one. Shared by the
     training forward and the cached programs."""
@@ -490,7 +491,7 @@ def mixed_mlp_block(cfg: LlamaConfig, x, layer: Params, valid=None):
         gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
         up = h @ layer["w_up"].astype(cdt)
         return (x + (gate * up) @ layer["w_down"].astype(cdt),
-                jnp.zeros((3,), jnp.int32), None)
+                jnp.zeros((len(COUNTERS),), jnp.int32), None)
 
 
 def mlp_block(

@@ -46,6 +46,19 @@ default and the ep>1 path — every shipped config with E ≤ 8 sits well
 inside its regime (``configs/llama_moe_64e.json`` ships the 64-expert
 ragged shape).
 
+A held share of the experts (a mixed configuration, ``sparse_mlp``): a
+chip that holds ``count`` of the router's experts sees about
+``count / num_experts`` of the k*T token-expert pairs, sorted to the
+front. ``_ragged_mlp`` then runs its gather, grouped products, select
+and scatter-add over the first ``short_rows`` sorted rows alone (twice
+the expected held pairs, in an odd number of 128s) whenever the held
+pairs fit them, and over all k*T rows otherwise (a ``lax.cond`` on
+``sum(group_sizes)``): the same pairs, groups and weights either way,
+nothing dropped. Where all experts are held there is no short path and
+no conditional.
+``sparse_mlp`` returns four counters (``COUNTERS``); the fourth says how
+often the short path was taken. On the chip (PERF.md, PR 28).
+
 Capacity factor (measured, round 5 — phase "cf", fixed 120-step budget
 on the pylib corpus, 8 experts top-2, ``runs/moe_evidence_r5.jsonl``):
 final train loss is FLAT across cf ∈ {1.0, 1.25, 1.5, 2.0}
@@ -60,13 +73,28 @@ longer budgets (capacity pressure grows with batch·seq).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from nanodiloco_tpu.models.config import LlamaConfig
 
+# The short path of ``_ragged_mlp`` takes the first ``short_rows`` rows of
+# the sorted pairs: SHORT_ROWS_FACTOR times the pairs a chip expects to
+# hold (k*T * held / router width), in an ODD number of SHORT_ROWS_TILE
+# rows. Settled by two sweeps on the chip (PERF.md, PR 28): the TPU
+# compiler's grouped-product kernel tiles its rows by the largest power
+# of two up to 512 that divides them and computes a whole tile for each
+# (tile, group) it meets, so at some thirty rows a group 1,152 rows
+# (tiles of 128) take 3.3 ms a layer where 1,024 (tiles of 512) take 4.8
+# and all 4,096 take 5.4; among odd counts the time hardly moves with the
+# factor (640 rows 3.24, 1,408 rows 3.37), so the factor buys headroom.
+SHORT_ROWS_FACTOR = 2
+SHORT_ROWS_TILE = 128
 
-import math
+# What ``sparse_mlp``'s int32 counter vector holds, in order.
+COUNTERS = ("moe_held_pairs", "moe_experts_hit", "moe_pairs", "moe_short_path")
 
 
 def expert_capacity(cfg: LlamaConfig, n_tokens: int) -> int:
@@ -157,10 +185,24 @@ def _experts_choose(
     return y, jnp.zeros((), jnp.float32), dropped
 
 
+def short_rows(cfg: LlamaConfig, n_pairs: int) -> int | None:
+    """Rows the short path of ``_ragged_mlp`` takes of ``n_pairs`` = k*T
+    sorted token-expert pairs, or None where there is no short path:
+    ``SHORT_ROWS_FACTOR`` times the pairs expected at held experts
+    (``n_pairs`` x held / router width), rounded up to an odd number of
+    ``SHORT_ROWS_TILE`` rows. None where that passes half of
+    ``n_pairs``: every configuration that holds all its experts, and a
+    share too large for the short path to save much."""
+    expected = n_pairs * cfg.held_experts[1] / cfg.num_experts
+    tiles = max(1, math.ceil(SHORT_ROWS_FACTOR * expected / SHORT_ROWS_TILE))
+    cap = SHORT_ROWS_TILE * (tiles + 1 - tiles % 2)
+    return cap if 2 * cap <= n_pairs else None
+
+
 def _ragged_mlp(
     cfg: LlamaConfig, x: jax.Array, topk_p: jax.Array, topk_e: jax.Array,
     layer: dict, valid_t: jax.Array | None,
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Sorted/ragged token-choice dispatch (the Mixtral/megablocks shape;
     implements the large-E alternative the module docstring previously
     only design-documented). Flatten the [T, k] (token, slot) routing
@@ -168,11 +210,12 @@ def _ragged_mlp(
     tokens are a contiguous run, and run the SwiGLU as three
     ``jax.lax.ragged_dot`` grouped matmuls with exact per-expert group
     sizes — no capacity, no dropped tokens, no one-hot [T, E, C] padding
-    FLOPs. All shapes stay static ([k·T, ...]); the data dependence is
+    FLOPs. All shapes stay static; the data dependence is
     confined to the gather/scatter indices and the group-size vector,
     which is what keeps it XLA-compilable. x: [T, d]; topk_p/topk_e:
     [T, k] combine weights / expert ids over the ROUTER's experts.
-    Returns (y [T, d], group sizes [count] int32).
+    Returns (y [T, d], group sizes [count] int32, short int32: 1 where
+    this call took the short path).
 
     The chip's share (``cfg.held_experts`` = (first, count); all of them
     by default): ``layer`` holds weights for experts first..first+count-1
@@ -181,6 +224,17 @@ def _ragged_mlp(
     their output is dropped), and ``y`` is the held experts' part of the
     layer's output. ``topk_p`` is used as given: the caller normalised
     it over all k chosen, held or not.
+
+    The short path: held pairs sort first, so while they number at most
+    ``short_rows`` the first that many sorted rows are the whole of the
+    held work, and the gather, the grouped products, the select and the
+    scatter-add run over those rows alone (the same groups over fewer
+    trailing rows); a ``lax.cond`` on ``sum(group_sizes)`` takes all
+    k*T rows otherwise, so no pair is ever dropped. Where
+    ``short_rows`` is None (all experts held) there is one body over
+    k*T rows and no conditional. Under a ``vmap`` (DiLoCo's worker
+    axis) the conditional becomes a select and both branches run:
+    exact still, and as slow as before.
 
     Padding tokens (valid_t = 0) are treated as routed elsewhere: no
     group, no output. Numerics vs dense dispatch at non-binding
@@ -206,20 +260,32 @@ def _ragged_mlp(
         held_sorted = held[order]
         rows = tok_flat[order]
 
-    with jax.named_scope("moe_experts"):
-        xg = x[rows]                                         # [kT, d] gather
+    def experts(rows, w_sorted, held_sorted):
+        xg = x[rows]                                         # [n, d] gather
         gate = jax.nn.silu(
             jax.lax.ragged_dot(xg, layer["w_gate"].astype(cdt), group_sizes)
         )
         up = jax.lax.ragged_dot(xg, layer["w_up"].astype(cdt), group_sizes)
         out = jax.lax.ragged_dot(
             gate * up, layer["w_down"].astype(cdt), group_sizes
-        )                                                    # [kT, d]
+        )                                                    # [n, d]
         # a select, not a product by 0: a row in no group holds whatever
         # the grouped product left there
         out = jnp.where(held_sorted[:, None], out * w_sorted.astype(cdt)[:, None], 0)
-        y = jnp.zeros((t, d), cdt).at[rows].add(out)
-    return y, group_sizes
+        return jnp.zeros((t, d), cdt).at[rows].add(out)
+
+    cap = short_rows(cfg, t * k)
+    with jax.named_scope("moe_experts"):
+        if cap is None:
+            y, short = experts(rows, w_sorted, held_sorted), jnp.zeros((), jnp.int32)
+        else:
+            fits = jnp.sum(group_sizes) <= cap
+            y = jax.lax.cond(
+                fits,
+                lambda: experts(rows[:cap], w_sorted[:cap], held_sorted[:cap]),
+                lambda: experts(rows, w_sorted, held_sorted))
+            short = fits.astype(jnp.int32)
+    return y, group_sizes, short
 
 
 def route(cfg: LlamaConfig, x: jax.Array, layer: dict):
@@ -251,16 +317,17 @@ def sparse_mlp(cfg: LlamaConfig, h: jax.Array, layer: dict,
     dropped token) and the shared experts (one SwiGLU of width
     ``num_shared_experts * expert_width`` that every token passes).
     h [B, S, d] normed hidden states; ``valid`` [B, S] marks real
-    tokens. Returns (out [B, S, d], counters int32[3], the chosen
-    experts [B, S, k] int32); the counters: token-expert pairs routed to
-    held experts, held experts at least one token chose, all pairs (k a
-    real token)."""
+    tokens. Returns (out [B, S, d], counters int32[4], the chosen
+    experts [B, S, k] int32); the counters (``COUNTERS`` names them):
+    token-expert pairs routed to held experts, held experts at least one
+    token chose, all pairs (k a real token), and 1 where the grouped
+    products took the short path (``_ragged_mlp``)."""
     b, s, d = h.shape
     x = h.reshape(b * s, d)
     valid_t = None if valid is None else valid.reshape(b * s)
     with jax.named_scope("moe_route"):
         w, topk_e = route(cfg, x, layer)
-    y, group_sizes = _ragged_mlp(cfg, x, w, topk_e, layer, valid_t)
+    y, group_sizes, short = _ragged_mlp(cfg, x, w, topk_e, layer, valid_t)
     if "shared_gate" in layer:
         with jax.named_scope("moe_shared"):
             cdt = x.dtype
@@ -269,7 +336,7 @@ def sparse_mlp(cfg: LlamaConfig, h: jax.Array, layer: dict,
             y = y + (gate * up) @ layer["shared_down"].astype(cdt)
     n_tok = jnp.int32(b * s) if valid_t is None else jnp.sum(valid_t > 0).astype(jnp.int32)
     counters = jnp.stack([jnp.sum(group_sizes), jnp.sum(group_sizes > 0).astype(jnp.int32),
-                          n_tok * cfg.num_experts_per_tok])
+                          n_tok * cfg.num_experts_per_tok, short])
     return y.reshape(b, s, d), counters, topk_e.reshape(b, s, -1)
 
 
@@ -378,10 +445,10 @@ def moe_mlp(
     if cfg.moe_dispatch == "ragged":
         # exact-sized grouped matmuls, no capacity, nothing dropped;
         # `keep` stays the full assignment for the shared stats below
-        y, _ = _ragged_mlp(
+        y = _ragged_mlp(
             cfg, x, topk_p, topk_e, layer,
             None if valid is None else valid.reshape(t),
-        )
+        )[0]
         keep = onehot
     else:
         # per-(token, slot) position in the chosen expert's queue: a

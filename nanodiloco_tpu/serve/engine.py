@@ -159,6 +159,7 @@ from nanodiloco_tpu.models.generate import (
     verify_slots_fn,
     verify_slots_paged_fn,
 )
+from nanodiloco_tpu.models.moe import COUNTERS
 from nanodiloco_tpu.obs.devtime import DispatchAccountant
 from nanodiloco_tpu.obs.telemetry import Histogram
 from nanodiloco_tpu.obs.tracer import trace_span
@@ -446,13 +447,14 @@ class InferenceEngine:
         self.capture_prefill_logits = False
         self.last_prefill_logits: np.ndarray | None = None
         # a mixed configuration's expert counters, summed over every
-        # tick and chunk: [held pairs, held experts hit, all pairs]
-        # (moe.sparse_mlp). A second debug probe, OFF by default: with
+        # tick and chunk: [held pairs, held experts hit, all pairs,
+        # sparse-layer calls that took the short path] (moe.COUNTERS,
+        # moe.sparse_mlp). A second debug probe, OFF by default: with
         # ``capture_routing`` set, the experts each slot's tokens chose
         # land in ``routing_log[slot]`` as [L_sparse, tokens, k] pieces,
         # in position order (the benchmark's check reads them)
-        self.moe_counts = {"prefill_chunk": np.zeros(3, np.int64),
-                           "decode": np.zeros(3, np.int64)}
+        self.moe_counts = {"prefill_chunk": np.zeros(len(COUNTERS), np.int64),
+                           "decode": np.zeros(len(COUNTERS), np.int64)}
         self.capture_routing = False
         self.routing_log: dict[int, list[np.ndarray]] = {}
         # device-resident copies of the slot state that only changes at
@@ -1217,14 +1219,16 @@ class InferenceEngine:
         a configuration without sparse layers of a mixed stack):
         token-expert pairs routed to experts held here, held experts
         (summed over layers, ticks and chunks) that at least one token
-        chose, and all pairs (k a token a layer); the sums, and
-        ``by_program`` the same for chunks and ticks apart."""
+        chose, all pairs (k a token a layer), and the sparse-layer calls
+        whose grouped products took the short path (a tick or a chunk
+        makes one call a sparse layer; always 0 where all experts are
+        held); the sums, and ``by_program`` the same for chunks and
+        ticks apart."""
         if not (self.mixed and self.cfg.num_experts):
             return None
-        names = ("moe_held_pairs", "moe_experts_hit", "moe_pairs")
-        by = {kind: dict(zip(names, (int(x) for x in c)))
+        by = {kind: dict(zip(COUNTERS, (int(x) for x in c)))
               for kind, c in self.moe_counts.items()}
-        return {**{n: sum(c[n] for c in by.values()) for n in names},
+        return {**{n: sum(c[n] for c in by.values()) for n in COUNTERS},
                 "by_program": by}
 
     def release(self, slot: int) -> None:

@@ -19,13 +19,20 @@ import pytest
 from benchmark import correctness_sparse as cs
 from benchmark.reference import exaone_moe_ref as ref
 from nanodiloco_tpu.models import LlamaConfig, init_params
-from nanodiloco_tpu.models.generate import generate
+from nanodiloco_tpu.models.generate import (
+    decode_slots_paged_fn,
+    generate,
+    init_kv_pool,
+    init_mixed_serve_cache,
+    prefill_chunk_mixed_fn,
+    prefill_chunk_paged_fn,
+)
 from nanodiloco_tpu.models.llama import causal_lm_loss, forward, layer_plan, sp_shard_loss
 from nanodiloco_tpu.models.moe import sparse_mlp
 from nanodiloco_tpu.ops.pipeline import _pipeline_setup
 from nanodiloco_tpu.parallel import Diloco, DilocoConfig, MeshConfig, build_mesh
 from nanodiloco_tpu.serve import InferenceEngine
-from nanodiloco_tpu.serve.scheduler import GenRequest
+from nanodiloco_tpu.serve.scheduler import GenRequest, Scheduler
 
 L, G = "sliding_attention", "full_attention"
 TINY = LlamaConfig(
@@ -61,6 +68,15 @@ def _highest(request):
         return
     with jax.default_matmul_precision("highest"):
         yield
+
+
+def _held(params, first, count):
+    """The weights of a chip that holds experts first..first+count-1."""
+    out = dict(params)
+    out["layers"] = tuple(
+        {k: (v[:, first:first + count] if k in ("w_gate", "w_up", "w_down") else v)
+         for k, v in layer.items()} for layer in params["layers"])
+    return out
 
 
 def _ref_forward(cfg, **kw):
@@ -117,9 +133,7 @@ def test_engine_chunks_then_ticks_match_the_reference_full_pass(params):
     wraps several times; slot 0 is released and reused without clearing;
     the chip's share (experts 4..11) is the reference's."""
     cfg = dataclasses.replace(TINY, experts_held=(4, 8))
-    held = dict(params)
-    held["layers"] = tuple({k: (v[:, 4:12] if k in ("w_gate", "w_up", "w_down") else v)
-                            for k, v in layer.items()} for layer in params["layers"])
+    held = _held(params, 4, 8)
     eng = InferenceEngine(held, cfg, num_slots=2, max_len=128, chunk_size=8,
                           kv_block_size=4)
     eng.capture_prefill_logits = True
@@ -236,16 +250,146 @@ def test_a_path_that_is_not_carried_over_refuses_by_name(path):
         call()
 
 
-def test_a_dense_configuration_lowers_to_the_program_it_had():
-    """sha256 of ``forward``'s lowered text for a dense toy, taken on the
-    commit before the mixed stack (b70a8d0) under jax 0.9.0: the fields
-    of a mixed configuration leave a dense one's program alone."""
+DENSE_TOY = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
+                        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2)
+# softmax top-2 of 4 experts, all held, grouped products: moe_mlp -> _ragged_mlp
+RAGGED_TOY = dataclasses.replace(DENSE_TOY, num_experts=4, num_experts_per_tok=2,
+                                 moe_dispatch="ragged")
+_S = jax.ShapeDtypeStruct
+
+
+def _lower_toy(cfg, program):
+    """One of the five programs the dense cells run, at a toy's size."""
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    tok = _S((2, 32), i32)
+    if program == "forward":
+        return jax.jit(lambda p, t: forward(p, t, cfg)).lower(shapes, tok)
+    if program == "loss_gradient":
+        return jax.jit(jax.grad(lambda p, t: causal_lm_loss(p, t, cfg)[0])).lower(shapes, tok)
+    if program == "fused_round":
+        dl = Diloco(cfg, DilocoConfig(num_workers=1, inner_steps=2, lr=1e-3, warmup_steps=1,
+                                      total_steps=8),
+                    build_mesh(MeshConfig(diloco=1), devices=jax.devices()[:1]))
+        state = jax.eval_shape(lambda: dl.init_state(jax.random.key(0)))
+        return dl._round_jit.lower(state, _S((2, 1, 1, 2, 32), i32), _S((2, 1, 1, 2, 32), i32))
+    pool = jax.eval_shape(lambda: init_kv_pool(cfg, 16, 4))
+    if program == "paged_chunk":
+        return prefill_chunk_paged_fn(cfg).lower(
+            shapes, pool, _S((8,), i32), _S((1, 8), i32), _S((1, 8), i32), _S((), i32),
+            _S((), i32), _S((2,), u32), _S((), f32), _S((), i32), _S((), f32))
+    assert program == "paged_tick"
+    return decode_slots_paged_fn(cfg).lower(
+        shapes, pool, _S((2, 8), i32), _S((2,), i32), _S((2,), i32), _S((2, 2), u32),
+        _S((2,), f32), _S((2,), i32), _S((2,), f32), _S((2,), i32))
+
+
+# sha256 of the lowered text under jax 0.9.0. "dense": the programs of
+# the dense cells; forward's was taken on the commit before the mixed
+# stack (b70a8d0), the rest on the commit before the short path of
+# models/moe.py (98f3879), as were "ragged"'s: a configuration that
+# holds all its experts has no short path and lowers as it did.
+LOWERED = {
+    ("dense", "forward"): "4cc0a5e8fbc000fa",
+    ("dense", "loss_gradient"): "0a25538edc8f2e24",
+    ("dense", "paged_chunk"): "22fd6380ece8aa8e",
+    ("dense", "paged_tick"): "c147e342d1bfd11d",
+    ("dense", "fused_round"): "2236461a9273367d",
+    ("ragged", "forward"): "a67ab44a2c55b2b3",
+    ("ragged", "loss_gradient"): "1dfd52542ec6df87",
+    ("ragged", "paged_chunk"): "4c6051755649e1bf",
+    ("ragged", "paged_tick"): "0ae473b63bb9d43b",
+    ("ragged", "fused_round"): "d51b6466d5cc44df",
+}
+
+
+@pytest.mark.parametrize("toy,program", sorted(LOWERED))
+def test_a_dense_configuration_lowers_to_the_program_it_had(toy, program):
+    """The fields of a mixed configuration leave a dense one's programs
+    alone, and the short path of the grouped products those of a
+    configuration that holds all its experts."""
     if jax.__version__ != "0.9.0":
         pytest.skip("the recorded text is jax 0.9.0's")
-    cfg = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
-                      num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2)
+    cfg = {"dense": DENSE_TOY, "ragged": RAGGED_TOY}[toy]
     assert not cfg.mixed
-    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
-    text = jax.jit(lambda p, t: forward(p, t, cfg)).lower(
-        shapes, jax.ShapeDtypeStruct((2, 32), jnp.int32)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest().startswith("4cc0a5e8fbc000fa")
+    text = _lower_toy(cfg, program).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest().startswith(LOWERED[toy, program])
+    if program in ("forward", "loss_gradient", "fused_round"):  # sampling has a case of its own
+        assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
+# -- the short path of the grouped products, through the serve programs -----
+
+# the chip's share as the benchmark's cell has it: an eighth of the experts
+SHARE = dataclasses.replace(TINY, experts_held=(6, 2))
+
+
+def _grouped_rows(jaxpr, under=()):
+    """[(the conditionals' branch indices above it, lhs rows)] of every
+    grouped product in ``jaxpr`` and the jaxprs inside it."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name.startswith("ragged_dot"):
+            found.append((under, e.invars[0].aval.shape[0]))
+        for name, val in e.params.items():
+            subs = val if isinstance(val, (tuple, list)) else (val,)
+            for i, sub in enumerate(subs):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    branch = under + (i,) if e.primitive.name == "cond" else under
+                    found += _grouped_rows(inner, branch)
+    return found
+
+
+@pytest.mark.parametrize("cfg,chunk,want", [
+    # 4 x 64 pairs, 32 expected here: 128 rows in the true branch, 256 in the other
+    (SHARE, 64, {(1,): [128] * 12, (0,): [256] * 12}),
+    # 4 x 32 pairs: 128 rows would be all of them, so one body and no conditional
+    (SHARE, 32, {(): [128] * 12}),
+    (TINY, 64, {(): [256] * 12}),  # all experts held
+], ids=["a_share", "a_share_short_chunk", "all_held"])
+def test_the_chunk_program_holds_both_row_counts(cfg, chunk, want):
+    shapes = jax.eval_shape(lambda: _held(init_params(jax.random.key(0), TINY),
+                                          *cfg.held_experts))
+    cache = jax.eval_shape(lambda: init_mixed_serve_cache(cfg, 2, 8 + chunk, 32, 4))
+    i32, f32 = jnp.int32, jnp.float32
+    jaxpr = jax.make_jaxpr(prefill_chunk_mixed_fn(cfg))(
+        shapes, cache, _S((32,), i32), _S((), i32), _S((1, chunk), i32),
+        _S((1, chunk), i32), _S((), i32), _S((), i32), _S((2,), jnp.uint32),
+        _S((), f32), _S((), i32), _S((), f32))
+    got = {}
+    for under, rows in _grouped_rows(jaxpr.jaxpr):
+        got.setdefault(under, []).append(rows)
+    assert got == want  # three products a sparse layer, four layers
+
+
+def test_the_short_path_is_counted_by_program_kind(params):
+    """Chunks of 64 tokens take the short path in all four sparse layers
+    (some 32 of 256 pairs are held of 128 rows), a tick of 2 slots has
+    none; the scheduler's stats carry the count by program kind, and the
+    served logits are the reference's for this share."""
+    held = _held(params, 6, 2)
+    eng = InferenceEngine(held, SHARE, num_slots=2, max_len=192, chunk_size=64,
+                          kv_block_size=4)
+    eng.capture_prefill_logits = True
+    sched = Scheduler(eng)
+    prompt = np.random.default_rng(0).integers(0, SHARE.vocab_size, 128).tolist()
+    ticket = sched.submit(GenRequest(prompt=tuple(prompt), max_new_tokens=6))
+    for _ in range(20):
+        if sched.tick() == 0 and ticket.done():
+            break
+    out = ticket.result["tokens"]
+    want = np.asarray(_ref_forward(SHARE, held=(6, 2))(
+        cs.reference_weights(held), jnp.asarray([prompt + out])))
+    np.testing.assert_allclose(np.array(eng.last_prefill_logits[0]), want[0, 127], atol=2e-5)
+    assert out == [int(t) for t in want[0, 127:-1].argmax(-1)]
+    moe = sched.stats()["moe"]
+    assert moe == eng.moe_stats()
+    chunks, ticks = moe["by_program"]["prefill_chunk"], moe["by_program"]["decode"]
+    assert chunks["moe_short_path"] == 4 * 2  # two chunks, four sparse layers each
+    assert chunks["moe_pairs"] == 4 * 4 * 128
+    assert 0 < chunks["moe_held_pairs"] <= 2 * 4 * 128  # under the rows the short path takes
+    assert ticks["moe_short_path"] == 0 and ticks["moe_pairs"] == 4 * 4 * 5
+    assert moe["moe_short_path"] == 8
+    # a configuration that holds all its experts never counts one
+    assert _engine().moe_stats()["moe_short_path"] == 0

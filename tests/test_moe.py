@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nanodiloco_tpu.models import LlamaConfig, causal_lm_loss, forward, init_params
+from nanodiloco_tpu.models import LlamaConfig, causal_lm_loss, forward, init_params, moe
+from nanodiloco_tpu.models.moe import _ragged_mlp, short_rows
 from nanodiloco_tpu.parallel import Diloco, DilocoConfig, MeshConfig, build_mesh
 
 MOE = LlamaConfig(
@@ -589,3 +590,122 @@ def test_ragged_rejected_at_diloco_layer_on_ep_mesh():
                         total_steps=10, lr=1e-3)
     with pytest.raises(ValueError, match="replicated experts"):
         Diloco(cfg, dcfg, build_mesh(MeshConfig(diloco=2, ep=2)))
+
+
+# -- the short path of _ragged_mlp (a held share of the experts) -------------
+
+# 2 of 16 experts held, k = 4, 128 tokens: 512 sorted pairs of which 64
+# are expected here, so the short path takes the first 128 rows
+HELD = LlamaConfig(
+    vocab_size=96, hidden_size=32, intermediate_size=64, num_attention_heads=4,
+    num_hidden_layers=2, first_k_dense_replace=1, moe_intermediate_size=16,
+    num_experts=16, num_experts_per_tok=4, moe_dispatch="ragged",
+    experts_held=(6, 2),
+)
+_T, _K = 128, 4
+
+
+def _held_case(n_held, pad=0):
+    """x, combine weights, a hand-made choice with exactly ``n_held`` of
+    the 512 pairs at held experts 6 and 7 (at most two a token, the rest
+    at experts held elsewhere), held weights, and a validity mask whose
+    first ``pad`` tokens are padding."""
+    ks = jax.random.split(jax.random.key(n_held), 6)
+    x = jax.random.normal(ks[0], (_T, 32))
+    topk_p = jax.random.uniform(ks[1], (_T, _K), minval=0.1)
+    elsewhere = np.array([0, 3, 9, 15])
+    topk_e = np.tile(elsewhere, (_T, 1))
+    for i in range(n_held):
+        t, second = i % _T, i // _T
+        topk_e[t, second] = (7 - t % 2) if second else 6 + t % 2
+    layer = {"w_gate": 0.3 * jax.random.normal(ks[2], (2, 32, 16)),
+             "w_up": 0.3 * jax.random.normal(ks[3], (2, 32, 16)),
+             "w_down": 0.3 * jax.random.normal(ks[4], (2, 16, 32))}
+    valid = jnp.asarray(np.arange(_T) >= pad, jnp.int32)
+    return x, topk_p, jnp.asarray(topk_e, jnp.int32), layer, valid
+
+
+def _full_rows(monkeypatch):
+    """The parent's one body over all k*T rows: the reference."""
+    monkeypatch.setattr(moe, "short_rows", lambda cfg, n: None)
+
+
+SHORT_CASES = {
+    # name: (pairs at held experts, padded tokens, held pairs that count, short path taken)
+    "under": (60, 0, 60, 1),
+    "at_cap": (128, 0, 128, 1),
+    "one_over": (129, 0, 129, 0),
+    "far_over": (256, 0, 256, 0),
+    "none_held": (0, 0, 0, 1),
+    # 140 pairs at held experts, 28 of them the 16 padded tokens': 112 count
+    "padding_brings_it_under": (140, 16, 112, 1),
+    "all_padding": (200, _T, 0, 1),
+}
+
+
+def test_short_rows_follow_the_held_share_and_the_pairs():
+    exaone = LlamaConfig(**{**HELD.to_dict(), "num_experts": 128,
+                            "num_experts_per_tok": 8, "experts_held": (0, 16)})
+    # a 512-, 256-, 128-token chunk and a tick of 32 slots at 16 of 128 held
+    assert [short_rows(exaone, 8 * t) for t in (512, 256, 128, 32)] == [1152, 640, 384, 128]
+    assert short_rows(exaone, 8 * 16) is None  # 128 rows of 128: nothing to save
+    assert short_rows(HELD, _T * _K) == 128
+    # all experts held, or half of them: no short path at any size
+    for cfg in (MOE, _ragged_cfg(), LlamaConfig(**{**HELD.to_dict(), "experts_held": (0, 8)})):
+        assert all(short_rows(cfg, n) is None for n in (8, 512, 4096, 1 << 20))
+
+
+@pytest.mark.parametrize("case", sorted(SHORT_CASES))
+def test_short_path_equals_the_full_rows(case, monkeypatch):
+    """Under the cap, exactly at it, one pair over it, with padding and
+    with nothing held: the output is the full rows' and the flag says
+    which branch ran."""
+    n_held, pad, counted, short = SHORT_CASES[case]
+    x, topk_p, topk_e, layer, valid = _held_case(n_held, pad)
+    with jax.default_matmul_precision("highest"):
+        y, sizes, flag = jax.jit(lambda *a: _ragged_mlp(HELD, *a))(
+            x, topk_p, topk_e, layer, valid)
+        _full_rows(monkeypatch)
+        y_full, sizes_full, flag_full = jax.jit(lambda *a: _ragged_mlp(HELD, *a))(
+            x, topk_p, topk_e, layer, valid)
+    assert int(flag) == short and int(flag_full) == 0
+    assert int(jnp.sum(sizes)) == counted
+    np.testing.assert_array_equal(sizes, sizes_full)
+    np.testing.assert_array_equal(y, y_full)  # the same rows in the same order
+    assert (float(jnp.max(jnp.abs(y))) > 0.01) == (counted > 0)
+    np.testing.assert_array_equal(y[:pad], 0)
+
+
+@pytest.mark.parametrize("case", ["under", "one_over", "padding_brings_it_under"])
+def test_short_path_gradients_equal_the_full_rows(case, monkeypatch):
+    n_held, pad, _, _ = SHORT_CASES[case]
+    x, topk_p, topk_e, layer, valid = _held_case(n_held, pad)
+    ct = jax.random.normal(jax.random.key(9), x.shape)
+
+    def grads():
+        return jax.jit(jax.grad(
+            lambda x, p, w: jnp.sum(ct * _ragged_mlp(HELD, x, p, topk_e, w, valid)[0]),
+            argnums=(0, 1, 2)))(x, topk_p, layer)
+
+    with jax.default_matmul_precision("highest"):
+        got = grads()
+        _full_rows(monkeypatch)
+        want = grads()
+    assert float(jnp.max(jnp.abs(want[0]))) > 0.01
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+
+
+def test_short_path_under_a_vmap_stays_exact():
+    """A vmapped conditional is a select over both branches: a batch of
+    loads under and over the cap equals the calls made one by one."""
+    cases = [_held_case(n) for n in (60, 256, 129)]
+    layer = cases[0][3]
+    one = lambda x, p, e, v: _ragged_mlp(HELD, x, p, e, layer, v)
+    with jax.default_matmul_precision("highest"):
+        ys, _, flags = jax.jit(jax.vmap(one))(
+            *(jnp.stack([c[i] for c in cases]) for i in (0, 1, 2, 4)))
+        for i, c in enumerate(cases):
+            y, _, flag = jax.jit(one)(c[0], c[1], c[2], c[4])
+            np.testing.assert_array_equal(ys[i], y)
+            assert int(flags[i]) == int(flag) == (i == 0)
