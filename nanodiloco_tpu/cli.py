@@ -537,26 +537,23 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    help="shared-prefix KV cache capacity in tokens (a "
                         "common system prompt prefills once and is "
                         "reused); 0 disables")
-    p.add_argument("--kv-block-size", type=int, default=0, metavar="TOKENS",
-                   help="page the KV cache into blocks of this many "
-                        "token rows (power of two <= --chunk-size): a "
-                        "request then holds only the blocks its "
-                        "sequence occupies instead of a worst-case "
-                        "max-len row, admission gates on free blocks, "
-                        "and shared prefixes map blocks copy-on-write; "
-                        "0 (default) keeps the dense per-slot cache")
+    p.add_argument("--kv-block-size", type=int, default=16, metavar="TOKENS",
+                   help="token rows in one block of the KV pool (>= 1; "
+                        "clamped to a power of two <= --chunk-size): a "
+                        "request holds only the blocks its sequence "
+                        "occupies, admission gates on free blocks, and "
+                        "shared prefixes map blocks copy-on-write")
     p.add_argument("--kv-dtype", choices=("model", "int8"), default="model",
                    help="KV cache storage dtype: 'model' stores the "
                         "compute dtype (bit-identical streams); 'int8' "
-                        "(paged only) quantizes K/V per row for ~4x "
+                        "quantizes K/V per row for ~4x "
                         "fp32 slots per HBM byte at a bounded logit "
                         "perturbation")
     p.add_argument("--kv-pool-blocks", type=int, default=None, metavar="N",
-                   help="paged KV pool size in blocks (the HBM budget: "
+                   help="KV pool size in blocks (the HBM budget: "
                         "pool bytes = N x block rows); default "
-                        "slots x ceil(max_len/block) — the dense "
-                        "footprint, oversubscribable downward because "
-                        "short requests only hold what they use")
+                        "slots x ceil(max_len/block), every slot at "
+                        "max_len; fewer oversubscribes the slots")
     p.add_argument("--tp", type=int, default=1, metavar="N",
                    help="tensor-parallel degree: shard the params, every "
                         "serve program (prefill chunks, the decode tick, "

@@ -516,45 +516,38 @@ def pad_prompts(prompts: list[list[int]], pad_id: int = 0):
 # ---------------------------------------------------------------------------
 # Slot-addressed serving programs (nanodiloco_tpu/serve)
 #
-# The continuous-batching engine owns either ONE dense cache
-# [L, B, S_max, Hkv, hd] whose B rows are independent request slots, or
-# (paged mode) ONE block arena [L, num_blocks, block_size, Hkv, hd]
-# addressed through per-slot block tables — a slot then holds only the
-# blocks its sequence actually occupies, so HBM caps concurrency by
-# TOKENS RESIDENT, not slots x worst-case S_max. The programs covering
-# a request's whole life:
-#   - prefill_chunk_fn / prefill_chunk_paged_fn: write one CHUNK of a
-#     request's prompt K/V into its slot at a traced offset (the same
-#     ``_cached_block`` the one-shot ``generate`` prefill uses, so the
-#     two paths can never drift), return the chunk's last-real-position
-#     logits AND the token sampled from them — sampling is fused into
-#     the chunk program, so a final chunk is ONE dispatch, not
+# The continuous-batching engine owns ONE block arena
+# [L, num_blocks, block_size, Hkv, hd] addressed through per-slot block
+# tables — a slot holds only the blocks its sequence actually occupies,
+# so HBM caps concurrency by TOKENS RESIDENT, not slots x worst-case
+# S_max. The programs covering a request's whole life:
+#   - prefill_chunk_paged_fn: write one CHUNK of a request's prompt K/V
+#     into its slot at a traced offset (the same ``_cached_block`` the
+#     one-shot ``generate`` prefill uses, so the two paths can never
+#     drift), return the chunk's last-real-position logits AND the
+#     token sampled from them — sampling is fused into the chunk
+#     program, so a final chunk is ONE dispatch, not
 #     attention-then-sample. Chunk lengths are BUCKETED to powers of
 #     two up to the engine's chunk size, so the compile count is
 #     bounded by log2(chunk_size)+1 — NOT one executable per prompt
-#     length, the PR-4 recompile trap. The paged variant gathers the
-#     slot's dense view through its block table, runs the identical
-#     ``_cached_block`` math, and scatters only the touched blocks
+#     length, the PR-4 recompile trap. It gathers the slot's contiguous
+#     view through its block table and scatters only the touched blocks
 #     back (out-of-range table entries drop, so a bucketed pad tail
 #     past the slot's allocation is a no-op write).
-#   - decode_slots_fn / decode_slots_paged_fn: advance ALL slots one
-#     token with PER-SLOT positions, PRNG keys, and sampling params,
-#     sampling fused in — one executable per tick does
-#     attention+sampling with zero extra dispatch; compiled once per
-#     (config, B, S) — admitting or retiring a request never
-#     recompiles anything. The paged variant gathers each layer's K/V
-#     through the block tables INSIDE the layer scan, so the dense
-#     working view exists one layer at a time, and writes each slot's
-#     new row by physical (block, offset) scatter (inactive slots are
-#     redirected out of range and dropped).
-#   - extract_chunk_fn / insert_chunk_fn: copy one whole chunk of K/V
-#     rows out of / into a dense slot — the shared-prefix cache's
-#     device-side halves in dense mode (one compile each; paged mode
-#     shares prefix BLOCKS by reference instead — zero device copies).
-# Sampling params ride as traced arrays so a new request with new
+#   - decode_slots_paged_fn: advance ALL slots one token with PER-SLOT
+#     positions, PRNG keys, and sampling params, sampling fused in —
+#     one executable per tick does attention+sampling with zero extra
+#     dispatch; compiled once per (config, B, table width) — admitting
+#     or retiring a request never recompiles anything. It gathers each
+#     layer's K/V through the block tables INSIDE the layer scan, so
+#     the contiguous working view exists one layer at a time, and
+#     writes each slot's new row by physical (block, offset) scatter
+#     (inactive slots are redirected out of range and dropped).
+# A shared prefix is shared BLOCKS, by reference: no program copies K/V
+# rows. Sampling params ride as traced arrays so a new request with new
 # temperature/top_k/top_p reuses the same executable.
 #
-# int8 KV (paged only): the arena stores int8 K/V plus one float32
+# int8 KV: the arena stores int8 K/V plus one float32
 # scale per (layer, block, row) — quantize on write (scale =
 # amax(|row|)/127 over the row's [Hkv, hd] values), dequantize in the
 # attention read. Per-ROW scales mean appending a token never
@@ -562,8 +555,8 @@ def pad_prompts(prompts: list[list[int]], pad_id: int = 0):
 # quantization error; rewriting an untouched row round-trips to the
 # same int8 bits (the scale reproduces to within 2^-23 relative, and
 # |q| <= 127 keeps round() exact). ~4x serve slots per HBM byte vs a
-# float32 cache at the cost of a bounded logit perturbation — the fp
-# paged path stays bit-identical to solo ``generate()``.
+# float32 cache at the cost of a bounded logit perturbation — the float
+# pool stays bit-identical to solo ``generate()``.
 # ---------------------------------------------------------------------------
 
 
@@ -676,21 +669,6 @@ def _sample_slots(logits, keys, temperature, top_k, top_p):
     return jnp.where(temperature > 0.0, drawn, greedy)
 
 
-def _decode_slots_block(params, cfg: LlamaConfig, tokens, cache, pos,
-                        key_valid, active):
-    """One decode step for B independent slots: ``tokens`` [B] at
-    PER-SLOT positions ``pos`` [B] — the T=1 special case of the
-    speculative verify block, delegated so the per-slot-position
-    transformer step (per-row RoPE phases, causal+valid mask, masked
-    dead-slot-safe cache writes, layer scan, head) has ONE
-    implementation the tick and its verify widening can never drift
-    between. Returns (logits [B, V] float32, updated cache)."""
-    logits, cache = _verify_slots_block(
-        params, cfg, tokens[:, None], cache, pos, key_valid, active
-    )
-    return logits[:, 0], cache
-
-
 def _serve_donate():
     # donating the cache makes each tick update in place on accelerators;
     # CPU has no donation and would warn on every call
@@ -704,7 +682,7 @@ def _serve_donate():
 # ``param_specs`` — the same layout solo ``generate(mesh=...)`` uses, so
 # a TP-served stream and a TP solo run shard every matmul identically
 # and stay BIT-identical on the same layout), the KV arenas are
-# constrained to ``kv_cache_spec`` (head-sharded: each shard owns its
+# constrained to ``kv_arena_leaf_spec`` (head-sharded: each shard owns its
 # own KV heads' rows end to end — no K/V ever crosses a shard), and the
 # final logits are constrained to REPLICATED before sampling, so the
 # fused per-slot sampling — and with it the per-step PRNG key schedule —
@@ -754,84 +732,30 @@ def _sample_one(logits, key_data, temperature, top_k, top_p):
 
 
 @functools.lru_cache(maxsize=8)
-def prefill_chunk_fn(cfg: LlamaConfig, mesh=None):
-    """Jitted ``(params, cache, chunk [1,C], chunk_valid [1,C], slot,
-    pos, last_idx, key_data [2]u32, temperature, top_k, top_p) ->
-    (token scalar, logits [1,V] float32, cache)``: run ONE chunk of
-    a prompt through the decoder, writing its K/V into cache slot
-    ``slot`` (traced) at positions ``[pos, pos+C)`` (traced), attending
-    causally over everything already written, and sample a token from
-    the chunk's last-real-position logits IN THE SAME EXECUTABLE (a
-    final chunk costs one dispatch, never attention-then-sample; an
-    interior chunk's sample is discarded by the caller — its cost is a
-    vocab sort, noise next to the decoder). The SAME ``_cached_block``
-    program the one-shot ``generate`` prefill runs — the two paths can
-    never drift — with the write offset and the last-real-token index
-    traced so one executable per CHUNK LENGTH covers every slot, every
-    offset, and every amount of right-padding. ``chunk_valid`` zeroes
-    pad tokens out of MoE routing; pad K/V writes land beyond the
-    prompt and are causally unreachable until decode overwrites them.
-    Retraces only per chunk length — the engine buckets those to powers
-    of two, so mixed-length traffic compiles a bounded program set."""
-
-    def run(params, cache, chunk, chunk_valid, slot, pos, last_idx,
-            key_data, temperature, top_k, top_p):
-        if mesh is not None:
-            params = _tp_params(params, cfg, mesh)
-            cache = _tp_kv(cache, mesh)
-        l, _b, s_max, nkv, hd = cache["k"].shape
-        with jax.named_scope("kv_gather"):
-            ck = jax.lax.dynamic_slice(
-                cache["k"], (0, slot, 0, 0, 0), (l, 1, s_max, nkv, hd)
-            )
-            cv = jax.lax.dynamic_slice(
-                cache["v"], (0, slot, 0, 0, 0), (l, 1, s_max, nkv, hd)
-            )
-        # every cache position reads as valid: the serve path never
-        # left-pads (each request prefills its own slot from 0), and
-        # positions at/after the live prefix are causally pruned
-        key_valid = jnp.ones((1, s_max), jnp.int32)
-        logits, sub = _cached_block(
-            params, cfg, chunk, {"k": ck, "v": cv}, pos,
-            key_valid, chunk_valid, block=0, last_index=last_idx,
-        )
-        with jax.named_scope("kv_write"):
-            cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], sub["k"], (0, slot, 0, 0, 0)
-                ),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], sub["v"], (0, slot, 0, 0, 0)
-                ),
-            }
-        if mesh is not None:
-            # replicated final logits: fused sampling (and its PRNG key
-            # schedule) runs exactly as on one device, per shard
-            logits = _tp_replicated(logits, mesh)
-            cache = _tp_kv(cache, mesh)
-        tok = _sample_one(logits, key_data, temperature, top_k, top_p)
-        return tok, logits, cache
-
-    return jax.jit(run, donate_argnums=_serve_donate())
-
-
-@functools.lru_cache(maxsize=8)
 def prefill_chunk_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
                            mesh=None):
-    """Paged twin of ``prefill_chunk_fn``: jitted ``(params, pool,
-    table [max_blocks] i32, chunk [1,C], chunk_valid [1,C], pos,
-    last_idx, key_data, temperature, top_k, top_p) -> (token, logits,
-    pool)``. Gathers the slot's dense K/V view through its block table
+    """Jitted ``(params, pool, table [max_blocks] i32, chunk [1,C],
+    chunk_valid [1,C], pos, last_idx, key_data [2]u32, temperature,
+    top_k, top_p) -> (token scalar, logits [1,V] float32, pool)``: run
+    ONE chunk of a prompt at positions ``[pos, pos+C)`` of the slot
+    whose block table this is, and sample from its last-real-position
+    logits in the same executable (an interior chunk's sample is
+    discarded by the caller — a vocab sort, noise next to the decoder).
+    Gathers the slot's contiguous K/V view through its block table
     (clamped out-of-range sentinel entries read causally-dead garbage),
-    runs the IDENTICAL ``_cached_block`` math — so paged-fp logits are
-    bit-identical to the dense path — and scatters only the touched
-    blocks back. The engine guarantees ``pos`` is block-aligned (chunk
-    starts are multiples of chunk_size and block_size divides
-    chunk_size), so the touched window is ``[pos, pos + max(C,
-    block_size))``; rows past the slot's allocation are pad positions
-    whose writes drop at the out-of-range sentinel. int8 mode
-    dequantizes the gather and quantizes the scattered rows per-row
-    (see module notes: rewriting an untouched row round-trips)."""
+    runs the SAME ``_cached_block`` the one-shot ``generate`` prefill
+    runs — so float-pool logits are bit-identical to solo
+    ``generate()`` — and scatters only the touched blocks back. The
+    engine guarantees ``pos`` is block-aligned (chunk starts are
+    multiples of chunk_size and block_size divides chunk_size), so the
+    touched window is ``[pos, pos + max(C, block_size))``; rows past
+    the slot's allocation are pad positions whose writes drop at the
+    out-of-range sentinel. ``chunk_valid`` zeroes pad tokens out of MoE
+    routing. int8 mode dequantizes the gather and quantizes the
+    scattered rows per-row (see module notes: rewriting an untouched
+    row round-trips). ``pos`` and ``last_idx`` are traced: one
+    executable per CHUNK LENGTH covers every slot, offset and amount
+    of right-padding."""
     quant = kv_dtype == "int8"
 
     def run(params, pool, table, chunk, chunk_valid, pos, last_idx,
@@ -858,9 +782,8 @@ def prefill_chunk_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
         )
         c = chunk.shape[1]
         # one block wider than the chunk itself: covers an unaligned
-        # start (the rare bucket-overflow refeed — see the engine's
-        # final-chunk note) and costs one identity rewrite of
-        # already-gathered rows in the aligned common case
+        # start (the engine sends none: see above) and costs one
+        # identity rewrite of already-gathered rows in the aligned case
         n_touch = min(c // bs + 1, mb) if c >= bs else 1
         # both slices clamp to the same block boundary; the explicit
         # min keeps the table slice and the data slice in lockstep
@@ -890,83 +813,16 @@ def prefill_chunk_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
     return jax.jit(run, donate_argnums=_serve_donate())
 
 
-@functools.lru_cache(maxsize=4)
-def extract_chunk_fn(cfg: LlamaConfig):
-    """Jitted ``(cache, slot, pos; size static) -> (k, v)`` with k/v
-    ``[L, size, Hkv, hd]``: copy one chunk of a slot's K/V rows out of
-    the pool — the prefix cache's insert path. One compile per chunk
-    size (the engine only extracts whole chunks)."""
-
-    @jax.named_scope("kv_gather")
-    def run(cache, slot, pos, size):
-        l, _b, _s, nkv, hd = cache["k"].shape
-        k = jax.lax.dynamic_slice(
-            cache["k"], (0, slot, pos, 0, 0), (l, 1, size, nkv, hd)
-        )[:, 0]
-        v = jax.lax.dynamic_slice(
-            cache["v"], (0, slot, pos, 0, 0), (l, 1, size, nkv, hd)
-        )[:, 0]
-        return k, v
-
-    return jax.jit(run, static_argnums=(3,))
-
-
-@functools.lru_cache(maxsize=4)
-def insert_chunk_fn(cfg: LlamaConfig):
-    """Jitted ``(cache, k [L,n,Hkv,hd], v, slot, pos) -> cache``: write
-    a cached prefix chunk's K/V rows into a slot — the prefix cache's
-    hit path. The rows were produced by the same chunk program over the
-    same tokens at the same positions, so a hit is bit-identical to
-    re-prefilling them."""
-
-    @jax.named_scope("kv_write")
-    def run(cache, k, v, slot, pos):
-        return {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], k[:, None], (0, slot, pos, 0, 0)
-            ),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], v[:, None], (0, slot, pos, 0, 0)
-            ),
-        }
-
-    return jax.jit(run, donate_argnums=(0,) if jax.default_backend() != "cpu" else ())
-
-
-@functools.lru_cache(maxsize=8)
-def decode_slots_fn(cfg: LlamaConfig, mesh=None):
-    """Jitted ``(params, cache, tokens [B], pos [B], key_valid [B,S],
-    key_data [B,2] uint32, temperature [B], top_k [B], top_p [B],
-    active [B]) -> (next_tokens [B], cache)``: one tick advancing every
-    slot. PRNG keys travel as raw key data so the host can stage each
-    slot's precomputed key sequence in numpy."""
-
-    def run(params, cache, tokens, pos, key_valid, key_data,
-            temperature, top_k, top_p, active):
-        if mesh is not None:
-            params = _tp_params(params, cfg, mesh)
-            cache = _tp_kv(cache, mesh)
-        logits, cache = _decode_slots_block(
-            params, cfg, tokens, cache, pos, key_valid, active
-        )
-        if mesh is not None:
-            logits = _tp_replicated(logits, mesh)
-            cache = _tp_kv(cache, mesh)
-        keys = jax.random.wrap_key_data(key_data)
-        nxt = _sample_slots(logits, keys, temperature, top_k, top_p)
-        return nxt, cache
-
-    return jax.jit(run, donate_argnums=_serve_donate())
-
-
 def _decode_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
                               tables, pos, active, quant: bool):
-    """``_decode_slots_block`` over the block arena — the T=1 special
-    case of the paged verify block (per-layer in-scan gather through
-    the tables, physical (block, row) scatter BEFORE the gather,
-    inactive slots redirected to the out-of-range sentinel and
-    dropped), delegated for the same single-implementation reason as
-    the dense path."""
+    """One decode step for B independent slots: ``tokens`` [B] at
+    PER-SLOT positions ``pos`` [B] — the T=1 special case of the
+    speculative verify block (per-layer in-scan gather through the
+    tables, physical (block, row) scatter BEFORE the gather, inactive
+    slots redirected to the out-of-range sentinel and dropped),
+    delegated so the per-slot-position transformer step has ONE
+    implementation the tick and its verify widening can never drift
+    between. Returns (logits [B, V] float32, updated pool)."""
     logits, pool = _verify_slots_paged_block(
         params, cfg, tokens[:, None], pool, tables, pos, active, quant
     )
@@ -976,11 +832,12 @@ def _decode_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
 @functools.lru_cache(maxsize=8)
 def decode_slots_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
                           mesh=None):
-    """Paged twin of ``decode_slots_fn``: jitted ``(params, pool,
-    tables [B, max_blocks] i32, tokens [B], pos [B], key_data [B,2]
-    u32, temperature [B], top_k [B], top_p [B], active [B]) ->
-    (next_tokens [B], pool)`` — one tick advancing every slot through
-    the block arena, sampling fused in."""
+    """Jitted ``(params, pool, tables [B, max_blocks] i32, tokens [B],
+    pos [B], key_data [B,2] u32, temperature [B], top_k [B], top_p [B],
+    active [B]) -> (next_tokens [B], pool)`` — one tick advancing every
+    slot through the block arena, sampling fused in. PRNG keys travel
+    as raw key data so the host can stage each slot's precomputed key
+    sequence in numpy."""
     quant = kv_dtype == "int8"
 
     def run(params, pool, tables, tokens, pos, key_data,
@@ -1097,117 +954,22 @@ def _slot_attention(q, ck, cv, mask):
     return jnp.einsum("bkgts,bskd->btkgd", probs, cv).reshape(b, t, nh * hd)
 
 
-def _verify_slots_block(params, cfg: LlamaConfig, tokens, cache, pos,
-                        key_valid, active):
-    """``_decode_slots_block`` widened to T = k+1 positions per slot:
-    ``tokens`` [B, T] write at per-slot positions ``pos..pos+T-1`` and
-    logits come back for EVERY position (each query's attention is the
-    same reduction the T=1 tick performs — rows past its own position
-    are causally masked, so a T-wide call is bit-identical per row to T
-    single-token ticks over the same cache bits, the chunked-prefill
-    property re-used). Returns (logits [B, T, V] float32, cache)."""
-    cdt = jnp.dtype(cfg.dtype)
-    b, t = tokens.shape
-    s_max = cache["k"].shape[2]
-    nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cdt)[tokens]  # [B, T, d]
-
-    qpos = pos[:, None] + jnp.arange(t)[None, :]  # [B, T] global positions
-    cos, sin = _slot_rope_tables(cfg, qpos, cdt)            # [B, T, 1, hd]
-
-    def rope(a):  # [B, T, H, hd] rotate-half with per-(slot, position) phases
-        half = a.shape[-1] // 2
-        a1, a2 = a[..., :half], a[..., half:]
-        return a * cos + jnp.concatenate([-a2, a1], axis=-1) * sin
-
-    ki = jnp.arange(s_max)
-    with jax.named_scope("attention"):
-        ok = (ki[None, None, :] <= qpos[:, :, None]) & (key_valid[:, None, :] > 0)
-        mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]      # [B, 1, T, S]
-    token_valid = jnp.broadcast_to(active[:, None], (b, t))
-
-    def layer_body(x, scanned):
-        layer, ck, cv = scanned
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("attn_proj"):
-            q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
-            k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
-            v = (h @ layer["wv"].astype(cdt)).reshape(b, t, nkv, hd)
-            q = rope(q)
-            k = rope(k)
-        # per-row masked writes, one position at a time (T is small and
-        # static): the exact values dynamic_update_slice would write,
-        # dead slots dropped — mid-prefill neighbours must not be
-        # stamped with garbage K/V (the PR-6 inactive-slot lesson)
-        with jax.named_scope("kv_write"):
-            for j in range(t):
-                wr = (
-                    (ki[None, :] == (pos + j)[:, None]) & (active[:, None] > 0)
-                )[:, :, None, None]
-                ck = jnp.where(wr, k[:, j][:, None], ck)
-                cv = jnp.where(wr, v[:, j][:, None], cv)
-
-        attn = _slot_attention(q, ck, cv, mask)
-        with jax.named_scope("attn_proj"):
-            x = x + attn @ layer["wo"].astype(cdt)
-
-        x, _aux = mlp_block(cfg, x, layer, valid=token_valid)
-        return x, (ck, cv)
-
-    # layer_scan: what the scan itself does to its per-layer operands
-    # (a layer's weights and cache sliced out, the cache stacked back)
-    with jax.named_scope("layer_scan"):
-        x, (ck, cv) = jax.lax.scan(
-            layer_body, x, (params["layers"], cache["k"], cache["v"])
-        )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)  # [B, T, d]
-    return _head_logits(params, x, cdt), {"k": ck, "v": cv}  # [B, T, V]
-
-
-@functools.lru_cache(maxsize=8)
-def verify_slots_fn(cfg: LlamaConfig, mesh=None):
-    """Jitted ``(params, cache, tokens [B,T], pos [B], draft_len [B],
-    key_valid [B,S], key_data [B,T,2] u32, temperature [B], top_k [B],
-    top_p [B], active [B]) -> (sampled [B,T], counts [B], cache)``: one
-    speculative tick. ``tokens`` = [current token, draft_0..draft_{k-1}]
-    per slot (pads beyond ``draft_len`` are ignored by acceptance);
-    ``counts[b]`` tokens of ``sampled[b]`` are the slot's emission this
-    tick. Retraces once per draft-width bucket T — the engine buckets
-    draft lengths to powers of two, so the compile count stays bounded
-    exactly like the prefill chunk programs."""
-
-    def run(params, cache, tokens, pos, draft_len, key_valid, key_data,
-            temperature, top_k, top_p, active):
-        if mesh is not None:
-            params = _tp_params(params, cfg, mesh)
-            cache = _tp_kv(cache, mesh)
-        logits, cache = _verify_slots_block(
-            params, cfg, tokens, cache, pos, key_valid, active
-        )
-        if mesh is not None:
-            logits = _tp_replicated(logits, mesh)
-            cache = _tp_kv(cache, mesh)
-        sampled = _sample_slots_multi(
-            logits, key_data, temperature, top_k, top_p
-        )
-        counts = _accept_prefix(tokens, sampled, draft_len)
-        return sampled, counts, cache
-
-    return jax.jit(run, donate_argnums=_serve_donate())
-
-
 def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
                               tables, pos, active, quant: bool):
-    """``_decode_slots_paged_block`` widened to T positions per slot:
-    each of the T new rows scatters at its own physical (block, row)
-    address — a verify window may CROSS a block boundary, so addresses
-    are resolved per position — before the gather, all inside the layer
-    scan. Positions past a slot's allocation hit the sentinel table
-    entry and drop; rejected/pad rows inside the allocation are
-    overwritten by a later tick before the cursor can ever expose them
-    (see the section note above)."""
+    """``_decode_slots_paged_block`` widened to T = k+1 positions per
+    slot: ``tokens`` [B, T] write at per-slot positions ``pos..pos+T-1``
+    and logits [B, T, V] come back for EVERY position (rows past a
+    query's own position are causally masked, so a T-wide call is
+    bit-identical per row to T single-token ticks over the same cache
+    bits). Each of the T new rows scatters at its own physical (block,
+    row) address — a verify window may CROSS a block boundary, so
+    addresses are resolved per position — before the gather, all inside
+    the layer scan; a dead slot's writes drop (a tick lands MID-prefill
+    of a neighbour slot, which must not be stamped with garbage K/V).
+    Positions past a slot's allocation hit the sentinel table entry and
+    drop; rejected/pad rows inside the allocation are overwritten by a
+    later tick before the cursor can ever expose them (see the section
+    note above)."""
     cdt = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
     _l, nb, bs, nkv, hd = pool["k"].shape
@@ -1302,11 +1064,14 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
 @functools.lru_cache(maxsize=8)
 def verify_slots_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
                           mesh=None):
-    """Paged twin of ``verify_slots_fn``: jitted ``(params, pool,
-    tables [B, max_blocks] i32, tokens [B,T], pos [B], draft_len [B],
-    key_data [B,T,2] u32, temperature [B], top_k [B], top_p [B],
-    active [B]) -> (sampled [B,T], counts [B], pool)`` — one
-    speculative tick through the block arena."""
+    """Jitted ``(params, pool, tables [B, max_blocks] i32, tokens
+    [B,T], pos [B], draft_len [B], key_data [B,T,2] u32, temperature
+    [B], top_k [B], top_p [B], active [B]) -> (sampled [B,T], counts
+    [B], pool)``: one speculative tick through the block arena.
+    ``tokens`` = [current token, draft_0..draft_{k-1}] per slot (pads
+    beyond ``draft_len`` are ignored by acceptance); ``counts[b]``
+    tokens of ``sampled[b]`` are the slot's emission this tick.
+    Retraces once per draft-width bucket T (powers of two)."""
     quant = kv_dtype == "int8"
 
     def run(params, pool, tables, tokens, pos, draft_len, key_data,

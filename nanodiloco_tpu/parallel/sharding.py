@@ -132,28 +132,21 @@ def _mixed_param_specs(cfg: LlamaConfig, worker_axis: bool, pp: bool) -> dict[st
     return specs
 
 
-def kv_cache_spec() -> P:
-    """Serve-side KV arenas — the dense cache ``[L, B, S, Hkv, hd]`` and
-    the paged pool ``[L, num_blocks, block_size, Hkv, hd]`` — shard on
-    the KV-HEAD axis over ``tp``: attention is head-parallel, so each
-    shard holds its own heads' K/V rows and never reads another shard's.
-    Everything host-side (block tables, free list, refcounts) stays
-    unsharded — a block id names the same physical block on every shard,
-    which is why paged allocation, copy-on-write prefix sharing, and
-    rejection-rollback cursor arithmetic are untouched by tensor
-    parallelism. int8 per-row scales ``[L, nb, bs]`` carry no head axis
-    and are replicated (``P()``)."""
-    return P(None, None, None, "tp", None)
-
-
 def kv_arena_leaf_spec(ndim: int) -> P:
-    """Per-leaf spec for one member of a serve KV arena pytree: the 5-d
-    k/v tensors take ``kv_cache_spec``; every lower-rank member (the
-    int8 per-row scales ``[L, nb, bs]``) is replicated. The ONE place
-    this rule lives — the engine's host-side ``device_put`` and the
-    compiled programs' ``with_sharding_constraint`` both read it, so
-    they cannot drift and force a per-tick resharding transfer."""
-    return kv_cache_spec() if ndim == 5 else P()
+    """Per-leaf spec for one member of the serve KV arena pytree: the
+    5-d k/v pool ``[L, num_blocks, block_size, Hkv, hd]`` shards on the
+    KV-HEAD axis over ``tp`` (attention is head-parallel, so each shard
+    holds its own heads' K/V rows and never reads another shard's);
+    every lower-rank member (the int8 per-row scales ``[L, nb, bs]``,
+    which carry no head axis) is replicated. Everything host-side
+    (block tables, free list, refcounts) stays unsharded — a block id
+    names the same physical block on every shard, which is why block
+    allocation, copy-on-write prefix sharing, and rejection-rollback
+    cursor arithmetic are untouched by tensor parallelism. The ONE
+    place this rule lives — the engine's host-side ``device_put`` and
+    the compiled programs' ``with_sharding_constraint`` both read it,
+    so they cannot drift and force a per-tick resharding transfer."""
+    return P(None, None, None, "tp", None) if ndim == 5 else P()
 
 
 def batch_spec(worker_axis: bool = True, accum_axis: bool = True, sp: bool = False) -> P:

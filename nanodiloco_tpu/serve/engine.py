@@ -1,35 +1,33 @@
 """Slot-based continuous-batching engine over the static-shape KV cache.
 
 Orca-style (Yu et al., OSDI'22) iteration-level scheduling on TPU terms:
-the engine owns ONE preallocated cache whose rows are independent
-request slots. Two storage modes share every scheduling surface:
-
-- DENSE (``kv_block_size=0``): one ``[L, B, S_max, Hkv, hd]`` row per
-  slot — a 30-token request reserves worst-case ``S_max`` HBM, so slot
-  count caps concurrency.
-- PAGED (``kv_block_size>0``): one ``[L, num_blocks, block_size, Hkv,
-  hd]`` arena plus a per-slot block table (vLLM's PagedAttention
-  insight, arXiv:2309.06180, on this repo's static-shape terms). A
-  request is admitted with exactly ``ceil((prompt + max_new) /
-  block_size)`` blocks — HBM caps concurrency by tokens RESIDENT, not
-  slots x worst-case — and its blocks return to the free list the
-  moment it retires, expires, or cancels. ``kv_dtype="int8"`` stores
-  the arena quantized (per-row scales, quantize on write, dequantize
-  in the attention read) for ~4x slots per HBM byte vs float32; the fp
-  arena stays bit-identical to solo ``generate()``.
+the engine owns ONE preallocated cache that independent request slots
+share. One storage form: a ``[L, num_blocks, block_size, Hkv, hd]``
+arena (the block pool) plus a per-slot block table (vLLM's
+PagedAttention insight, arXiv:2309.06180, on this repo's static-shape
+terms). A request is admitted with exactly ``ceil((prompt + max_new) /
+block_size)`` blocks — HBM caps concurrency by tokens RESIDENT, not
+slots x worst-case — and its blocks return to the free list the moment
+it retires, expires, or cancels. The pool's default size,
+``num_slots * ceil(max_len / block_size)`` blocks, admits ``num_slots``
+requests of ``max_len`` tokens each; ``kv_pool_blocks`` sizes it to the
+HBM a deployment has. ``kv_dtype="int8"`` stores the arena quantized
+(per-row scales, quantize on write, dequantize in the attention read)
+for ~4x slots per HBM byte vs float32; the float arena stays
+bit-identical to solo ``generate()``.
 
 A request's life:
 
 - ``start_prefill(slot, request)`` stages the request into a free slot
   and, when the prefix cache holds the prompt's leading chunks, reuses
-  them. In dense mode that copies cached K/V rows in; in paged mode it
-  maps the cached chunks' BLOCKS into the slot's table copy-on-write
-  (refcount bump, zero device copies) — "copy" never happens, because
-  a slot only ever writes past its prefix-hit boundary, into blocks it
-  owns exclusively. Paged admission is all-or-nothing: if the pool
-  cannot supply the blocks, ``BlocksExhausted`` is raised with nothing
-  allocated and nothing counted, and the scheduler leaves the request
-  queued (admission gates on free BLOCKS, not just free slots).
+  them: it maps the cached chunks' BLOCKS into the slot's table
+  copy-on-write (refcount bump, zero device copies) — "copy" never
+  happens, because a slot only ever writes past its prefix-hit
+  boundary, into blocks it owns exclusively. Admission is
+  all-or-nothing: if the pool cannot supply the blocks,
+  ``BlocksExhausted`` is raised with nothing allocated and nothing
+  counted, and the scheduler leaves the request queued (admission gates
+  on free BLOCKS, not just free slots).
 - ``prefill_step(slot)`` runs ONE prefill chunk (Sarathi-Serve,
   arXiv:2403.02310: chunked prefill is what keeps a 4k-token prompt
   from freezing every live decode stream between two ticks). The final
@@ -54,12 +52,11 @@ A request's life:
   greedy and sampled — bit-identical to solo ``generate()``; for the
   deterministic prompt-lookup proposal this rule coincides with
   rejection sampling, so it costs no acceptance either.
-- ``release(slot)`` frees the row (mid-prefill or mid-decode). Nothing
+- ``release(slot)`` frees the slot (mid-prefill or mid-decode). Nothing
   is zeroed: a retired slot's stale K/V is causally unreachable to the
-  next occupant. In paged mode every block the slot referenced is
-  deref'd — shared prefix blocks survive while the prefix cache (or
-  another slot) still holds them; exclusive blocks return to the free
-  list immediately.
+  next occupant. Every block the slot referenced is deref'd — shared
+  prefix blocks survive while the prefix cache (or another slot) still
+  holds them; exclusive blocks return to the free list immediately.
 
 Chunking math (why it is exact): K/V at position i depend only on
 ``tokens[:i+1]``, so writing them chunk-by-chunk produces the same cache
@@ -74,7 +71,7 @@ re-feeding earlier tokens, is what makes copy-on-write safe: a slot
 never writes at positions below its prefix-hit boundary, so shared
 blocks are read-only by construction.)
 
-Determinism contract (tested, dense AND paged-fp): a request's token
+Determinism contract (tested, float pool): a request's token
 stream is exactly the stream ``generate()`` produces alone with the
 same seed and sampling params — through chunked admission AND through a
 prefix-cache hit. The per-request PRNG schedule is replicated on the
@@ -86,8 +83,8 @@ The int8 arena trades that bit-parity for HBM: its contract is logit
 tolerance + greedy-token parity (tests/test_kv_paging.py), not bits.
 
 Tensor parallelism (``tp > 1``): params shard by the training
-``param_specs`` rules, both cache modes shard on the KV-HEAD axis
-(``parallel/sharding.py::kv_cache_spec``), and every compiled program
+``param_specs`` rules, the arena shards on the KV-HEAD axis
+(``parallel/sharding.py::kv_arena_leaf_spec``), and every compiled program
 above runs sharded with the final logits replicated before sampling —
 the per-step PRNG schedule is unchanged, so a TP stream is bit-identical
 to solo ``generate(mesh=...)`` on the same layout. Everything host-side
@@ -120,8 +117,8 @@ and chunk programs return the expert layer's counters with the tokens
 the plain decode tick, release and reuse of a slot (a ring is never
 cleared: its mask is rebuilt from the slot's position). NOT carried
 over, and refused at construction by name: speculation, the prefix
-cache, int8 rows, the unpaged cache, a serving mesh; ``export_kv`` and
-``import_kv`` refuse at the call.
+cache, int8 rows, a serving mesh; ``export_kv`` and ``import_kv``
+refuse at the call.
 
 Known divergence, inherited from ``generate`` and narrowed here: dense-
 dispatch token-choice MoE sizes expert capacity from the tokens in the
@@ -143,20 +140,14 @@ import numpy as np
 
 from nanodiloco_tpu.models.config import LlamaConfig
 from nanodiloco_tpu.models.generate import (
-    decode_slots_fn,
     decode_slots_mixed_fn,
     decode_slots_paged_fn,
-    extract_chunk_fn,
-    init_kv_cache,
     init_kv_pool,
     init_mixed_serve_cache,
-    insert_chunk_fn,
     kv_bytes_per_token,
     mixed_cache_bytes,
-    prefill_chunk_fn,
     prefill_chunk_mixed_fn,
     prefill_chunk_paged_fn,
-    verify_slots_fn,
     verify_slots_paged_fn,
 )
 from nanodiloco_tpu.models.moe import COUNTERS
@@ -213,7 +204,7 @@ class InferenceEngine:
         max_len: int = 1024,
         chunk_size: int = 64,
         prefix_cache_tokens: int = 0,
-        kv_block_size: int = 0,
+        kv_block_size: int = 16,
         kv_dtype: str | None = None,
         kv_pool_blocks: int | None = None,
         spec_k: int = 0,
@@ -226,6 +217,9 @@ class InferenceEngine:
             raise ValueError(f"max_len must be >= 2; got {max_len}")
         if chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1; got {chunk_size}")
+        if kv_block_size < 1:
+            raise ValueError(
+                f"kv_block_size must be >= 1; got {kv_block_size}")
         if cfg.num_experts and cfg.router_type == "experts_choose":
             raise ValueError(
                 "expert-choice routing is training-only (see generate()); "
@@ -245,25 +239,19 @@ class InferenceEngine:
                 (spec_k, "speculation (spec_k > 0: the verify programs)"),
                 (prefix_cache_tokens, "the prefix cache (prefix_cache_tokens > 0)"),
                 (self.kv_dtype == "int8", "kv_dtype='int8' (quantized rows)"),
-                (not kv_block_size, "the unpaged cache (kv_block_size=0)"),
                 (int(tp) > 1, "a serving mesh (tp > 1)"),
             ):
                 if on:
                     raise ValueError(
                         f"{what} is not carried over to a mixed layer stack "
                         "(window layers in per-slot rings beside the paged "
-                        "pool): serve this configuration with a paged "
+                        "pool): serve this configuration with a "
                         "model-dtype cache, no speculation, no prefix cache, "
                         "tp=1")
-        if self.kv_dtype == "int8" and not kv_block_size:
-            raise ValueError(
-                "int8 KV storage requires the paged cache; pass "
-                "kv_block_size > 0"
-            )
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0; got {spec_k}")
         # tensor parallelism: shard params (param_specs), the compiled
-        # serve programs, and the KV arenas (kv_cache_spec: the KV-head
+        # serve programs, and the KV arena (kv_arena_leaf_spec: the KV-head
         # axis) over a tp-axis mesh. Validated LOUDLY here, at boot —
         # a bad degree must be a readable config error, never a shape
         # error out of the first traced program.
@@ -308,82 +296,62 @@ class InferenceEngine:
         self.max_len = int(max_len)
         # chunk lengths are bucketed to powers of two; capping the top
         # bucket at the largest power of two <= max_len keeps every
-        # bucketed write inside the slot row (a bucket can right-pad a
-        # final chunk, and dynamic_update_slice would CLAMP an
-        # out-of-range write backwards over real positions)
+        # bucketed write inside the slot's gathered view (a bucket can
+        # right-pad a final chunk, and dynamic_update_slice would CLAMP
+        # an out-of-range write backwards over real positions)
         self.chunk_size = _floor_pow2(min(int(chunk_size), self.max_len))
         self.vocab_size = cfg.vocab_size
-        self.paged = bool(kv_block_size)
-        self._chunk = None
-        self._decode = None
-        self._extract = None
-        self._insert = None
         b = self.num_slots
-        if self.paged:
-            # block size: a power of two no larger than the chunk size,
-            # so every chunk start (a multiple of chunk_size) is
-            # block-aligned and shared prefix chunks map to whole blocks
-            self.kv_block_size = _floor_pow2(
-                min(int(kv_block_size), self.chunk_size)
-            )
-            bs = self.kv_block_size
-            self.max_blocks = -(-self.max_len // bs)   # allocation bound
-            # the TABLE is one chunk of sentinel entries wider than any
-            # allocation: a right-padded final bucket then always fits
-            # the gathered view (done + bucket <= ceil(max_len/cs)*cs <
-            # view), so the paged path NEVER takes the re-feed fallback
-            # — which would rewrite rows below the prefix-hit boundary,
-            # and in int8 mode re-feed bits are NOT identical (the
-            # original chunk attended its own rows as fresh fp; a
-            # re-feed reads them dequantized), i.e. it would corrupt
-            # shared copy-on-write blocks. Pad writes land on the
-            # sentinel and drop; pad reads are causally masked.
-            self.table_blocks = self.max_blocks + self.chunk_size // bs
-            default_blocks = self.num_slots * self.max_blocks
-            nb = int(kv_pool_blocks) if kv_pool_blocks else default_blocks
-            # a pool SMALLER than one max_len request is legal — it
-            # serves short requests and validate() rejects the long
-            # ones outright (they could never be admitted)
-            self.block_pool = BlockPool(nb, bs)
-            self.cache = None
-            if self.mixed:
-                # full layers in the pool, sliding layers in rings a
-                # chunk wider than the window (generate.py says why)
-                self.ring_rows = int(cfg.sliding_window or 0) + self.chunk_size
-                self.pool = init_mixed_serve_cache(
-                    cfg, self.num_slots, self.ring_rows, nb, bs)
-                self._chunk_paged = prefill_chunk_mixed_fn(cfg)
-                self._decode_paged = decode_slots_mixed_fn(cfg)
-            else:
-                self.pool = self._shard_kv(
-                    init_kv_pool(cfg, nb, bs, self.kv_dtype))
-                self._chunk_paged = prefill_chunk_paged_fn(
-                    cfg, self.kv_dtype, self.mesh
-                )
-                self._decode_paged = decode_slots_paged_fn(
-                    cfg, self.kv_dtype, self.mesh
-                )
-            # per-slot block tables; the sentinel nb is out of range:
-            # reads clamp to causally-dead garbage, writes drop
-            self._tables = np.full((b, self.table_blocks), nb, np.int32)
-            self._slot_blocks: list[list[int]] = [[] for _ in range(b)]
-            self.kv_block_evictions = 0
-            self.hist_blocks_per_request = Histogram(_BLOCK_BUCKETS)
+        # block size: a power of two no larger than the chunk size, so
+        # every chunk start (a multiple of chunk_size) is block-aligned
+        # and shared prefix chunks map to whole blocks
+        self.kv_block_size = _floor_pow2(
+            min(int(kv_block_size), self.chunk_size)
+        )
+        bs = self.kv_block_size
+        self.max_blocks = -(-self.max_len // bs)   # allocation bound
+        # the TABLE is one chunk of sentinel entries wider than any
+        # allocation: a right-padded final bucket then always fits the
+        # gathered view (done + bucket <= ceil(max_len/cs)*cs < view),
+        # so a final chunk never has to start below the cursor, where
+        # it would rewrite shared copy-on-write blocks (with OTHER bits
+        # in int8: a row read back dequantized is not the fresh row).
+        # Pad writes land on the sentinel and drop; pad reads are
+        # causally masked.
+        self.table_blocks = self.max_blocks + self.chunk_size // bs
+        default_blocks = self.num_slots * self.max_blocks
+        nb = int(kv_pool_blocks) if kv_pool_blocks else default_blocks
+        # a pool SMALLER than one max_len request is legal — it serves
+        # short requests and validate() rejects the long ones outright
+        # (they could never be admitted)
+        self.block_pool = BlockPool(nb, bs)
+        if self.mixed:
+            # full layers in the pool, sliding layers in rings a chunk
+            # wider than the window (generate.py says why)
+            self.ring_rows = int(cfg.sliding_window or 0) + self.chunk_size
+            self.pool = init_mixed_serve_cache(
+                cfg, self.num_slots, self.ring_rows, nb, bs)
+            self._chunk_paged = prefill_chunk_mixed_fn(cfg)
+            self._decode_paged = decode_slots_mixed_fn(cfg)
         else:
-            self.kv_block_size = 0
-            self.block_pool = None
-            self.pool = None
-            self.cache = self._shard_kv(
-                init_kv_cache(cfg, self.num_slots, self.max_len)
+            self.pool = self._shard_kv(
+                init_kv_pool(cfg, nb, bs, self.kv_dtype))
+            self._chunk_paged = prefill_chunk_paged_fn(
+                cfg, self.kv_dtype, self.mesh
             )
-            self._chunk = prefill_chunk_fn(cfg, self.mesh)
-            self._decode = decode_slots_fn(cfg, self.mesh)
-            self._extract = extract_chunk_fn(cfg)
-            self._insert = insert_chunk_fn(cfg)
+            self._decode_paged = decode_slots_paged_fn(
+                cfg, self.kv_dtype, self.mesh
+            )
+        # per-slot block tables; the sentinel nb is out of range: reads
+        # clamp to causally-dead garbage, writes drop
+        self._tables = np.full((b, self.table_blocks), nb, np.int32)
+        self._slot_blocks: list[list[int]] = [[] for _ in range(b)]
+        self.kv_block_evictions = 0
+        self.hist_blocks_per_request = Histogram(_BLOCK_BUCKETS)
         self.prefix_cache = (
             PrefixCache(
                 int(prefix_cache_tokens), self.chunk_size,
-                on_evict=self._evict_prefix_blocks if self.paged else None,
+                on_evict=self._evict_prefix_blocks,
             )
             if prefix_cache_tokens else None
         )
@@ -399,10 +367,8 @@ class InferenceEngine:
             self.speculator = PromptLookupProposer(
                 self.spec_k, max_ngram=self.spec_ngram
             )
-            self._verify = (
-                verify_slots_paged_fn(cfg, self.kv_dtype, self.mesh)
-                if self.paged else verify_slots_fn(cfg, self.mesh)
-            )
+            self._verify = verify_slots_paged_fn(
+                cfg, self.kv_dtype, self.mesh)
         else:
             self.speculator = None
             self._verify = None
@@ -424,10 +390,8 @@ class InferenceEngine:
         self._params_by_gen: dict[int, object] = {0: self.params}
         self._slot_gen = [0] * b
 
-        s = self.max_len
         self._tokens = np.zeros(b, np.int32)       # next input token per slot
         self._pos = np.zeros(b, np.int32)          # next cache write position
-        self._key_valid = np.zeros((b, s), np.int32)
         self._active = np.zeros(b, np.int32)
         self._temp = np.zeros(b, np.float32)
         self._topk = np.zeros(b, np.int32)
@@ -458,8 +422,7 @@ class InferenceEngine:
         self.capture_routing = False
         self.routing_log: dict[int, list[np.ndarray]] = {}
         # device-resident copies of the slot state that only changes at
-        # admit/release (key_valid alone is [B, S_max] — re-uploading it
-        # every tick would put an H2D transfer on the per-token path)
+        # admit/release (``_stage_dev`` says why)
         self._dev: dict | None = None
         # (kind -> bucket set) of every program shape dispatched, for
         # the layout-qualified compile-count introspection
@@ -561,8 +524,8 @@ class InferenceEngine:
         self.params = params
         if self.prefix_cache is not None:
             # cached K/V was computed under the old weights; reusing it
-            # would break the bit-parity contract (paged mode derefs the
-            # cached blocks through on_evict, exactly like LRU eviction)
+            # would break the bit-parity contract (the cached blocks are
+            # deref'd through on_evict, exactly like LRU eviction)
             self.prefix_cache.clear()
         self._prune_param_generations()
         return self.deploy_generation
@@ -590,7 +553,7 @@ class InferenceEngine:
     # -- request validation (shared with the server's 400 path) -------------
 
     def blocks_for(self, prompt_tokens: int, max_new_tokens: int) -> int:
-        """KV blocks a request occupies for its whole life (paged mode):
+        """KV blocks a request occupies for its whole life:
         prompt + completion rows, rounded up to whole blocks. Allocation
         is up-front and exact, so a request admitted never runs out of
         cache mid-decode."""
@@ -598,7 +561,7 @@ class InferenceEngine:
 
     def validate(self, prompt, max_new_tokens: int) -> None:
         """Raises ValueError when a request cannot be served by this
-        engine's static shapes (including a paged pool it could NEVER
+        engine's static shapes (including a pool it could NEVER
         fit — transient block shortage is ``BlocksExhausted`` at
         admission instead, and retryable)."""
         if len(prompt) < 1:
@@ -613,14 +576,13 @@ class InferenceEngine:
                 f"({max_new_tokens}) exceeds the engine's max_len "
                 f"({self.max_len})"
             )
-        if self.paged:
-            need = self.blocks_for(len(prompt), max_new_tokens)
-            if need > self.block_pool.num_blocks:
-                raise ValueError(
-                    f"request needs {need} KV blocks but the pool only "
-                    f"has {self.block_pool.num_blocks} in total — it can "
-                    f"never be admitted"
-                )
+        need = self.blocks_for(len(prompt), max_new_tokens)
+        if need > self.block_pool.num_blocks:
+            raise ValueError(
+                f"request needs {need} KV blocks but the pool only "
+                f"has {self.block_pool.num_blocks} in total — it can "
+                f"never be admitted"
+            )
         bad = [t for t in prompt if not 0 <= int(t) < self.vocab_size]
         if bad:
             raise ValueError(
@@ -634,69 +596,58 @@ class InferenceEngine:
         """Stage ``request`` into free slot ``slot``: validate, reuse
         any cached shared-prefix K/V, and return the number of prefill
         chunks still to run (>= 1 — the last prompt token always
-        prefills for real, its logits seed the first sample). Paged
-        mode allocates the request's whole block budget here,
+        prefills for real, its logits seed the first sample). The
+        request's whole block budget is allocated here,
         all-or-nothing: ``BlocksExhausted`` (nothing mutated, nothing
         counted) tells the scheduler to keep the request queued until
         blocks free up."""
         ids = [int(t) for t in request.prompt]
         self.validate(ids, request.max_new_tokens)
-        done = 0
         use_cache = self.prefix_cache is not None and getattr(
             request, "prefix_cache", True
         )
-        if self.paged:
-            cs, bs = self.chunk_size, self.kv_block_size
-            need = self.blocks_for(len(ids), request.max_new_tokens)
-            # PEEK the prefix cache first: sizing must precede any
-            # side effect so a block-starved admission rolls back to
-            # nothing (no counters, no LRU churn, no refs). Under
-            # pressure, RECLAIM cache-only blocks by evicting LRU
-            # prefixes: cached K/V is a best-effort optimization, and
-            # without this path a cache that swallowed the pool would
-            # livelock admission forever (no prefill can complete, so
-            # insert-side eviction never runs). Each eviction can
-            # invalidate the matched chain, so the peek re-walks.
-            while True:
-                chains = (
-                    self.prefix_cache.match(ids, record=False)
-                    if use_cache else []
+        need = self.blocks_for(len(ids), request.max_new_tokens)
+        # PEEK the prefix cache first: sizing must precede any
+        # side effect so a block-starved admission rolls back to
+        # nothing (no counters, no LRU churn, no refs). Under
+        # pressure, RECLAIM cache-only blocks by evicting LRU
+        # prefixes: cached K/V is a best-effort optimization, and
+        # without this path a cache that swallowed the pool would
+        # livelock admission forever (no prefill can complete, so
+        # insert-side eviction never runs). Each eviction can
+        # invalidate the matched chain, so the peek re-walks.
+        while True:
+            chains = (
+                self.prefix_cache.match(ids, record=False)
+                if use_cache else []
+            )
+            shared = [blk for chunk in chains for blk in chunk]
+            own_need = need - len(shared)
+            if own_need <= self.block_pool.free_blocks:
+                break
+            if (self.prefix_cache is None
+                    or not self.prefix_cache.evict_lru()):
+                raise BlocksExhausted(
+                    f"request needs {own_need} KV blocks "
+                    f"({need} total, {len(shared)} shared) but only "
+                    f"{self.block_pool.free_blocks}/"
+                    f"{self.block_pool.num_blocks} are free"
                 )
-                shared = [blk for chunk in chains for blk in chunk]
-                own_need = need - len(shared)
-                if own_need <= self.block_pool.free_blocks:
-                    break
-                if (self.prefix_cache is None
-                        or not self.prefix_cache.evict_lru()):
-                    raise BlocksExhausted(
-                        f"request needs {own_need} KV blocks "
-                        f"({need} total, {len(shared)} shared) but only "
-                        f"{self.block_pool.free_blocks}/"
-                        f"{self.block_pool.num_blocks} are free"
-                    )
-            # commit: record the hit/miss for real (same chain —
-            # nothing mutated between the peek and this), take the
-            # references
-            if use_cache:
-                chains = self.prefix_cache.match(ids)
-            own = self.block_pool.alloc(own_need)
-            self.block_pool.ref(shared)
-            blocks = shared + own
-            self._slot_blocks[slot] = blocks
-            row = np.full(self.table_blocks, self.block_pool.num_blocks,
-                          np.int32)
-            row[: len(blocks)] = blocks
-            self._tables[slot] = row
-            self._dev = None
-            done = len(chains) * cs
-        elif use_cache:
-            blocks = self.prefix_cache.match(ids)
-            for i, (k, v) in enumerate(blocks):
-                self.cache = self._insert(
-                    self.cache, k, v, self._jarr(slot, np.int32),
-                    self._jarr(i * self.chunk_size, np.int32),
-                )
-            done = len(blocks) * self.chunk_size
+        # commit: record the hit/miss for real (same chain —
+        # nothing mutated between the peek and this), take the
+        # references
+        if use_cache:
+            chains = self.prefix_cache.match(ids)
+        own = self.block_pool.alloc(own_need)
+        self.block_pool.ref(shared)
+        blocks = shared + own
+        self._slot_blocks[slot] = blocks
+        row = np.full(self.table_blocks, self.block_pool.num_blocks,
+                      np.int32)
+        row[: len(blocks)] = blocks
+        self._tables[slot] = row
+        self._dev = None
+        done = len(chains) * self.chunk_size
         # the request is admitted under the CURRENT weights; every chunk
         # and decode tick of its life dispatches this generation's
         # params, even if a hot swap lands mid-stream
@@ -706,7 +657,7 @@ class InferenceEngine:
 
     def _run_chunk(self, slot: int, chunk, valid, pos: int, last: int,
                    key_data, temp: float, top_k: int, top_p: float):
-        """Dispatch one (bucketed) chunk through the mode's compiled
+        """Dispatch one (bucketed) chunk through the compiled chunk
         program; returns (token scalar, logits [1, V])."""
         self._buckets.setdefault("prefill_chunk", set()).add(len(chunk))
         params = self._params_by_gen[self._slot_gen[slot]]
@@ -732,15 +683,10 @@ class InferenceEngine:
                     real = int(np.asarray(valid).sum())
                     self.routing_log.setdefault(slot, []).append(
                         np.asarray(chosen)[:, 0, :real])
-            elif self.paged:
+            else:
                 tok, logits, self.pool = self._chunk_paged(
                     params, self.pool,
                     self._jarr(self._tables[slot]), *args,
-                )
-            else:
-                tok, logits, self.cache = self._chunk(
-                    params, self.cache, args[0], args[1],
-                    self._jarr(slot, np.int32), *args[2:],
                 )
             # fence INSIDE the section: interior chunks have no host
             # consumer (the final chunk's int(tok) is the only natural
@@ -774,37 +720,18 @@ class InferenceEngine:
 
         # final chunk, bucketed to a power of two and right-padded: the
         # chunk always starts AT the cursor (never re-feeds earlier
-        # positions — which is what makes shared prefix blocks read-only
-        # under paging), pads land past the prompt (causally unreachable,
+        # positions — which is what makes shared prefix blocks
+        # read-only), pads land past the prompt (causally unreachable,
         # then overwritten by decode), and the true last-real index rides
-        # into the program as a traced scalar. One exception, DENSE
-        # only: when the padded bucket would poke past the cache view
-        # (max_len not a multiple of the bucket — dynamic_update_slice
-        # would CLAMP the write backwards over real rows), fall back to
-        # RE-FEEDING the prompt's last ``bucket`` tokens: recomputed
-        # fp K/V bits are identical to what those positions already
-        # hold (same tokens, same positions, same params), so the
-        # rewrite is a no-op and the write stays in range. The PAGED
-        # view is a chunk wider than any allocation precisely so this
-        # branch can never trigger there — a paged re-feed would write
-        # below the prefix-hit boundary, and in int8 mode those bits
-        # are NOT a no-op (shared-block corruption).
+        # into the program as a traced scalar. The padded bucket always
+        # fits the gathered view: the table is a chunk wider than any
+        # allocation (``__init__`` says why).
         bucket = _ceil_pow2(remaining)
-        view = (
-            self.table_blocks * self.kv_block_size if self.paged
-            else self.max_len
-        )
-        if pf.done + bucket <= view:
-            lo = pf.done
-            chunk = ids[lo:] + [0] * (bucket - remaining)
-            valid = np.zeros((1, bucket), np.int32)
-            valid[0, :remaining] = 1
-            last = remaining - 1
-        else:  # overflow implies done >= chunk_size >= bucket, so lo >= 0
-            lo = p - bucket
-            chunk = ids[lo:]
-            valid = np.ones((1, bucket), np.int32)
-            last = bucket - 1
+        lo = pf.done
+        chunk = ids[lo:] + [0] * (bucket - remaining)
+        valid = np.zeros((1, bucket), np.int32)
+        valid[0, :remaining] = 1
+        last = remaining - 1
         req = pf.request
         temp = float(req.temperature)
         top_k = min(int(req.top_k), self.vocab_size)
@@ -831,7 +758,6 @@ class InferenceEngine:
             )
         self._step_idx[slot] = 0
         self._pos[slot] = p
-        self._key_valid[slot] = 1
         self._tokens[slot] = tok0
         self._temp[slot] = temp
         self._topk[slot] = top_k
@@ -862,27 +788,18 @@ class InferenceEngine:
             # already cached are registered
             cs = self.chunk_size
             n_chunks = (p - 1) // cs
-            if self.paged:
-                # zero-copy: the cache takes a REFERENCE to the slot's
-                # own blocks for each new chunk (bumping their refcount)
-                # — the rows never move, and they outlive the slot
-                cpb = cs // self.kv_block_size
+            # zero-copy: the cache takes a REFERENCE to the slot's own
+            # blocks for each new chunk (bumping their refcount) — the
+            # rows never move, and they outlive the slot
+            cpb = cs // self.kv_block_size
 
-                def extract(i: int):
-                    blks = tuple(
-                        int(x) for x in
-                        self._tables[slot][i * cpb:(i + 1) * cpb]
-                    )
-                    self.block_pool.ref(blks)
-                    return blks
-            else:
-
-                def extract(i: int):
-                    k, v = self._extract(
-                        self.cache, self._jarr(slot, np.int32),
-                        self._jarr(i * cs, np.int32), cs,
-                    )
-                    return k, v
+            def extract(i: int):
+                blks = tuple(
+                    int(x) for x in
+                    self._tables[slot][i * cpb:(i + 1) * cpb]
+                )
+                self.block_pool.ref(blks)
+                return blks
 
             self.prefix_cache.insert(ids, n_chunks, extract)
         return tok0
@@ -899,19 +816,16 @@ class InferenceEngine:
 
     def _stage_dev(self) -> dict:
         """Device-resident slot state that only changes at admit/release
-        (uploading key_valid/tables every tick would put an H2D copy on
-        the per-token path)."""
+        (uploading the tables every tick would put an H2D copy on the
+        per-token path)."""
         if self._dev is None:
             self._dev = {
                 "temp": self._jarr(self._temp),
                 "topk": self._jarr(self._topk),
                 "topp": self._jarr(self._topp),
                 "active": self._jarr(self._active),
+                "tables": self._jarr(self._tables),
             }
-            if self.paged:
-                self._dev["tables"] = self._jarr(self._tables)
-            else:
-                self._dev["key_valid"] = self._jarr(self._key_valid)
         return self._dev
 
     def _collect_drafts(self) -> tuple[list[list[int]], int]:
@@ -1011,17 +925,10 @@ class InferenceEngine:
                             tokens, pos, keys,
                             dev["temp"], dev["topk"], dev["topp"], active,
                         )
-                    elif self.paged:
+                    else:
                         nxt, self.pool = self._decode_paged(
                             params, self.pool, dev["tables"],
                             tokens, pos, keys,
-                            dev["temp"], dev["topk"], dev["topp"], active,
-                        )
-                    else:
-                        nxt, self.cache = self._decode(
-                            params, self.cache,
-                            tokens, pos,
-                            dev["key_valid"], keys,
                             dev["temp"], dev["topk"], dev["topp"], active,
                         )
                 # the host fetch below is the tick's natural fence;
@@ -1088,18 +995,11 @@ class InferenceEngine:
         for params, slots, active in dispatches:
             with self.accountant.section("verify", t, self.kv_layout):
                 with trace_span("engine.decode_dispatch"):
-                    if self.paged:
-                        sampled, counts, self.pool = self._verify(
-                            params, self.pool, dev["tables"],
-                            jtokens, jpos, jdlen, jkeys,
-                            dev["temp"], dev["topk"], dev["topp"], active,
-                        )
-                    else:
-                        sampled, counts, self.cache = self._verify(
-                            params, self.cache, jtokens, jpos, jdlen,
-                            dev["key_valid"], jkeys,
-                            dev["temp"], dev["topk"], dev["topp"], active,
-                        )
+                    sampled, counts, self.pool = self._verify(
+                        params, self.pool, dev["tables"],
+                        jtokens, jpos, jdlen, jkeys,
+                        dev["temp"], dev["topk"], dev["topp"], active,
+                    )
                 with trace_span("engine.fetch_tokens"):
                     sampled = np.asarray(sampled)
                     counts = np.asarray(counts)
@@ -1233,7 +1133,6 @@ class InferenceEngine:
 
     def release(self, slot: int) -> None:
         self._active[slot] = 0
-        self._key_valid[slot] = 0
         self._keys[slot] = None
         self._pos[slot] = 0
         self._tokens[slot] = 0
@@ -1249,20 +1148,19 @@ class InferenceEngine:
         if self._spec_ok[slot]:
             self.speculator.release(slot)
         self._spec_ok[slot] = False
-        if self.paged:
-            blocks = self._slot_blocks[slot]
-            if blocks:
-                self.hist_blocks_per_request.observe(len(blocks))
-                self.block_pool.deref(blocks)
-            self._slot_blocks[slot] = []
-            self._tables[slot] = self.block_pool.num_blocks
+        blocks = self._slot_blocks[slot]
+        if blocks:
+            self.hist_blocks_per_request.observe(len(blocks))
+            self.block_pool.deref(blocks)
+        self._slot_blocks[slot] = []
+        self._tables[slot] = self.block_pool.num_blocks
         self._dev = None
         # a retiring slot may have been the last reference to a
         # pre-swap weight generation — release the old snapshot
         self._prune_param_generations()
 
     def _evict_prefix_blocks(self, blocks) -> None:
-        """Prefix-cache LRU eviction hook (paged): drop the cache's
+        """Prefix-cache LRU eviction hook: drop the cache's
         references; blocks still mapped into a live slot survive until
         that slot releases them."""
         self.block_pool.deref(blocks)
@@ -1279,7 +1177,7 @@ class InferenceEngine:
         fingerprint fields, and the cache cursor ``pos`` — which the
         server packs into the wire doc together with the cursor the
         scheduler owns (emitted tokens, request spec). Only blocks
-        actually written travel: a paged export gathers the used blocks
+        actually written travel: the export gathers the used blocks
         device-side and transfers those, never the slot's whole
         allocation. Read-only: the slot stays live (release is the
         scheduler's call, after the export is in hand)."""
@@ -1291,28 +1189,21 @@ class InferenceEngine:
             raise ValueError(f"slot {slot} has no live stream to export")
         t0 = time.perf_counter()
         pos = int(self._pos[slot])
-        blocks_moved = 0
-        if self.paged:
-            bs = self.kv_block_size
-            nb = -(-pos // bs)
-            blocks = self._slot_blocks[slot][:nb]
-            idx = jnp.asarray(np.asarray(blocks, np.int32))
-            k = np.asarray(self.pool["k"][:, idx])
-            v = np.asarray(self.pool["v"][:, idx])
-            layers = k.shape[0]
-            k = k.reshape(layers, nb * bs, *k.shape[3:])[:, :pos]
-            v = v.reshape(layers, nb * bs, *v.shape[3:])[:, :pos]
-            ks = vs = None
-            if self.kv_dtype == "int8":
-                ks = np.asarray(self.pool["ks"][:, idx]).reshape(
-                    layers, nb * bs)[:, :pos].astype(np.float32)
-                vs = np.asarray(self.pool["vs"][:, idx]).reshape(
-                    layers, nb * bs)[:, :pos].astype(np.float32)
-            blocks_moved = nb
-        else:
-            k = np.asarray(self.cache["k"][:, slot, :pos])
-            v = np.asarray(self.cache["v"][:, slot, :pos])
-            ks = vs = None
+        bs = self.kv_block_size
+        nb = -(-pos // bs)
+        blocks = self._slot_blocks[slot][:nb]
+        idx = jnp.asarray(np.asarray(blocks, np.int32))
+        k = np.asarray(self.pool["k"][:, idx])
+        v = np.asarray(self.pool["v"][:, idx])
+        layers = k.shape[0]
+        k = k.reshape(layers, nb * bs, *k.shape[3:])[:, :pos]
+        v = v.reshape(layers, nb * bs, *v.shape[3:])[:, :pos]
+        ks = vs = None
+        if self.kv_dtype == "int8":
+            ks = np.asarray(self.pool["ks"][:, idx]).reshape(
+                layers, nb * bs)[:, :pos].astype(np.float32)
+            vs = np.asarray(self.pool["vs"][:, idx]).reshape(
+                layers, nb * bs)[:, :pos].astype(np.float32)
         out = {
             "config": kvship.config_fingerprint(self.cfg),
             "generation": int(self._slot_gen[slot]),
@@ -1326,7 +1217,7 @@ class InferenceEngine:
         c = self.kvship_counts
         c["export_requests"] += 1
         c["export_bytes"] += int(nbytes)
-        c["export_blocks"] += blocks_moved
+        c["export_blocks"] += nb
         c["export_seconds"] += time.perf_counter() - t0
         return out
 
@@ -1336,9 +1227,8 @@ class InferenceEngine:
         (amax/127) into an int8 arena, dequantize out of an int8 wire;
         an fp wire into a DIFFERENT fp arena dtype is a loud
         ``ShipMismatchError`` — never a silent cast."""
-        arena_int8 = self.paged and self.kv_dtype == "int8"
         wire_int8 = shipped.wire_dtype == "int8"
-        if arena_int8:
+        if self.kv_dtype == "int8":
             if wire_int8:
                 return shipped.k, shipped.v, shipped.ks, shipped.vs
             qk, sk = kvship.quantize_rows(shipped.k)
@@ -1356,57 +1246,6 @@ class InferenceEngine:
                 "would silently break the bit-parity contract"
             )
         return shipped.k, shipped.v, None, None
-
-    def _import_paged(self, slot: int, ids, request, pos: int,
-                      k, v, ks, vs) -> int:
-        """Re-block shipped rows into this engine's pool geometry: the
-        request's FULL block budget is allocated all-or-nothing at
-        refcount 1 (``BlocksExhausted`` stays the retryable admission
-        signal, and ``release`` derefs exactly like a local admission —
-        refcount conservation needs no new path), the written rows land
-        in the leading blocks, and the trailing blocks hold the
-        decode-to-come. Returns the block count the payload filled."""
-        bs = self.kv_block_size
-        need = self.blocks_for(len(ids), request.max_new_tokens)
-        own = self.block_pool.alloc(need)
-        try:
-            nb = -(-pos // bs)
-            layers, heads, hd = k.shape[0], k.shape[2], k.shape[3]
-
-            def blockify(rows):
-                pad = np.zeros((layers, nb * bs, heads, hd), rows.dtype)
-                pad[:, :pos] = rows
-                return pad.reshape(layers, nb, bs, heads, hd)
-
-            idx = jnp.asarray(np.asarray(own[:nb], np.int32))
-            self.pool["k"] = self.pool["k"].at[:, idx].set(
-                jnp.asarray(blockify(k), self.pool["k"].dtype))
-            self.pool["v"] = self.pool["v"].at[:, idx].set(
-                jnp.asarray(blockify(v), self.pool["v"].dtype))
-            if ks is not None:
-
-                def blockify_s(sc):
-                    pad = np.zeros((layers, nb * bs), np.float32)
-                    pad[:, :pos] = sc
-                    return pad.reshape(layers, nb, bs)
-
-                self.pool["ks"] = self.pool["ks"].at[:, idx].set(
-                    jnp.asarray(blockify_s(ks)))
-                self.pool["vs"] = self.pool["vs"].at[:, idx].set(
-                    jnp.asarray(blockify_s(vs)))
-            if self.mesh is not None:
-                self.pool = self._shard_kv(self.pool)
-            row = np.full(self.table_blocks, self.block_pool.num_blocks,
-                          np.int32)
-            row[:need] = own
-            self._tables[slot] = row
-            self._slot_blocks[slot] = own
-            return nb
-        except BaseException:
-            # a failed scatter must not leak the allocation (zero-leak
-            # under mid-ship failure is part of the ship contract)
-            self.block_pool.deref(own)
-            raise
 
     def import_kv(self, slot: int, request, shipped) -> None:
         """Import a shipped stream into free slot ``slot`` and resume it
@@ -1462,26 +1301,58 @@ class InferenceEngine:
                 f"({self.vocab_size})"
             )
         pos = int(shipped.pos)
-        arena = self.pool["k"] if self.paged else self.cache["k"]
-        layers, heads, hd = arena.shape[0], arena.shape[-2], arena.shape[-1]
+        layers, _nb, _bs, heads, hd = self.pool["k"].shape
         if tuple(shipped.k.shape) != (layers, pos, heads, hd):
             raise kvship.ShipMismatchError(
                 f"payload rows are {tuple(shipped.k.shape)} but this "
                 f"engine expects [{layers}, {pos}, {heads}, {hd}]"
             )
         k, v, ks, vs = self._convert_wire(shipped)
-        if self.paged:
-            blocks_moved = self._import_paged(
-                slot, ids, request, pos, k, v, ks, vs
-            )
-        else:
-            blocks_moved = 0
-            self.cache["k"] = self.cache["k"].at[:, slot, :pos].set(
-                jnp.asarray(k))
-            self.cache["v"] = self.cache["v"].at[:, slot, :pos].set(
-                jnp.asarray(v))
-            if self.mesh is not None:
-                self.cache = self._shard_kv(self.cache)
+        # re-block the rows into this engine's pool geometry: the
+        # request's FULL block budget is allocated all-or-nothing at
+        # refcount 1 (``BlocksExhausted`` stays the retryable admission
+        # signal, and ``release`` derefs exactly like a local admission —
+        # refcount conservation needs no new path), the written rows land
+        # in the leading blocks, and the trailing blocks hold the
+        # decode-to-come
+        bs = self.kv_block_size
+        need = self.blocks_for(len(ids), request.max_new_tokens)
+        own = self.block_pool.alloc(need)
+        try:
+            nb = -(-pos // bs)
+
+            def blockify(rows):
+                pad = np.zeros((layers, nb * bs, heads, hd), rows.dtype)
+                pad[:, :pos] = rows
+                return pad.reshape(layers, nb, bs, heads, hd)
+
+            idx = jnp.asarray(np.asarray(own[:nb], np.int32))
+            self.pool["k"] = self.pool["k"].at[:, idx].set(
+                jnp.asarray(blockify(k), self.pool["k"].dtype))
+            self.pool["v"] = self.pool["v"].at[:, idx].set(
+                jnp.asarray(blockify(v), self.pool["v"].dtype))
+            if ks is not None:
+
+                def blockify_s(sc):
+                    pad = np.zeros((layers, nb * bs), np.float32)
+                    pad[:, :pos] = sc
+                    return pad.reshape(layers, nb, bs)
+
+                self.pool["ks"] = self.pool["ks"].at[:, idx].set(
+                    jnp.asarray(blockify_s(ks)))
+                self.pool["vs"] = self.pool["vs"].at[:, idx].set(
+                    jnp.asarray(blockify_s(vs)))
+            self.pool = self._shard_kv(self.pool)
+            row = np.full(self.table_blocks, self.block_pool.num_blocks,
+                          np.int32)
+            row[:need] = own
+            self._tables[slot] = row
+            self._slot_blocks[slot] = own
+        except BaseException:
+            # a failed scatter must not leak the allocation (zero-leak
+            # under mid-ship failure is part of the ship contract)
+            self.block_pool.deref(own)
+            raise
         # prefill_step's activation tail, replicated: the one-shot
         # generate()'s key schedule from the request seed, the cursors
         # from the shipped emission count
@@ -1499,7 +1370,6 @@ class InferenceEngine:
         )
         self._step_idx[slot] = len(emitted) - 1
         self._pos[slot] = pos
-        self._key_valid[slot] = 1
         self._tokens[slot] = emitted[-1]
         self._temp[slot] = temp
         self._topk[slot] = top_k
@@ -1525,7 +1395,7 @@ class InferenceEngine:
         c = self.kvship_counts
         c["import_requests"] += 1
         c["import_bytes"] += int(nbytes)
-        c["import_blocks"] += blocks_moved
+        c["import_blocks"] += nb
         c["import_seconds"] += time.perf_counter() - t0
 
     def kvship_stats(self) -> dict | None:
@@ -1549,13 +1419,11 @@ class InferenceEngine:
         cache is disabled)."""
         return None if self.prefix_cache is None else self.prefix_cache.stats()
 
-    def kv_stats(self) -> dict | None:
-        """Block-pool gauges for /metrics and the stats JSONL (None in
-        dense mode). ``kv_bytes`` is the arena's true HBM footprint;
+    def kv_stats(self) -> dict:
+        """Block-pool gauges for /metrics and the stats JSONL.
+        ``kv_bytes`` is the arena's true HBM footprint;
         ``hist_blocks_per_request`` is the blocks-held distribution
         observed at release."""
-        if not self.paged:
-            return None
         ps = self.block_pool.stats()
         if self.mixed:
             # by kind: the pool's blocks are the full layers' alone, a
@@ -1602,12 +1470,8 @@ class InferenceEngine:
         return self.accountant.snapshot()
 
     def blocks_held(self, slot: int) -> int:
-        """KV blocks currently mapped into ``slot`` (0 in dense mode —
-        a dense slot's cache rows are a fixed arena share, not a
-        metered allocation). The scheduler's ``kv_block_seconds``
-        attribution reads this at admission."""
-        if not self.paged:
-            return 0
+        """KV blocks currently mapped into ``slot``. The scheduler's
+        ``kv_block_seconds`` attribution reads this at admission."""
         return len(self._slot_blocks[slot])
 
     def spec_stats(self) -> dict | None:
@@ -1651,12 +1515,10 @@ class InferenceEngine:
 
     @property
     def kv_layout(self) -> str:
-        """The engine's program layout tag: cache storage mode plus the
-        tensor-parallel degree when sharded — the string every
+        """The engine's program layout tag: what the cache stores plus
+        the tensor-parallel degree when sharded — the string every
         ``compile_counts`` key carries."""
-        if not self.paged:
-            base = "dense"
-        elif self.kv_dtype == "int8":
+        if self.kv_dtype == "int8":
             base = "paged-int8"
         elif self.mixed:
             base = "paged-rings"  # full layers paged, sliding layers in rings
@@ -1668,19 +1530,16 @@ class InferenceEngine:
         """Compiled-executable counts per program, keyed by
         ``kind:layout`` — the bounded-compile contract is testable, not
         folklore: chunk programs are capped by the power-of-two bucket
-        set, decode/copy by 1 each (sampling is fused into chunk and
-        decode, so there is no separate sample program to count).
+        set, decode by 1 (sampling is fused into chunk and decode, so
+        there is no separate sample program to count; a prefix hit maps
+        blocks by reference, so there is no copy program either).
 
         Keys are LAYOUT-QUALIFIED (``prefill_chunk:paged-int8-tp2``,
         not ``prefill_chunk``): a flat kind key let a per-layout pin
-        silently read the wrong mode's count — a paged test asserting
-        ``prefill_chunk <= 4`` could not tell whether it had measured
-        the paged program set or the dense one. ``buckets`` records the
+        silently read another layout's count. ``buckets`` records the
         (kind -> program shape) set actually dispatched, so a pin can
         assert the exact (kind, bucket, layout) triples too."""
         def size(fn):
-            if fn is None:
-                return None
             try:
                 return fn._cache_size()
             except Exception:  # pragma: no cover - older/newer jit internals
@@ -1691,18 +1550,9 @@ class InferenceEngine:
             "layout": layout,
             "tp_degree": self.tp,
             "buckets": {k: sorted(v) for k, v in sorted(self._buckets.items())},
-            f"prefill_chunk:{layout}": size(
-                self._chunk_paged if self.paged else self._chunk
-            ),
-            f"decode:{layout}": size(
-                self._decode_paged if self.paged else self._decode
-            ),
+            f"prefill_chunk:{layout}": size(self._chunk_paged),
+            f"decode:{layout}": size(self._decode_paged),
         }
         if self._verify is not None:
             out[f"verify:{layout}"] = size(self._verify)
-        if not self.paged:
-            # the dense-only prefix-cache copy programs; paged mode
-            # shares prefix blocks by reference and never compiles them
-            out[f"extract:{layout}"] = size(self._extract)
-            out[f"insert:{layout}"] = size(self._insert)
         return out

@@ -1,5 +1,5 @@
 """KV block shipping: the wire format for moving a live request's
-paged (or dense) KV cache between serving replicas.
+KV blocks between serving replicas.
 
 This is the mechanism behind disaggregated prefill/decode serving
 (DistServe, arXiv:2401.09670; Splitwise, arXiv:2311.18677): a prefill

@@ -5,11 +5,12 @@ block) makes whole-prompt prefill O(requests x prefix) for work that is
 O(prefix): K/V at position i depend only on ``tokens[:i+1]`` and the
 frozen params, so two prompts with the same token prefix have
 bit-identical K/V rows over it (vLLM's PagedAttention observation,
-arXiv:2309.06180, on this repo's dense-slot terms).
+arXiv:2309.06180, on this repo's static-shape terms).
 
 Granularity is the engine's prefill CHUNK: an entry is one whole chunk
-of K/V rows ``[L, chunk_tokens, Hkv, hd]`` keyed by the token tuple of
-the ENTIRE prefix through that chunk (a Python dict over token tuples IS
+of K/V rows, held as the ids of the pool blocks that store them (a
+reference, never a copy), keyed by the token tuple of the ENTIRE
+prefix through that chunk (a Python dict over token tuples IS
 a content-hashed map, with collision resolution for free — no rolling
 hash to get wrong). Corollary: a shared prefix shorter than one chunk
 never caches, and sharing stops at the last whole-chunk boundary inside
@@ -35,8 +36,8 @@ import collections
 
 class PrefixCache:
     """Chunk-granular LRU over token-prefix keys. ``blocks`` values are
-    opaque to this class (the engine stores ``(k, v)`` device arrays),
-    so every policy decision is testable without a model."""
+    opaque to this class (the engine stores tuples of block ids), so
+    every policy decision is testable without a model."""
 
     def __init__(self, capacity_tokens: int, chunk_tokens: int,
                  on_evict=None) -> None:
@@ -49,9 +50,8 @@ class PrefixCache:
             )
         self.chunk_tokens = int(chunk_tokens)
         self.capacity_tokens = int(capacity_tokens)
-        # eviction hook, called with the evicted block value: the paged
-        # engine derefs the chunk's KV blocks here (dense mode needs
-        # nothing — dropping the device arrays frees them)
+        # eviction hook, called with the evicted block value: the
+        # engine derefs the chunk's KV blocks here
         self.on_evict = on_evict
         # prefix token tuple (whole chunks) -> block; move_to_end = LRU
         self._blocks: collections.OrderedDict[tuple, object] = (

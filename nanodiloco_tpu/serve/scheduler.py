@@ -761,7 +761,7 @@ class Scheduler:
             # KV blocks the admission just allocated (all-or-nothing,
             # constant until release): the block-seconds bill is
             # blocks x held-time, settled at release. Backends without
-            # the accessor (dense, fakes) bill zero.
+            # the accessor (fakes) bill zero.
             held = getattr(self.backend, "blocks_held", None)
             self._slots[slot] = _Prefilling(
                 q.ticket, q.request, q.submitted_at, q.deadline_at,
@@ -975,8 +975,8 @@ class Scheduler:
 
     def _saturation_detail(self) -> str:
         """Why the system is not draining, for the 429 message: KV
-        block availability when the backend pages its cache ('' for
-        dense backends) — a client/operator reading the error learns
+        block availability ('' for a backend that reports none) — a
+        client/operator reading the error learns
         whether the ceiling is slots or HBM."""
         kv_stats = getattr(self.backend, "kv_stats", None)
         if kv_stats is None:
@@ -984,8 +984,6 @@ class Scheduler:
         try:
             kv = kv_stats()
         except Exception:  # pragma: no cover - defensive: message only
-            return ""
-        if not kv:
             return ""
         return (
             f"; KV blocks {kv['blocks_free']}/{kv['num_blocks']} free"
@@ -1300,9 +1298,7 @@ class Scheduler:
                 out["prefix_cache"] = ps
         kv_stats = getattr(self.backend, "kv_stats", None)
         if kv_stats is not None:
-            kv = kv_stats()
-            if kv is not None:
-                out["kv_pool"] = kv
+            out["kv_pool"] = kv_stats()
         spec_stats = getattr(self.backend, "spec_stats", None)
         if spec_stats is not None:
             spec = spec_stats()

@@ -397,9 +397,8 @@ def summarize_run(path: str) -> dict[str, Any]:
                 out["prefix_cache_hit_rate"] = round(
                     (pc.get("hits") or 0) / looked, 4
                 )
-        # paged KV block pool (kv_block_size > 0 serves): the same keys
-        # the /metrics gauges export — absent from older JSONLs, whose
-        # summaries are unchanged
+        # KV block pool: the same keys the /metrics gauges export —
+        # absent from older JSONLs, whose summaries are unchanged
         kv = last.get("kv_pool")
         if isinstance(kv, dict):
             out["kv_blocks_free"] = kv.get("blocks_free")

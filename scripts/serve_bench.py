@@ -11,8 +11,11 @@ Workloads:
 - ``uniform`` (default): every client cycles through ``--prompt-lens``
   with unique random prompts — the PR-4 throughput shape.
 - ``capacity``: the paged-KV economics sweep. At a FIXED KV HBM budget
-  (``--kv-hbm-budget-mb``) it sizes three engines — dense per-slot
-  rows, paged-fp, and paged-int8 — admits identical requests
+  (``--kv-hbm-budget-mb``) it sizes three engines — ``dense`` (the
+  default pool: a worst-case ``max_len`` of blocks reserved for every
+  slot, so slots bind), paged-fp, and paged-int8 (pools sized to the
+  budget with more slots than fit, so blocks bind) — admits identical
+  requests
   (``--capacity-prompt-len`` + ``--max-new-tokens`` tokens) until
   admission refuses, then measures aggregate decode tok/s with every
   admitted slot live. The admission count is MEASURED (the engine
@@ -214,17 +217,16 @@ def build_parser() -> argparse.ArgumentParser:
                    help="bench on N virtual CPU devices (the TP record "
                         "on a laptop/CI box; same mechanism as the "
                         "serve CLI flag)")
-    # paged-KV engine knobs (any workload) + the capacity sweep's shape
-    p.add_argument("--kv-block-size", type=int, default=0,
-                   help="page the KV cache into blocks of this many "
-                        "token rows (0 = dense per-slot rows; the "
+    # KV pool knobs (any workload) + the capacity sweep's shape
+    p.add_argument("--kv-block-size", type=int, default=16,
+                   help="token rows in one block of the KV pool (the "
                         "capacity workload ignores this and uses "
-                        "--capacity-block-size for its paged modes)")
+                        "--capacity-block-size)")
     p.add_argument("--kv-dtype", choices=("model", "int8"), default="model",
-                   help="KV storage dtype (int8 requires paging)")
+                   help="KV storage dtype")
     p.add_argument("--kv-pool-blocks", type=int, default=None,
-                   help="paged pool size in blocks (default: the dense "
-                        "footprint)")
+                   help="pool size in blocks (default: every slot at "
+                        "max_len)")
     p.add_argument("--kv-hbm-budget-mb", type=float, default=2.0,
                    help="[capacity] fixed KV HBM budget each mode must "
                         "live inside")
@@ -338,7 +340,8 @@ def _device_seconds_per_token(results: list[dict]) -> float | None:
 def _capacity_mode(args, cfg, params, mode: str, budget_bytes: int) -> dict:
     """Size ONE engine variant to the fixed KV HBM budget, admit
     identical requests until admission refuses (slots exhausted for
-    dense, blocks exhausted for paged — both MEASURED, not computed),
+    ``dense``, whose pool reserves max_len for every slot; blocks
+    exhausted for the others — both MEASURED, not computed),
     then time decode ticks with every admitted slot live."""
     from nanodiloco_tpu.models.generate import kv_bytes_per_token
     from nanodiloco_tpu.serve import (
@@ -362,9 +365,8 @@ def _capacity_mode(args, cfg, params, mode: str, budget_bytes: int) -> dict:
         slots = max(1, int(budget_bytes // per_slot))
         eng = InferenceEngine(
             params, cfg, num_slots=slots, max_len=max_len,
-            chunk_size=args.chunk_size, tp=args.tp,
+            chunk_size=args.chunk_size, kv_block_size=bs, tp=args.tp,
         )
-        kv_bytes = int(eng.cache["k"].nbytes + eng.cache["v"].nbytes)
     else:
         kv_dtype = "int8" if mode == "paged-int8" else "model"
         tok_bytes = kv_bytes_per_token(
@@ -380,7 +382,7 @@ def _capacity_mode(args, cfg, params, mode: str, budget_bytes: int) -> dict:
             chunk_size=args.chunk_size, kv_block_size=bs,
             kv_dtype=kv_dtype, kv_pool_blocks=nb, tp=args.tp,
         )
-        kv_bytes = int(eng.kv_stats()["kv_bytes"])
+    kv_bytes = int(eng.kv_stats()["kv_bytes"])
     rng = __import__("random").Random(args.seed)
     admitted = 0
     for slot in range(eng.num_slots):
@@ -444,8 +446,8 @@ def _capacity_mode(args, cfg, params, mode: str, budget_bytes: int) -> dict:
             round(dev_s / window_tokens, 8)
             if window_tokens and dev_s > 0 else None
         ),
-        **({"kv_pool_blocks": eng.block_pool.num_blocks,
-            "kv_block_size": eng.kv_block_size} if eng.paged else {}),
+        "kv_pool_blocks": eng.block_pool.num_blocks,
+        "kv_block_size": eng.kv_block_size,
     }
 
 

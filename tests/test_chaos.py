@@ -12,8 +12,8 @@ Three layers:
   harness's replica-killer hook + aborted connection), flap_health on
   probe ordinals only, and passthrough for everything else;
 - CANCEL hygiene over real serve servers: ``/v1/cancel`` frees the
-  slot and the paged KV blocks of an in-flight stream (dense AND
-  paged), cancels a QUEUED request before it ever decodes, and the
+  slot and the KV blocks of an in-flight stream (two block sizes),
+  cancels a QUEUED request before it ever decodes, and the
   router's hedge loser is cancelled over the wire with zero leaked
   slots/blocks — plus the provider discipline that a SIGKILLed (chaos-
   killed) replica is a crash, not a preemption: dropped, never
@@ -59,8 +59,8 @@ CFG = LlamaConfig(
 )
 
 KV_MODES = [
-    pytest.param({}, id="dense"),
-    pytest.param({"kv_block_size": 4}, id="paged"),
+    pytest.param({}, id="default"),   # blocks of 16 rows, clamped to the chunk
+    pytest.param({"kv_block_size": 4}, id="bs4"),
 ]
 
 
@@ -394,9 +394,8 @@ def test_cancel_frees_slot_and_kv_blocks(params, kv):
         s = sched.stats()
         assert s["slots_busy"] == 0 and s["queue_depth"] == 0
         assert s["cancelled"] == 1
-        kvs = eng.kv_stats()
-        if kvs is not None:                        # paged: zero leaked
-            assert kvs["blocks_free"] == kvs["num_blocks"]
+        kvs = eng.kv_stats()                       # zero leaked
+        assert kvs["blocks_free"] == kvs["num_blocks"]
     finally:
         server.stop()
 
@@ -483,8 +482,7 @@ def test_hedge_loser_cancelled_over_the_wire_zero_leak(params, kv):
         st = sched0.stats()
         assert st["cancelled"] == 1 and st["slots_busy"] == 0
         kvs = eng0.kv_stats()
-        if kvs is not None:
-            assert kvs["blocks_free"] == kvs["num_blocks"]
+        assert kvs["blocks_free"] == kvs["num_blocks"]
         assert sched1.stats()["slots_busy"] == 0
     finally:
         s0.stop()

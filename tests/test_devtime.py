@@ -390,8 +390,8 @@ def test_devtime_families_round_trip_byte_exact():
 
 
 @pytest.mark.parametrize("kv", [
-    pytest.param({}, id="dense"),
-    pytest.param({"kv_block_size": 4}, id="paged"),
+    pytest.param({}, id="default"),   # blocks of 16 rows, clamped to the chunk
+    pytest.param({"kv_block_size": 4}, id="bs4"),
 ])
 def test_engine_accountant_reconciles_with_scheduler_attribution(kv):
     """The chip drill's wire assertion, in-process: with the REAL

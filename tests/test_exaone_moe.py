@@ -236,7 +236,7 @@ REFUSED = {
     "speculation": (lambda: _engine(spec_k=2), "speculation"),
     "prefix_cache": (lambda: _engine(prefix_cache_tokens=64), "prefix cache"),
     "int8": (lambda: _engine(kv_dtype="int8"), "int8"),
-    "unpaged": (lambda: _engine(kv_block_size=0), "unpaged cache"),
+    "unpaged": (lambda: _engine(kv_block_size=0), "kv_block_size"),
     "serving_mesh": (lambda: _engine(tp=2), "serving mesh"),
     "export_kv": (lambda: _engine().export_kv(0), "export_kv"),
     "import_kv": (lambda: _engine().import_kv(0, None, None), "import_kv"),

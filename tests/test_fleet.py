@@ -4,8 +4,8 @@ Three layers, each on its own terms:
 
 - ENGINE hot-swap bit-parity: a swap mid-stream keeps every in-flight
   stream bit-identical to solo ``generate()`` on the OLD weights while
-  post-swap admissions are bit-identical on the NEW ones — dense and
-  paged, mid-decode and mid-prefill — plus prefix-cache invalidation,
+  post-swap admissions are bit-identical on the NEW ones — two block
+  sizes, mid-decode and mid-prefill — plus prefix-cache invalidation,
   rollback bit-exactness, and loud shape validation.
 - ROUTER/CONTROLLER policy units: scripted probe/post + injected
   clock, no sockets, no model — least-loaded pick from the gauges,
@@ -42,8 +42,8 @@ CFG = LlamaConfig(
 )
 
 KV_MODES = [
-    pytest.param({}, id="dense"),
-    pytest.param({"kv_block_size": 4}, id="paged"),
+    pytest.param({}, id="default"),   # blocks of 16 rows, clamped to the chunk
+    pytest.param({"kv_block_size": 4}, id="bs4"),
 ]
 
 

@@ -267,6 +267,38 @@ def test_request_that_can_never_fit_is_rejected_loudly(params):
     assert eng.block_pool.free_blocks == eng.block_pool.num_blocks
 
 
+@pytest.mark.parametrize("bad", [0, -1])
+def test_block_size_below_one_is_refused_at_construction(params, bad):
+    """The pool is the engine's only cache: there is no block size that
+    means "no blocks", and the refusal names the parameter."""
+    with pytest.raises(ValueError, match="kv_block_size"):
+        InferenceEngine(params, CFG, num_slots=1, max_len=16,
+                        kv_block_size=bad)
+
+
+def test_default_pool_holds_every_slot_at_max_len(params):
+    """An engine built with no KV argument sizes its pool at
+    ``num_slots * ceil(max_len / block)`` blocks: every slot can hold a
+    request of ``max_len`` tokens at once, whatever the others hold —
+    admission is then bound by slots, never by blocks."""
+    eng = InferenceEngine(params, CFG, num_slots=3, max_len=24)
+    assert eng.kv_block_size == 16  # the default, under the chunk's cap
+    assert eng.block_pool.num_blocks == 3 * 2  # ceil(24 / 16) a slot
+    sched = Scheduler(eng)
+    tickets = [
+        sched.submit(GenRequest(prompt=tuple(range(1 + i, 21 + i)),
+                                max_new_tokens=4, seed=i))
+        for i in range(3)
+    ]  # 20 + 4 = max_len tokens each
+    sched.tick()  # all three admitted in one tick: none waits for blocks
+    assert sched.stats()["admission_blocked_no_blocks"] == 0
+    assert [eng.blocks_held(s) for s in range(3)] == [2, 2, 2]
+    assert eng.kv_stats()["blocks_free"] == 0
+    _drain(sched, tickets)
+    assert all(t.result["finish_reason"] == "length" for t in tickets)
+    assert eng.kv_stats()["blocks_free"] == eng.block_pool.num_blocks
+
+
 def test_scheduler_keeps_slo_order_while_block_starved():
     """Model-free: a fake backend that refuses blocks keeps the peeked
     request AT ITS QUEUE POSITION (head-of-line — a later, smaller
@@ -398,7 +430,7 @@ def test_int8_tp2_greedy_parity_across_layouts(params):
     solo fp ``generate()`` token for token (per-row quantization is
     amax/127 — max is exactly associative, so the int8 bits are
     layout-invariant; only the fp matmul reassociation moves, and
-    greedy argmax absorbs it at this scale like the dense tp tests)."""
+    greedy argmax absorbs it at this scale like the float tp tests)."""
     lens = [3, 5, 8]
     reqs = [
         GenRequest(
@@ -509,9 +541,8 @@ def test_compile_count_bounded_under_paging():
     # {1, 2, 4, 8}; admitting/retiring never recompiled the tick
     assert 1 <= counts["prefill_chunk:paged"] <= 4
     assert counts["decode:paged"] == 1
-    # the dense-only copy programs never compile in paged mode (prefix
-    # sharing is by block reference, zero device copies) — and under
-    # the layout-keyed introspection they do not even have a key
+    # no copy program exists: prefix sharing is by block reference,
+    # zero device copies
     assert not any(k.startswith(("extract", "insert")) for k in counts)
 
 

@@ -5,8 +5,8 @@ contract.
 - ROUND-TRIP BIT-PARITY: a stream prefilled on one engine, parked,
   exported through ``kvship.pack`` -> ``unpack`` (the real wire bytes),
   and resumed on a SECOND engine is bit-identical to solo
-  ``generate()`` — across pool geometries (dense<->paged, different
-  block sizes, tp degrees) because the wire format is layout-invariant.
+  ``generate()`` — across pool geometries (different block sizes, tp
+  degrees) because the wire format is layout-invariant.
 - REFCOUNT CONSERVATION: imported blocks are freed on retire and on
   mid-stream cancel, and a failure mid-import leaks nothing
   (all-or-nothing).
@@ -109,9 +109,9 @@ def _resume(params, req: GenRequest, shipped, **kv):
 
 
 @pytest.mark.parametrize("src,dst", [
-    pytest.param({}, {}, id="dense-to-dense"),
-    pytest.param({}, {"kv_block_size": 4}, id="dense-to-paged"),
-    pytest.param({"kv_block_size": 4}, {}, id="paged-to-dense"),
+    pytest.param({}, {}, id="default-to-default"),   # blocks of 16 rows
+    pytest.param({}, {"kv_block_size": 4}, id="default-to-bs4"),
+    pytest.param({"kv_block_size": 4}, {}, id="bs4-to-default"),
     pytest.param({"kv_block_size": 4}, {"kv_block_size": 8},
                  id="paged4-to-paged8"),
 ])

@@ -1,8 +1,8 @@
 """Serving integration tests (nanodiloco_tpu/serve): continuous-batching
-bit-parity against sequential ``generate()`` — run against BOTH the
-dense per-slot cache and the paged block pool (the paged-fp engine must
-reproduce every stream bit-identically through block tables, chunk
-scatter, and copy-on-write prefix sharing) — and the HTTP server over a
+bit-parity against sequential ``generate()`` — run over two geometries
+of the block pool (the float pool must reproduce every stream
+bit-identically through block tables, chunk scatter, and copy-on-write
+prefix sharing) — and the HTTP server over a
 REAL socket (POST /v1/generate, /healthz, serve gauges on /metrics)."""
 
 import json
@@ -29,12 +29,13 @@ CFG = LlamaConfig(
     num_attention_heads=4, num_hidden_layers=2, max_position_embeddings=64,
 )
 
-# the parity suite runs twice: dense per-slot rows and the paged block
-# pool (fp arena) — the latter must stay bit-identical through block
-# gather/scatter and copy-on-write prefix sharing
+# the parity suite runs over two pool geometries (float arena): the
+# default block size (16, clamped to the chunk size: the block at the
+# chunk's cap) and blocks of 4 rows — both must stay bit-identical
+# through block gather/scatter and copy-on-write prefix sharing
 KV_MODES = [
-    pytest.param({}, id="dense"),
-    pytest.param({"kv_block_size": 4}, id="paged"),
+    pytest.param({}, id="default"),
+    pytest.param({"kv_block_size": 4}, id="bs4"),
 ]
 
 # THE acceptance test additionally runs on a tensor-parallel mesh
@@ -43,8 +44,8 @@ KV_MODES = [
 # (generate(mesh=...)) — across layouts only greedy token-identity can
 # hold, because the tp psums reassociate float reductions
 KV_TP_MODES = KV_MODES + [
-    pytest.param({"tp": 2}, id="dense-tp2"),
-    pytest.param({"kv_block_size": 4, "tp": 2}, id="paged-tp2"),
+    pytest.param({"tp": 2}, id="default-tp2"),
+    pytest.param({"kv_block_size": 4, "tp": 2}, id="bs4-tp2"),
 ]
 
 
@@ -200,15 +201,15 @@ def test_chunked_prefill_boundary_parity(params, kv):
 
 
 @pytest.mark.parametrize("kv", KV_MODES + [
-    # dense-tp2: the extract/insert device copies move SHARDED chunk
-    # K/V through the host-keyed cache — the one tp path the
-    # acceptance matrix doesn't already cross
-    pytest.param({"tp": 2}, id="dense-tp2"),
+    # default-tp2: a prefix hit maps blocks of a SHARDED arena through
+    # the host-keyed cache — the one tp path the acceptance matrix
+    # doesn't already cross
+    pytest.param({"tp": 2}, id="default-tp2"),
 ])
 def test_prefix_cache_hit_parity_and_counters(params, kv):
     """Cached-prefix admission bit-parity: requests B and D share A's
-    chunk-aligned prefix — their admission copies A's cached K/V rows
-    and prefills only the suffix — and C opts out. All four streams are
+    chunk-aligned prefix — their admission maps A's cached blocks into
+    their tables and prefills only the suffix — and C opts out. All four streams are
     bit-identical to solo generate(); the counters prove B and D
     genuinely reused cached chunks (D's whole prompt IS the prefix, so
     the reuse is capped one chunk short: the last token must prefill
@@ -251,8 +252,8 @@ def test_prefix_cache_hit_parity_and_counters(params, kv):
 def test_compile_count_bounded_across_mixed_lengths():
     """The recompile-trap pin: mixed-length admissions compile chunk
     programs only for the power-of-two bucket set (<= log2(chunk)+1),
-    NOT one executable per prompt length, and exactly one decode/sample
-    program each. Uses its own config so the jit caches under count
+    NOT one executable per prompt length, exactly one decode program,
+    and no copy program for the prefix cache (a hit maps blocks). Uses its own config so the jit caches under count
     start empty."""
     cfg2 = LlamaConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64,
@@ -274,17 +275,16 @@ def test_compile_count_bounded_across_mixed_lengths():
             break
     assert all(t.done() for t in tickets)
     counts = eng.compile_counts()
-    assert counts["layout"] == "dense"
-    if counts["prefill_chunk:dense"] is None:
+    assert counts["layout"] == "paged"
+    if counts["prefill_chunk:paged"] is None:
         pytest.skip("jit cache introspection unavailable on this jax")
     # 12 distinct prompt lengths -> at most the 4 bucket lengths
     # {1, 2, 4, 8} ever compile (the PR-4 path compiled 12); sampling
     # is fused into the chunk and decode programs, so there is no
     # separate sample executable at all
-    assert 1 <= counts["prefill_chunk:dense"] <= 4
-    assert counts["decode:dense"] == 1
-    assert counts["extract:dense"] in (None, 0, 1)
-    assert counts["insert:dense"] in (None, 0, 1)
+    assert 1 <= counts["prefill_chunk:paged"] <= 4
+    assert counts["decode:paged"] == 1
+    assert not any(k.startswith(("extract", "insert")) for k in counts)
     # the dispatched program-shape ledger: every chunk bucket a power
     # of two <= 8, the decode tick always T=1
     assert set(counts["buckets"]["prefill_chunk"]) <= {1, 2, 4, 8}
@@ -306,7 +306,7 @@ def test_engine_validates_impossible_requests(params):
 
 def test_tp_greedy_token_identical_across_layouts(params):
     """Cross-layout greedy token-identity: the same greedy requests
-    through dense-tp2, tp4, and paged-tp2 engines produce the same
+    through tp2, tp4, and tp2 with blocks of 4 rows produce the same
     token ids as unsharded solo generate(). (Bit-parity of SAMPLED
     streams only holds within one layout — the tp psums reassociate
     float reductions — which is exactly what the same-layout acceptance
@@ -353,7 +353,7 @@ def test_compile_counts_keyed_by_layout():
     keyed (kind, layout) — with ``buckets`` carrying the dispatched
     (kind, bucket) shapes — so a per-layout compile pin can NEVER
     silently read another layout's program set (the old flat
-    ``prefill_chunk`` key reported dense and paged counts identically
+    ``prefill_chunk`` key reported float and int8 counts identically
     named). Dedicated config — distinct VALUES too, not just a fresh
     object: LlamaConfig hashes by value, so a config equal to another
     test's would share its lru-cached jits and absorb its compiles."""
@@ -377,31 +377,29 @@ def test_compile_counts_keyed_by_layout():
                 break
         assert all(t.done() for t in tickets)
 
-    dense = InferenceEngine(paramsc, cfgc, num_slots=2, max_len=32,
-                            chunk_size=8)
+    quant = InferenceEngine(paramsc, cfgc, num_slots=2, max_len=32,
+                            chunk_size=8, kv_dtype="int8")
     paged = InferenceEngine(paramsc, cfgc, num_slots=2, max_len=32,
                             chunk_size=8, kv_block_size=8)
-    drive(dense)
+    drive(quant)
     drive(paged)
-    dc, pc = dense.compile_counts(), paged.compile_counts()
-    assert dc["layout"] == "dense" and pc["layout"] == "paged"
+    qc, pc = quant.compile_counts(), paged.compile_counts()
+    assert qc["layout"] == "paged-int8" and pc["layout"] == "paged"
     # each layout's counts live ONLY under its own keys
-    assert "prefill_chunk:dense" in dc and "prefill_chunk:paged" not in dc
-    assert "prefill_chunk:paged" in pc and "prefill_chunk:dense" not in pc
-    # dense-only copy programs never appear under the paged layout
-    assert "extract:dense" in dc and not any(
-        k.startswith("extract") for k in pc
-    )
+    assert "prefill_chunk:paged-int8" in qc and "prefill_chunk:paged" not in qc
+    assert "prefill_chunk:paged" in pc and "prefill_chunk:paged-int8" not in pc
+    # no layout has a copy program: a prefix hit maps blocks
+    assert not any(k.startswith(("extract", "insert")) for k in (*qc, *pc))
     # the dispatched shapes: prompts of 3 and 8 -> chunk buckets {4, 8}
     # in both layouts, decode always T=1
-    assert dc["buckets"]["prefill_chunk"] == [4, 8]
+    assert qc["buckets"]["prefill_chunk"] == [4, 8]
     assert pc["buckets"]["prefill_chunk"] == [4, 8]
-    assert dc["buckets"]["decode"] == pc["buckets"]["decode"] == [1]
+    assert qc["buckets"]["decode"] == pc["buckets"]["decode"] == [1]
     # a tp engine's keys are further qualified by the degree
     tp = InferenceEngine(paramsc, cfgc, num_slots=1, max_len=32,
                          chunk_size=8, tp=2)
-    assert tp.compile_counts()["layout"] == "dense-tp2"
-    assert "prefill_chunk:dense-tp2" in tp.compile_counts()
+    assert tp.compile_counts()["layout"] == "paged-tp2"
+    assert "prefill_chunk:paged-tp2" in tp.compile_counts()
 
 
 def test_tp_metrics_and_stats_jsonl_flow(params, tmp_path):

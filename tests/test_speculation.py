@@ -2,7 +2,7 @@
 the engine/scheduler multi-token tick contract).
 
 The load-bearing contract: with speculation enabled, EVERY stream —
-greedy and sampled, dense and paged, whatever the proposer does — is
+greedy and sampled, at either block size, whatever the proposer does — is
 bit-identical to solo ``generate()``, because acceptance is exact
 (a draft survives iff it equals the token the plain tick would have
 sampled with the same per-step key; for a deterministic proposal this
@@ -31,8 +31,8 @@ CFG = LlamaConfig(
 )
 
 KV_MODES = [
-    pytest.param({}, id="dense"),
-    pytest.param({"kv_block_size": 4}, id="paged"),
+    pytest.param({}, id="default"),   # blocks of 16 rows, clamped to the chunk
+    pytest.param({"kv_block_size": 4}, id="bs4"),
 ]
 
 
@@ -216,7 +216,7 @@ def test_proposer_adaptive_k_feedback():
     assert p.current_k(0) == 0 and p.propose(0, 4) == []
 
 
-# -- greedy + sampled bit-parity, dense x paged x proposer -------------------
+# -- greedy + sampled bit-parity, block size x proposer -----------------------
 
 
 SPEC_MODES = [
@@ -500,11 +500,11 @@ def test_compile_count_pinned_with_speculation():
             break
     assert all(t.done() for t in tickets)
     counts = eng.compile_counts()
-    if counts["verify:dense"] is None:
+    if counts["verify:paged"] is None:
         pytest.skip("jit cache introspection unavailable on this jax")
-    assert 1 <= counts["verify:dense"] <= 3   # T buckets {2, 3, 5}
-    assert counts["decode:dense"] == 1
-    assert 1 <= counts["prefill_chunk:dense"] <= 4
+    assert 1 <= counts["verify:paged"] <= 3   # T buckets {2, 3, 5}
+    assert counts["decode:paged"] == 1
+    assert 1 <= counts["prefill_chunk:paged"] <= 4
     # every dispatched verify width was a bucketed T in {2, 3, 5}
     assert set(counts["buckets"].get("verify", [])) <= {2, 3, 5}
 
