@@ -37,6 +37,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from nanodiloco_tpu.models.config import LlamaConfig
 from nanodiloco_tpu.models.llama import (
@@ -499,8 +500,6 @@ def pad_prompts(prompts: list[list[int]], pad_id: int = 0):
     (tokens [B, P], valid [B, P]) ready for ``generate``. An empty ROW is
     allowed (all-pad, valid all zero — the caller decides whether an
     empty prompt is meaningful); an empty LIST is not."""
-    import numpy as np
-
     if not prompts:
         raise ValueError("pad_prompts needs at least one prompt")
     p = max(len(x) for x in prompts)
@@ -542,7 +541,16 @@ def pad_prompts(prompts: list[list[int]], pad_id: int = 0):
 #     layer's K/V through the block tables INSIDE the layer scan, so
 #     the contiguous working view exists one layer at a time, and
 #     writes each slot's new row by physical (block, offset) scatter
-#     (inactive slots are redirected out of range and dropped).
+#     (inactive slots are redirected out of range and dropped). The
+#     view is NOT the table's whole width: the tick reads the first
+#     ``w`` blocks of every table, ``w`` the narrowest of a fixed ladder
+#     of widths that holds the longest live slot's rows, picked inside
+#     the program from the positions (``view_ladder``, below).
+# A table is one chunk of sentinel entries wider than any allocation
+# (the engine's ``table_blocks``): a right-padded final chunk's rows
+# past the allocation then still fall inside the table, where their
+# writes drop, and the ladder's top width is that whole table, so a
+# call at the very top of an allocation fits its view too.
 # A shared prefix is shared BLOCKS, by reference: no program copies K/V
 # rows. Sampling params ride as traced arrays so a new request with new
 # temperature/top_k/top_p reuses the same executable.
@@ -741,8 +749,11 @@ def prefill_chunk_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
     whose block table this is, and sample from its last-real-position
     logits in the same executable (an interior chunk's sample is
     discarded by the caller — a vocab sort, noise next to the decoder).
-    Gathers the slot's contiguous K/V view through its block table
-    (clamped out-of-range sentinel entries read causally-dead garbage),
+    Gathers the slot's contiguous K/V view through its WHOLE block
+    table, every layer's at once (clamped out-of-range sentinel entries
+    read causally-dead garbage; the ticks' ladder of view widths is not
+    taken here: ``_cached_block`` wants all layers' views before its
+    layer scan),
     runs the SAME ``_cached_block`` the one-shot ``generate`` prefill
     runs — so float-pool logits are bit-identical to solo
     ``generate()`` — and scatters only the touched blocks back. The
@@ -818,8 +829,10 @@ def _decode_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
     """One decode step for B independent slots: ``tokens`` [B] at
     PER-SLOT positions ``pos`` [B] — the T=1 special case of the
     speculative verify block (per-layer in-scan gather through the
-    tables, physical (block, row) scatter BEFORE the gather, inactive
-    slots redirected to the out-of-range sentinel and dropped),
+    first blocks of the tables, as many as the longest live slot's rows
+    need (``_table_attention``), physical (block, row) scatter BEFORE
+    the gather, inactive slots redirected to the out-of-range sentinel
+    and dropped),
     delegated so the per-slot-position transformer step has ONE
     implementation the tick and its verify widening can never drift
     between. Returns (logits [B, V] float32, updated pool)."""
@@ -954,6 +967,73 @@ def _slot_attention(q, ck, cv, mask):
     return jnp.einsum("bkgts,bskd->btkgd", probs, cv).reshape(b, t, nh * hd)
 
 
+# -- the view a full-attention read takes through the block tables ----------
+#
+# A block table is as wide as the longest request the engine admits, and
+# a call's queries see rows ``[0, need)`` alone, ``need`` = the last
+# query position of any live row + 1: every row at or past it is masked
+# for every query of the call, and ``MASK_VALUE`` underflows to an exact
+# zero in the float32 softmax, so a view of ANY width >= need gives the
+# same bits. The read therefore takes the narrowest of a fixed ladder of
+# widths that holds ``need``, chosen INSIDE the program (``lax.switch``
+# on the positions it is handed): one executable whatever the streams
+# hold, nothing for the host to stage. The new rows' write stays outside
+# the branches and before them; the pool enters them read-only.
+
+VIEW_STEPS = 8
+
+
+def view_ladder(table_blocks: int) -> tuple[int, ...]:
+    """The view widths, in blocks, a table of ``table_blocks`` entries
+    is read at: the distinct ``ceil(table_blocks * i / VIEW_STEPS)``,
+    ascending, the table's whole width last. A function of the table's
+    width alone (a toy table collapses to fewer widths), shared by the
+    programs and by the engine's host-side tally of the widths taken."""
+    return tuple(sorted({-(-table_blocks * i // VIEW_STEPS)
+                         for i in range(1, VIEW_STEPS + 1)}))
+
+
+def view_rung(ladder: tuple[int, ...], need_rows, block_size: int):
+    """Index of the narrowest width of ``ladder`` that holds
+    ``need_rows`` rows (the count of widths too narrow; the top width
+    where none holds them). ``need_rows`` is a host integer or a traced
+    scalar: the program and the engine's tally run this one rule."""
+    need_blocks = (need_rows + block_size - 1) // block_size
+    return (np.asarray(ladder[:-1], np.int32) < need_blocks).sum()
+
+
+def _table_attention(tables, block_size: int, qpos, active):
+    """``attend(q, read) -> [B, T, H * hd]`` for queries at positions
+    ``qpos`` [B, T] over each row's K/V behind ``tables`` [B, mb]:
+    ``read(tables[:, :w])`` gathers (and dequantizes) a view of ``w``
+    blocks, ``(ck, cv)`` [B, w * block_size, Hkv, hd], and runs inside
+    the one branch whose width is taken, under a causal mask of that
+    width. Dead rows (``active`` 0) count no rows; their queries read
+    garbage, as they always did. A ladder of one width emits no branch
+    and builds its mask here, outside any layer loop."""
+    mb = tables.shape[1]
+    ladder = view_ladder(mb)
+
+    def mask_of(w):
+        with jax.named_scope("attention"):
+            ok = jnp.arange(w * block_size)[None, None, :] <= qpos[:, :, None]
+            return jnp.where(ok, 0.0, MASK_VALUE)[:, None]   # [B, 1, T, S]
+
+    if len(ladder) == 1:
+        mask = mask_of(mb)
+        return lambda q, read: _slot_attention(q, *read(tables), mask)
+    need = jnp.max(jnp.where(active > 0, qpos[:, -1] + 1, 0))
+    rung = view_rung(ladder, need, block_size)
+
+    def attend(q, read):
+        def at(w):
+            return lambda q: _slot_attention(q, *read(tables[:, :w]), mask_of(w))
+
+        return jax.lax.switch(rung, [at(w) for w in ladder], q)
+
+    return attend
+
+
 def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
                               tables, pos, active, quant: bool):
     """``_decode_slots_paged_block`` widened to T = k+1 positions per
@@ -964,8 +1044,13 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
     bits). Each of the T new rows scatters at its own physical (block,
     row) address — a verify window may CROSS a block boundary, so
     addresses are resolved per position — before the gather, all inside
-    the layer scan; a dead slot's writes drop (a tick lands MID-prefill
-    of a neighbour slot, which must not be stamped with garbage K/V).
+    the layer scan. The gather reads each table's first ``w`` blocks,
+    the narrowest width of ``view_ladder`` that holds ``pos + T`` rows
+    of the longest live slot (``_table_attention``: one branch a width
+    inside this one program; rows past it are masked for every query of
+    the call, so any such width gives the same bits); a dead slot's
+    writes drop (a tick lands MID-prefill of a neighbour slot, which
+    must not be stamped with garbage K/V).
     Positions past a slot's allocation hit the sentinel table entry and
     drop; rejected/pad rows inside the allocation are overwritten by a
     later tick before the cursor can ever expose them (see the section
@@ -974,7 +1059,6 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
     b, t = tokens.shape
     _l, nb, bs, nkv, hd = pool["k"].shape
     mb = tables.shape[1]
-    s_view = mb * bs
     nh = cfg.num_attention_heads
 
     with jax.named_scope("embed"):
@@ -988,10 +1072,7 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
         a1, a2 = a[..., :half], a[..., half:]
         return a * cos + jnp.concatenate([-a2, a1], axis=-1) * sin
 
-    ki = jnp.arange(s_view)
-    with jax.named_scope("attention"):
-        ok = ki[None, None, :] <= qpos[:, :, None]
-        mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]      # [B, 1, T, S]
+    view = _table_attention(tables, bs, qpos, active)
     # per-(slot, position) physical addresses; inactive slots redirect
     # past the arena and drop, exactly like the T=1 tick
     with jax.named_scope("kv_write"):
@@ -1024,16 +1105,19 @@ def _verify_slots_paged_block(params, cfg: LlamaConfig, tokens, pool,
             else:
                 pk = pk.at[phys, off].set(k.astype(pk.dtype), mode="drop")
                 pv = pv.at[phys, off].set(v.astype(pv.dtype), mode="drop")
-        with jax.named_scope("kv_gather"):
-            if quant:
-                ck = _dequantize_rows(pk[tables], pks[tables], cdt)
-                cv = _dequantize_rows(pv[tables], pvs[tables], cdt)
-            else:
-                ck, cv = pk[tables], pv[tables]
-            ck = ck.reshape(b, s_view, nkv, hd).astype(cdt)
-            cv = cv.reshape(b, s_view, nkv, hd).astype(cdt)
 
-        attn = _slot_attention(q, ck, cv, mask)
+        @jax.named_scope("kv_gather")
+        def read(tw):
+            if quant:
+                ck = _dequantize_rows(pk[tw], pks[tw], cdt)
+                cv = _dequantize_rows(pv[tw], pvs[tw], cdt)
+            else:
+                ck, cv = pk[tw], pv[tw]
+            rows = tw.shape[1] * bs
+            return (ck.reshape(b, rows, nkv, hd).astype(cdt),
+                    cv.reshape(b, rows, nkv, hd).astype(cdt))
+
+        attn = view(q, read)
         with jax.named_scope("attn_proj"):
             x = x + attn @ layer["wo"].astype(cdt)
 
@@ -1148,7 +1232,10 @@ def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slo
                        pos, active, token_valid):
     """The decoder over ``tokens`` [B, T] at per-slot positions
     ``pos..pos+T-1`` through both kinds of cache. ``tables`` [B, mb] are
-    the rows' block tables; ``ring_slot`` is None where row b IS ring
+    the rows' block tables, read by a full layer at the narrowest width
+    of ``view_ladder`` that holds ``pos + T`` rows of the longest live
+    row (``_table_attention``: a tick's longest stream, a chunk's own
+    end); ``ring_slot`` is None where row b IS ring
     slot b (the tick: B = slots) or the traced ring slot of the one row
     (a prefill chunk: B = 1); ``active`` [B] drops dead rows' writes.
     Returns (final-normed hidden [B, T, d], cache, counters int32[4],
@@ -1169,9 +1256,7 @@ def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slo
     def attend_full(entry):
         nb, bs = entry["k"].shape[:2]
         mb = tables.shape[1]
-        with jax.named_scope("attention"):
-            ok = jnp.arange(mb * bs)[None, None, :] <= qpos[:, :, None]
-            mask = jnp.where(ok, 0.0, MASK_VALUE)[:, None]   # [B, 1, T, S]
+        view = _table_attention(tables, bs, qpos, active)
         with jax.named_scope("kv_write"):
             phys = jnp.take_along_axis(tables, jnp.clip(qpos // bs, 0, mb - 1), axis=1)
             phys = jnp.where(active[:, None] > 0, phys, nb)  # dead rows drop
@@ -1181,10 +1266,14 @@ def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slo
             with jax.named_scope("kv_write"):
                 pk = entry["k"].at[phys, off].set(k.astype(cdt), mode="drop")
                 pv = entry["v"].at[phys, off].set(v.astype(cdt), mode="drop")
-            with jax.named_scope("kv_gather"):
-                ck = pk[tables].reshape(b, mb * bs, *pk.shape[2:])
-                cv = pv[tables].reshape(b, mb * bs, *pv.shape[2:])
-            return _slot_attention(q, ck, cv, mask), {"k": pk, "v": pv}
+
+            @jax.named_scope("kv_gather")
+            def read(tw):
+                rows = tw.shape[1] * bs
+                return (pk[tw].reshape(b, rows, *pk.shape[2:]),
+                        pv[tw].reshape(b, rows, *pv.shape[2:]))
+
+            return view(q, read), {"k": pk, "v": pv}
 
         return attend
 
@@ -1255,7 +1344,9 @@ def decode_slots_mixed_fn(cfg: LlamaConfig):
     ``(params, cache, tables [B, max_blocks] i32, tokens [B], pos [B],
     key_data [B,2] u32, temperature [B], top_k [B], top_p [B],
     active [B]) -> (next_tokens [B], cache, counters int32[4], chosen
-    experts [L_sparse, B, 1, k])``: one tick advancing every slot."""
+    experts [L_sparse, B, 1, k])``: one tick advancing every slot, the
+    full layers reading the tables' first blocks up to the longest live
+    slot's rows and no further (``_table_attention``)."""
 
     def run(params, cache, tables, tokens, pos, key_data,
             temperature, top_k, top_p, active):

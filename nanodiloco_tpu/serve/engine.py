@@ -149,6 +149,8 @@ from nanodiloco_tpu.models.generate import (
     prefill_chunk_mixed_fn,
     prefill_chunk_paged_fn,
     verify_slots_paged_fn,
+    view_ladder,
+    view_rung,
 )
 from nanodiloco_tpu.models.moe import COUNTERS
 from nanodiloco_tpu.obs.devtime import DispatchAccountant
@@ -345,6 +347,13 @@ class InferenceEngine:
         # per-slot block tables; the sentinel nb is out of range: reads
         # clamp to causally-dead garbage, writes drop
         self._tables = np.full((b, self.table_blocks), nb, np.int32)
+        # the width a tick's full-attention read took through the tables
+        # (generate.py ``view_ladder``): the program picks it from the
+        # positions it is handed, and the host names it by the same rule
+        # from ``_pos``: a histogram of view rows over the decode and
+        # verify dispatches, one bucket a width (``kv_stats()``)
+        self._view_ladder = view_ladder(self.table_blocks)
+        self.hist_view_rows = Histogram(w * bs for w in self._view_ladder)
         self._slot_blocks: list[list[int]] = [[] for _ in range(b)]
         self.kv_block_evictions = 0
         self.hist_blocks_per_request = Histogram(_BLOCK_BUCKETS)
@@ -897,6 +906,14 @@ class InferenceEngine:
                         self._jarr(act)))
         return out
 
+    def _tally_view(self, slots: list[int], t: int) -> None:
+        """Count one dispatch over ``slots`` at ``t`` positions a slot
+        under the view width its program takes."""
+        bs = self.kv_block_size
+        need = int(self._pos[slots].max()) + t if slots else 0
+        rung = int(view_rung(self._view_ladder, need, bs))
+        self.hist_view_rows.observe(self._view_ladder[rung] * bs)
+
     def _step_plain(self) -> list[list[int]]:
         b = self.num_slots
         with trace_span("engine.stage"):
@@ -916,6 +933,7 @@ class InferenceEngine:
             dispatches = self._gen_dispatches(dev)
         out: list[list[int]] = [[] for _ in range(b)]
         for params, slots, active in dispatches:
+            self._tally_view(slots, 1)
             with self.accountant.section("decode", 1, self.kv_layout):
                 counts = chosen = None
                 with trace_span("engine.decode_dispatch"):
@@ -993,6 +1011,7 @@ class InferenceEngine:
             dispatches = self._gen_dispatches(dev)
         out: list[list[int]] = [[] for _ in range(b)]
         for params, slots, active in dispatches:
+            self._tally_view(slots, t)
             with self.accountant.section("verify", t, self.kv_layout):
                 with trace_span("engine.decode_dispatch"):
                     sampled, counts, self.pool = self._verify(
@@ -1442,10 +1461,25 @@ class InferenceEngine:
                 self.block_pool.num_blocks * self.kv_block_size
                 * kv_bytes_per_token(self.cfg, self.kv_dtype)
             )}
+        # the full-attention read's view over the decode and verify
+        # dispatches so far: mean rows a slot, their share of the table's
+        # rows, dispatches by view rows (the widths taken alone)
+        views = self.hist_view_rows.snapshot()
+        mean = views["sum"] / views["count"] if views["count"] else None
+        below, by_view = 0, {}
+        for rows, cum in views["buckets"][:-1]:
+            if cum > below:
+                by_view[str(int(rows))] = cum - below
+            below = cum
         out = {
             **ps,
             "kv_dtype": self.kv_dtype or str(self.cfg.dtype),
             "block_evictions": self.kv_block_evictions,
+            "view_rows_mean": mean,
+            "view_share": (None if mean is None else
+                           mean / (self.table_blocks * self.kv_block_size)),
+            "ticks_by_view": by_view,
+            "hist_view_rows": views,
             **extra,
             "hist_blocks_per_request": self.hist_blocks_per_request.snapshot(),
         }

@@ -900,6 +900,19 @@ class ServeServer:
                 "nanodiloco_kv_block_size_tokens", "gauge",
                 "token rows per KV block", [(None, kv["block_size"])],
             ))
+            if kv.get("view_share") is not None:
+                families.append((
+                    "nanodiloco_kv_view_share", "gauge",
+                    "rows the ticks' full-attention reads gathered through "
+                    "the block tables, over the tables' rows",
+                    [(None, kv["view_share"])],
+                ))
+                families.append((
+                    "nanodiloco_kv_view_rows", "histogram",
+                    "rows a slot a tick's full-attention read gathered, "
+                    "over the decode and verify dispatches (one bucket "
+                    "a view width)", kv["hist_view_rows"],
+                ))
             per_shard = kv.get("blocks_free_per_shard")
             if per_shard:
                 # its own family (not labeled samples on

@@ -406,6 +406,9 @@ def summarize_run(path: str) -> dict[str, Any]:
             out["kv_block_evictions"] = kv.get("block_evictions")
             if kv.get("block_size") is not None:
                 out["kv_block_size"] = kv.get("block_size")
+            if kv.get("view_share") is not None:
+                out["kv_view_share"] = kv["view_share"]
+                out["kv_view_rows_mean"] = kv["view_rows_mean"]
         for key in ("admission_blocked_no_slot",
                     "admission_blocked_no_blocks"):
             if last.get(key) is not None:

@@ -20,12 +20,15 @@ from benchmark import correctness_sparse as cs
 from benchmark.reference import exaone_moe_ref as ref
 from nanodiloco_tpu.models import LlamaConfig, init_params
 from nanodiloco_tpu.models.generate import (
+    decode_slots_mixed_fn,
     decode_slots_paged_fn,
     generate,
     init_kv_pool,
     init_mixed_serve_cache,
     prefill_chunk_mixed_fn,
     prefill_chunk_paged_fn,
+    verify_slots_paged_fn,
+    view_ladder,
 )
 from nanodiloco_tpu.models.llama import causal_lm_loss, forward, layer_plan, sp_shard_loss
 from nanodiloco_tpu.models.moe import sparse_mlp
@@ -279,26 +282,52 @@ def _lower_toy(cfg, program):
             shapes, pool, _S((8,), i32), _S((1, 8), i32), _S((1, 8), i32), _S((), i32),
             _S((), i32), _S((2,), u32), _S((), f32), _S((), i32), _S((), f32))
     assert program == "paged_tick"
-    return decode_slots_paged_fn(cfg).lower(
-        shapes, pool, _S((2, 8), i32), _S((2,), i32), _S((2,), i32), _S((2, 2), u32),
-        _S((2,), f32), _S((2,), i32), _S((2,), f32), _S((2,), i32))
+    return _lower_tick(cfg, "tick", 1)
+
+
+def _lower_tick(cfg, program, mb):
+    """A tick program of the serve engine over tables of ``mb`` blocks:
+    ``tick`` or ``verify`` (three positions a slot), ``_int8`` for
+    int8 rows; for the mixed stack ``tick`` or ``chunk`` (four tokens)."""
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    slot = (_S((2,), f32), _S((2,), i32), _S((2,), f32), _S((2,), i32))
+    if cfg.mixed:
+        cache = jax.eval_shape(lambda: init_mixed_serve_cache(cfg, 2, 16, 32, 4))
+        if program == "chunk":
+            return prefill_chunk_mixed_fn(cfg).lower(
+                shapes, cache, _S((mb,), i32), _S((), i32), _S((1, 4), i32), _S((1, 4), i32),
+                _S((), i32), _S((), i32), _S((2,), u32), _S((), f32), _S((), i32), _S((), f32))
+        return decode_slots_mixed_fn(cfg).lower(
+            shapes, cache, _S((2, mb), i32), _S((2,), i32), _S((2,), i32), _S((2, 2), u32), *slot)
+    kv = "int8" if program.endswith("_int8") else None
+    pool = jax.eval_shape(lambda: init_kv_pool(cfg, 16, 4, kv))
+    if program.startswith("verify"):
+        return verify_slots_paged_fn(cfg, kv).lower(
+            shapes, pool, _S((2, mb), i32), _S((2, 3), i32), _S((2,), i32), _S((2,), i32),
+            _S((2, 3, 2), u32), *slot)
+    return decode_slots_paged_fn(cfg, kv).lower(
+        shapes, pool, _S((2, mb), i32), _S((2,), i32), _S((2,), i32), _S((2, 2), u32), *slot)
 
 
 # sha256 of the lowered text under jax 0.9.0. "dense": the programs of
 # the dense cells; forward's was taken on the commit before the mixed
 # stack (b70a8d0), the rest on the commit before the short path of
 # models/moe.py (98f3879), as were "ragged"'s: a configuration that
-# holds all its experts has no short path and lowers as it did.
+# holds all its experts has no short path and lowers as it did. The
+# ticks' since PR 30 over tables of ONE block, taken on the commit
+# before the ladder of view widths (08b6a60): a ladder of one width
+# emits no branch.
 LOWERED = {
     ("dense", "forward"): "4cc0a5e8fbc000fa",
     ("dense", "loss_gradient"): "0a25538edc8f2e24",
     ("dense", "paged_chunk"): "22fd6380ece8aa8e",
-    ("dense", "paged_tick"): "c147e342d1bfd11d",
+    ("dense", "paged_tick"): "36ba83875010c97a",
     ("dense", "fused_round"): "2236461a9273367d",
     ("ragged", "forward"): "a67ab44a2c55b2b3",
     ("ragged", "loss_gradient"): "1dfd52542ec6df87",
     ("ragged", "paged_chunk"): "4c6051755649e1bf",
-    ("ragged", "paged_tick"): "0ae473b63bb9d43b",
+    ("ragged", "paged_tick"): "d4f3c9d1e798c798",
     ("ragged", "fused_round"): "d51b6466d5cc44df",
 }
 
@@ -316,6 +345,96 @@ def test_a_dense_configuration_lowers_to_the_program_it_had(toy, program):
     assert hashlib.sha256(text.encode()).hexdigest().startswith(LOWERED[toy, program])
     if program in ("forward", "loss_gradient", "fused_round"):  # sampling has a case of its own
         assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
+# -- the ladder of view widths (models/generate.py ``view_ladder``) ----------
+
+# sha256 of the lowered text of the tick programs over tables of ONE
+# block on the commit before the ladder (08b6a60), jax 0.9.0
+ONE_RUNG = {
+    ("dense", "tick_int8"): "19960ff43ba37937",
+    ("dense", "verify"): "0040eb21b53023ad",
+    ("dense", "verify_int8"): "c821b97e364966a6",
+    ("mixed", "chunk"): "1eadc95d19219e28",
+    ("mixed", "tick"): "96820e4f7a6b4953",
+}
+
+
+@pytest.mark.parametrize("toy,program", sorted(ONE_RUNG))
+def test_a_table_of_one_width_lowers_to_the_program_it_had(toy, program):
+    """A ladder of one width emits no branch: the text is the parent's.
+    Over a table of 8 blocks the same program holds one conditional
+    more, with a branch a width."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded text is jax 0.9.0's")
+    cfg = {"dense": DENSE_TOY, "mixed": TINY}[toy]
+    text = _lower_tick(cfg, program, 1).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest().startswith(ONE_RUNG[toy, program])
+    wide = _lower_tick(cfg, program, 8).as_text()
+    assert wide.count("stablehlo.case") == text.count("stablehlo.case") + 1
+    assert len(view_ladder(8)) == 8 and view_ladder(1) == (1,)
+
+
+def _serve_all(eng, reqs, ticks=200):
+    sched = Scheduler(eng)
+    tickets = [sched.submit(r) for r in reqs]
+    for _ in range(ticks):
+        if sched.tick() == 0 and all(t.done() for t in tickets):
+            break
+    return [t.result["tokens"] for t in tickets]
+
+
+def _prompt(n, seed):
+    return tuple(np.random.default_rng(seed).integers(0, TINY.vocab_size, n).tolist())
+
+
+# max_len 64 in blocks of 4 and chunks of 8: a table of 18 blocks, read at
+# 12, 20, 28, 36, 48, 56, 64 or 72 rows
+VIEW_STREAMS = {
+    # the short stream (6-13 rows) ticks at 12 rows while the long one's
+    # prompt is still in chunks, then beside its 42-48 rows at 48
+    "short_beside_long": [(41, 8), (5, 8)],
+    # rows 10..39 of one stream: the tick passes 12, 20, 28, 36 and 48 rows
+    "crosses_widths_mid_decode": [(9, 30)],
+    # the final chunk starts at 56 with 7 tokens and pads to 8: rows up to
+    # 64, the top of the slot's allocation, in a view of 64
+    "padded_chunk_at_the_top": [(63, 1)],
+}
+
+
+@pytest.mark.parametrize("streams", sorted(VIEW_STREAMS))
+def test_mixed_streams_hold_through_the_view_widths(params, streams):
+    """The full layer's read through the block table at the width the
+    positions ask for: every stream is solo ``generate()``'s."""
+    eng = InferenceEngine(params, TINY, num_slots=2, max_len=64, chunk_size=8,
+                          kv_block_size=4)
+    assert [w * 4 for w in view_ladder(eng.table_blocks)] == [12, 20, 28, 36, 48, 56, 64, 72]
+    reqs = [GenRequest(prompt=_prompt(n, 7 + i), max_new_tokens=new)
+            for i, (n, new) in enumerate(VIEW_STREAMS[streams])]
+    got = _serve_all(eng, reqs)
+    for r, tokens in zip(reqs, got):
+        want = generate(params, jnp.asarray([r.prompt]), TINY, r.max_new_tokens)
+        assert tokens == np.asarray(want[0]).tolist()
+    taken = {int(r) for r in eng.kv_stats()["ticks_by_view"]}
+    assert taken == {"short_beside_long": {12, 48},
+                     "crosses_widths_mid_decode": {12, 20, 28, 36, 48},
+                     "padded_chunk_at_the_top": set()}[streams]
+
+
+def test_every_view_width_is_one_mixed_tick_program():
+    """A stream that grows through all eight widths (chunks of one
+    block: the table's last width is within a tick's reach) runs one
+    decode executable and the chunk buckets it dispatched."""
+    cfg = dataclasses.replace(TINY, initializer_range=0.11)  # programs of its own
+    eng = _engine(cfg, chunk_size=4)
+    out, = _serve_all(eng, [GenRequest(prompt=_prompt(5, 3), max_new_tokens=59)])
+    assert len(out) == 59
+    kv = eng.kv_stats()
+    assert [int(r) for r in kv["ticks_by_view"]] == [w * 4 for w in view_ladder(17)]
+    assert sum(kv["ticks_by_view"].values()) == 58
+    counts = eng.compile_counts()
+    assert counts["decode:paged-rings"] == 1
+    assert counts["prefill_chunk:paged-rings"] == len(counts["buckets"]["prefill_chunk"]) == 2
 
 
 # -- the short path of the grouped products, through the serve programs -----

@@ -5,6 +5,8 @@ bit-identically through block tables, chunk scatter, and copy-on-write
 prefix sharing) — and the HTTP server over a
 REAL socket (POST /v1/generate, /healthz, serve gauges on /metrics)."""
 
+import dataclasses
+import importlib
 import json
 import threading
 
@@ -14,6 +16,7 @@ import numpy as np
 import pytest
 
 from nanodiloco_tpu.models import LlamaConfig, generate, init_params
+from nanodiloco_tpu.models.generate import view_ladder, view_rung
 from nanodiloco_tpu.obs.telemetry import parse_metrics_text
 from nanodiloco_tpu.serve import (
     GenRequest,
@@ -23,6 +26,9 @@ from nanodiloco_tpu.serve import (
     http_get,
     http_post_json,
 )
+
+# the module: ``nanodiloco_tpu.models.generate`` as an attribute is the function
+generate_module = importlib.import_module("nanodiloco_tpu.models.generate")
 
 CFG = LlamaConfig(
     vocab_size=128, hidden_size=64, intermediate_size=128,
@@ -435,16 +441,190 @@ def test_tp_metrics_and_stats_jsonl_flow(params, tmp_path):
         "tp_degree": stats["tp_degree"],
         "kv_pool": {"blocks_free": 16, "blocks_used": 0,
                     "num_blocks": 16, "block_size": 4,
+                    "view_share": stats["kv_pool"]["view_share"],
+                    "view_rows_mean": stats["kv_pool"]["view_rows_mean"],
                     "blocks_free_per_shard": {"0": 16, "1": 16}},
     }) + "\n")
     s = summarize_run(str(new))
     assert s["serve_tp_degree"] == 2
+    # one tick of a 4-row stream through a table of 16 blocks of 4 rows
+    assert s["kv_view_rows_mean"] == 8 and s["kv_view_share"] == 8 / 64
     assert s["kv_blocks_free_per_shard"] == {"0": 16, "1": 16}
     old = tmp_path / "old.jsonl"
     old.write_text(json.dumps({"serve_stats": True, "served": 1}) + "\n")
     s2 = summarize_run(str(old))
-    assert "serve_tp_degree" not in s2
+    assert "serve_tp_degree" not in s2 and "kv_view_share" not in s2
     assert "kv_blocks_free_per_shard" not in s2
+
+
+# -- the ladder of view widths (models/generate.py ``view_ladder``) ----------
+
+
+@pytest.mark.parametrize("mb", [1, 2, 7, 17, 18, 144, 544])
+def test_view_ladder_takes_the_narrowest_width_that_holds_the_rows(mb):
+    """For every count of blocks a call can need, the width taken is the
+    smallest of the ladder that holds it; the top width is the table's;
+    the program's traced rule is the host's."""
+    ladder = view_ladder(mb)
+    assert ladder[-1] == mb and list(ladder) == sorted(set(ladder))
+    assert 1 <= len(ladder) <= generate_module.VIEW_STEPS
+    assert set(ladder) == {-(-mb * i // 8) for i in range(1, 9)}
+    bs = 4
+    needs = np.arange(0, mb * bs + 2 * bs)          # past the table too
+    got = [ladder[int(view_rung(ladder, int(n), bs))] for n in needs]
+    want = [min((w for w in ladder if w * bs >= n), default=mb) for n in needs]
+    assert got == want
+    traced = jax.jit(jax.vmap(lambda n: view_rung(ladder, n, bs) + 0))(
+        jnp.asarray(needs, jnp.int32))
+    assert [ladder[int(i)] for i in traced] == want
+
+
+class _JunkProposer:
+    """Always proposes ``cap`` copies of one token: every tick is a
+    verify tick of k + 1 positions a slot, nearly every draft rejected."""
+
+    def begin(self, slot, prompt_ids, first_token): pass
+    def release(self, slot): pass
+    def propose(self, slot, cap): return [1] * cap
+    def observe(self, slot, emitted): pass
+    def feedback(self, slot, proposed, accepted): pass
+
+
+# max_len 64 in blocks and chunks of 4: a table of 17 blocks, read at
+# 12, 20, 28, 36, 44, 52, 60 or 68 rows
+VIEW_ENGINES = {
+    "paged": {},
+    "paged-int8": {"kv_dtype": "int8"},
+    "paged-tp2": {"tp": 2},
+    "verify": {"spec_k": 3},
+}
+VIEW_STREAMS = {
+    # the short stream (4-11 rows) ticks alone at 12 rows while the long
+    # one's prompt is in chunks, then beside its 42-50 rows at 44 and 52
+    "short_beside_long": [(41, 10, 0.8), (3, 9, 0.7)],
+    # rows 10..34 of one stream: its ticks pass four widths mid-decode
+    "crosses_widths_mid_decode": [(9, 26, 0.9)],
+}
+
+
+def _view_requests(streams):
+    return [
+        GenRequest(prompt=tuple((5 * i + 3 * j) % 50 + 1 for j in range(n)),
+                   max_new_tokens=new, temperature=temp, top_k=12, seed=60 + i)
+        for i, (n, new, temp) in enumerate(VIEW_STREAMS[streams])
+    ]
+
+
+def _serve_views(params, cfg, reqs, **kw):
+    eng = InferenceEngine(params, cfg, num_slots=2, max_len=64, chunk_size=4,
+                          kv_block_size=4, **kw)
+    if eng.spec_k:
+        eng.speculator = _JunkProposer()
+    sched = Scheduler(eng)
+    tickets = [sched.submit(r) for r in reqs]
+    for _ in range(300):
+        if sched.tick() == 0 and all(t.done() for t in tickets):
+            break
+    return eng, [t.result["tokens"] for t in tickets]
+
+
+@pytest.mark.parametrize("streams", sorted(VIEW_STREAMS))
+@pytest.mark.parametrize("engine", sorted(VIEW_ENGINES))
+def test_streams_hold_through_the_view_widths(params, monkeypatch, engine, streams):
+    """A tick reads each slot's K/V through its block table at the
+    narrowest width of the ladder that holds every live row: a short
+    stream beside a long neighbour, and a stream whose rows pass widths
+    mid-decode, are bit-identical to solo ``generate()``; int8 rows to
+    the same engine reading every table at its whole width."""
+    kw = VIEW_ENGINES[engine]
+    reqs = _view_requests(streams)
+    with jax.default_matmul_precision("highest"):
+        eng, got = _serve_views(params, CFG, reqs, **kw)
+        if engine == "paged-int8":
+            # an equal configuration that differs in a field nothing
+            # here reads: programs of its own, traced with one width
+            whole = dataclasses.replace(CFG, max_position_embeddings=65)
+            monkeypatch.setattr(generate_module, "view_ladder", lambda mb: (mb,))
+            _, want = _serve_views(params, whole, reqs, **kw)
+        else:
+            want = [_reference(params, r, tp=kw.get("tp", 1)) for r in reqs]
+    assert got == want
+    taken = sorted(int(r) for r in eng.kv_stats()["ticks_by_view"])
+    if engine == "verify":
+        assert eng.spec_ticks > 0 and len(taken) >= 2  # rows pos + 4 a tick
+    else:
+        assert taken == {"short_beside_long": [12, 44, 52],
+                         "crosses_widths_mid_decode": [12, 20, 28, 36]}[streams]
+
+
+@pytest.mark.parametrize("engine", ["paged", "paged-int8", "verify"])
+def test_every_view_width_is_one_tick_program(engine):
+    """One stream grows through all eight widths of its table: one
+    decode executable (and one verify executable a draft width), the
+    chunk buckets it dispatched and no other."""
+    cfg2 = LlamaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        num_attention_heads=2, num_hidden_layers=1,
+        max_position_embeddings=66,  # programs of its own
+    )
+    params2 = init_params(jax.random.key(1), cfg2)
+    req = GenRequest(prompt=(5, 9, 2, 11, 3), max_new_tokens=59, seed=2)
+    eng, (out,) = _serve_views(params2, cfg2, [req], **VIEW_ENGINES[engine])
+    assert len(out) == 59
+    kv = eng.kv_stats()
+    assert [int(r) for r in kv["ticks_by_view"]] == [w * 4 for w in view_ladder(17)]
+    counts = eng.compile_counts()
+    layout = counts["layout"]
+    if counts[f"decode:{layout}"] is None:
+        pytest.skip("jit cache introspection unavailable on this jax")
+    assert counts[f"prefill_chunk:{layout}"] == len(counts["buckets"]["prefill_chunk"]) == 2
+    if engine == "verify":
+        assert counts[f"verify:{layout}"] == len(counts["buckets"]["verify"])
+        assert counts[f"decode:{layout}"] <= 1
+    else:
+        assert counts[f"decode:{layout}"] == 1
+
+
+@pytest.mark.parametrize("prompt_len,new", [(61, 3), (57, 7)])
+def test_a_stream_at_the_top_of_its_allocation_fits_its_view(params, prompt_len, new):
+    """The last rows a slot can hold (max_len 64: 16 of the table's 17
+    blocks): the final chunk is right-padded to its bucket there and the
+    ticks after it read the table's top width."""
+    req = GenRequest(prompt=tuple((3 * j) % 50 + 1 for j in range(prompt_len)),
+                     max_new_tokens=new, temperature=0.8, top_k=12, seed=9)
+    with jax.default_matmul_precision("highest"):
+        eng, (got,) = _serve_views(params, CFG, [req])
+        assert got == _reference(params, req)
+    assert max(int(r) for r in eng.kv_stats()["ticks_by_view"]) == 68
+
+
+def test_kv_stats_view_share_is_what_the_positions_imply(params):
+    """A scripted run: two slots prefilled to 5 and 30 rows, ticked
+    together, the long one released, the short one ticked on. The
+    tally names each tick's width from the longest live slot's rows."""
+    eng = InferenceEngine(params, CFG, num_slots=2, max_len=64, chunk_size=4,
+                          kv_block_size=4)
+    assert eng.kv_stats()["view_share"] is None
+    assert eng.kv_stats()["ticks_by_view"] == {}
+    eng.prefill(0, GenRequest(prompt=tuple(range(1, 6)), max_new_tokens=30))
+    eng.prefill(1, GenRequest(prompt=tuple(range(1, 31)), max_new_tokens=30))
+    rows = []
+    for tick in range(20):
+        if tick == 8:
+            eng.release(1)
+        live = [5 + tick] + ([30 + tick] if tick < 8 else [])
+        need = max(live) + 1                  # the row this tick writes
+        rows.append(min(w * 4 for w in view_ladder(17) if w * 4 >= need))
+        eng.step()
+    kv = Scheduler(eng).stats()["kv_pool"]
+    assert kv["ticks_by_view"] == {
+        str(r): rows.count(r) for r in sorted(set(rows))}
+    assert kv["view_rows_mean"] == sum(rows) / 20
+    assert kv["view_share"] == sum(rows) / (20 * 68)
+    assert kv["hist_view_rows"]["count"] == 20
+    assert [b for b, _ in kv["hist_view_rows"]["buckets"][:-1]] == [
+        w * 4 for w in view_ladder(17)]
+    assert rows[0] == 36 and rows[7] == 44 and rows[8] == 20 and rows[-1] == 28
 
 
 # -- the HTTP server over a real socket --------------------------------------
@@ -511,6 +691,12 @@ def test_generate_endpoint_over_real_socket(params):
         assert m["nanodiloco_serve_ttft_seconds"] > 0
         assert m["nanodiloco_serve_decode_tokens_per_sec"] > 0
         assert m["nanodiloco_serve_tokens_total"] >= 18
+        # the ticks' view through the block tables: every dispatch counted
+        # at its width, the share what the histogram's mean implies
+        kv = srv._scheduler.stats()["kv_pool"]
+        assert m["nanodiloco_kv_view_rows_count"] == kv["hist_view_rows"]["count"] > 0
+        assert m["nanodiloco_kv_view_share"] == pytest.approx(kv["view_share"])
+        assert 0 < kv["view_share"] <= 1
         assert body.rstrip().endswith("# EOF")
         # the TTFT histogram: 3 served requests, cumulative buckets
         # monotone and capped by the +Inf bucket == _count
