@@ -99,6 +99,11 @@ class LlamaConfig:
     # "all": rotary embedding in every layer; "sliding": in sliding
     # layers only (full layers see no position but the causal order)
     rope_layers: str = "all"
+    # rotary parameters by layer kind (an HF ``rope_parameters`` whose
+    # keys are LAYER_KINDS), frozen to sorted pairs ((kind, ((key,
+    # value), ...)), ...) so that the configuration stays hashable:
+    # ``rope_for(kind)`` reads it. None: one table from ``rope_theta``
+    rope_parameters: tuple | None = None
     # layers [0, first_k_dense_replace) keep the dense SwiGLU of width
     # ``intermediate_size``; the rest are sparse, each expert of width
     # ``moe_intermediate_size`` (None: ``intermediate_size``)
@@ -133,6 +138,7 @@ class LlamaConfig:
         return bool(
             (self.layer_types is not None and "sliding_attention" in self.layer_types)
             or self.qk_norm or self.rope_layers != "all"
+            or self.rope_parameters is not None
             or self.first_k_dense_replace or self.num_shared_experts
             or self.scoring_func != "softmax" or not self.norm_topk_prob
             or self.routed_scaling_factor != 1.0
@@ -148,6 +154,15 @@ class LlamaConfig:
     def held_experts(self) -> tuple[int, int]:
         """(first, count) of the routed experts this chip holds."""
         return self.experts_held or (0, self.num_experts)
+
+    def rope_for(self, kind: str) -> dict[str, Any]:
+        """The rotary parameters of a layer of ``kind``: ``rope_type``
+        ("default" or "yarn"), ``rope_theta`` and, for YaRN, ``factor``,
+        ``original_max_position_embeddings``, ``beta_fast``,
+        ``beta_slow`` and ``attention_factor``."""
+        if self.rope_parameters is None:
+            return {"rope_type": "default", "rope_theta": self.rope_theta}
+        return dict(dict(self.rope_parameters)[kind])
 
     def layer_kind(self, i: int) -> tuple[str, bool]:
         """(attention kind, sparse feed-forward?) of layer ``i``."""
@@ -214,6 +229,15 @@ class LlamaConfig:
                 raise ValueError("sliding_attention layers need sliding_window >= 1")
         if self.rope_layers not in ("all", "sliding"):
             raise ValueError(f"rope_layers must be 'all' or 'sliding'; got {self.rope_layers!r}")
+        if self.rope_parameters is not None:
+            kinds = {kind for kind, _ in self.rope_parameters}
+            if kinds != set(LAYER_KINDS):
+                raise ValueError(
+                    f"rope_parameters by layer kind must name {LAYER_KINDS}; got {sorted(kinds)}")
+            for kind in LAYER_KINDS:
+                if self.rope_for(kind).get("rope_type", "default") not in ("default", "yarn"):
+                    raise ValueError(
+                        f"rope_type must be 'default' or 'yarn'; got {self.rope_for(kind)!r}")
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"scoring_func must be 'softmax' or 'sigmoid'; got {self.scoring_func!r}")
@@ -256,15 +280,21 @@ class LlamaConfig:
         (ref nanodiloco/main.py:97); we accept the same files, including
         keys we don't model (``architectures``, ``use_cache``). HF's
         ``head_dim`` is ``explicit_head_dim`` here, ``rope_theta`` may
-        stand inside ``rope_parameters``, and lists become tuples (the
+        stand inside ``rope_parameters``, a ``rope_parameters`` keyed by
+        layer kind is kept (``rope_for``), and lists become tuples (the
         configuration is a hashable jit-static argument).
         """
         names = {f.name for f in dataclasses.fields(cls)}
         d = dict(d)
         if "head_dim" in d and "explicit_head_dim" not in d:
             d["explicit_head_dim"] = d["head_dim"]
-        if "rope_theta" not in d and "rope_theta" in (d.get("rope_parameters") or {}):
-            d["rope_theta"] = float(d["rope_parameters"]["rope_theta"])
+        rope = d.pop("rope_parameters", None) or {}
+        if "rope_theta" not in d and "rope_theta" in rope:
+            d["rope_theta"] = float(rope["rope_theta"])
+        if rope and set(dict(rope)) <= set(LAYER_KINDS):  # a dict, or its frozen pairs
+            d["rope_parameters"] = tuple(sorted(
+                (kind, tuple(sorted((k, v) for k, v in dict(of).items())))
+                for kind, of in dict(rope).items()))
         return cls(**{k: tuple(v) if isinstance(v, list) else v
                       for k, v in d.items() if k in names})
 

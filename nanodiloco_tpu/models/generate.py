@@ -941,8 +941,10 @@ def _accept_prefix(tokens, sampled, draft_len):
 def _slot_rope_tables(cfg: LlamaConfig, qpos, cdt):
     """cos/sin ``[B, T, 1, hd]`` in the rotate-half convention for
     per-(slot, position) global positions ``qpos`` [B, T]: the serve
-    programs' ``rope_tables``, one phase a row."""
+    programs' ``rope_tables``, one phase a row (and, as there, one
+    table: refused where the configuration has one a layer kind)."""
     hd = cfg.head_dim
+    rope_tables(cfg, 0)  # raises for rotary parameters by layer kind
     inv_freq = 1.0 / (
         cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     )

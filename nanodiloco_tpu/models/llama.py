@@ -36,8 +36,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from nanodiloco_tpu.models.config import LlamaConfig
-from nanodiloco_tpu.models.moe import COUNTERS
+from nanodiloco_tpu.models.config import LAYER_KINDS, LlamaConfig
+from nanodiloco_tpu.models.moe import COUNTERS, ROUTER_STATS
 
 Params = dict[str, Any]
 
@@ -276,18 +276,58 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (normed * scale.astype(jnp.float32)).astype(dtype)
 
 
+def yarn_ramp(rope: dict, hd: int) -> tuple[int, int]:
+    """(low, high) of YaRN's linear ramp over the ``hd // 2`` rotary
+    dimensions (arXiv:2309.00071): the dimension that turns ``beta``
+    times over the original context is d(beta) = hd ln(L / (2 pi beta))
+    / (2 ln theta); dimensions below ``low`` = floor(d(beta_fast)) keep
+    their frequency, those from ``high`` = ceil(d(beta_slow)) on are
+    divided by ``factor``, the ones between are blended. Clipped to
+    [0, hd - 1] as the published code does."""
+    def d(beta):
+        return hd * math.log(rope["original_max_position_embeddings"] / (2 * math.pi * beta)) / (
+            2 * math.log(rope["rope_theta"]))
+
+    return (max(math.floor(d(rope["beta_fast"])), 0),
+            min(math.ceil(d(rope["beta_slow"])), hd - 1))
+
+
 def rope_tables(
-    cfg: LlamaConfig, seq_len: int, offset: int | jax.Array = 0
+    cfg: LlamaConfig, seq_len: int, offset: int | jax.Array = 0, kind: str | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables in the HF rotate-half convention: frequencies are
     computed for the half head-dim then concatenated with themselves.
     Shapes [seq_len, head_dim], float32. ``offset`` may be a traced scalar
-    (e.g. ``axis_index`` under shard_map for sequence parallelism)."""
+    (e.g. ``axis_index`` under shard_map for sequence parallelism).
+    ``kind`` (one of LAYER_KINDS) takes the layer kind's own parameters
+    (``cfg.rope_for``): the default table at its ``rope_theta``, or
+    YaRN's blended frequencies with cos and sin times
+    ``attention_factor``. None: the one table of ``cfg.rope_theta``,
+    refused for a configuration with a table a layer kind (the cached
+    and pipelined programs know one table: they would run such a model
+    with its rotary parameters left out)."""
     hd = cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    if kind is None and cfg.rope_parameters is not None:
+        raise ValueError(
+            "this configuration has rotary parameters by layer kind (rope_parameters) "
+            "and this program builds one table: only the training forward pass "
+            "(models/llama.py:forward) builds a table a layer kind")
+    rope = {"rope_theta": cfg.rope_theta} if kind is None else cfg.rope_for(kind)
+    inv_freq = 1.0 / (rope["rope_theta"] ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    scale = None
+    if rope.get("rope_type", "default") == "yarn":
+        low, high = yarn_ramp(rope, hd)
+        ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 0.001), 0.0, 1.0)
+        inv_freq = (1.0 - ramp) * inv_freq + ramp * inv_freq / rope["factor"]
+        scale = rope.get("attention_factor")
+        if scale is None:
+            scale = 0.1 * math.log(rope["factor"]) + 1.0
     pos = jnp.arange(seq_len, dtype=jnp.float32) + offset
     freqs = jnp.outer(pos, inv_freq)                     # [S, hd/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)       # [S, hd]
+    if scale is not None:
+        return jnp.cos(emb) * scale, jnp.sin(emb) * scale
     return jnp.cos(emb), jnp.sin(emb)
 
 
@@ -308,19 +348,23 @@ MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def causal_mask(s: int, valid: jax.Array | None = None, start: int = 0,
-                window: int | None = None) -> jax.Array:
-    """Additive [B|1, 1, S - start, S] float32 mask for query rows
-    ``start..S`` over keys ``0..S``: causal, optionally restricted to
-    ``valid`` [B, S] key positions (1 = real token) and to the last
-    ``window`` keys of each row (a sliding layer: i - window < j <= i)."""
-    qi = jax.lax.broadcasted_iota(jnp.int32, (s - start, s), 0) + start
-    ki = jax.lax.broadcasted_iota(jnp.int32, (s - start, s), 1)
+                window: int | None = None, first_key: int = 0) -> jax.Array:
+    """Additive [B|1, 1, S - start, S - first_key] float32 mask for query
+    rows ``start..S`` over keys ``first_key..S``: causal, optionally
+    restricted to ``valid`` [B, S] key positions (1 = real token) and to
+    the last ``window`` keys of each row (a sliding layer: i - window <
+    j <= i)."""
+    qi = jax.lax.broadcasted_iota(jnp.int32, (s - start, s - first_key), 0) + start
+    ki = jax.lax.broadcasted_iota(jnp.int32, (s - start, s - first_key), 1)
+    if first_key:
+        ki = ki + first_key
+        valid = None if valid is None else valid[:, first_key:]
     ok = qi >= ki
     if window is not None:
         ok = ok & (qi - ki < window)
-    ok = ok[None]                              # [1, S - start, S]
+    ok = ok[None]                              # [1, S - start, S - first_key]
     if valid is not None:
-        ok = ok & (valid[:, None, :] > 0)      # [B, S - start, S]
+        ok = ok & (valid[:, None, :] > 0)      # [B, S - start, S - first_key]
     return jnp.where(ok, 0.0, MASK_VALUE)[:, None]
 
 
@@ -335,6 +379,13 @@ def causal_mask(s: int, valid: jax.Array | None = None, start: int = 0,
 # longer ones: the rule is a function of S alone.
 DENSE_BLOCK_Q = 256
 DENSE_MAX_BLOCKS = 16
+# The probabilities of every block are what a backward pass keeps of one
+# layer's attention. Where they pass this many bytes (2 x 8,192 tokens,
+# 32 heads: 4.6 GB a full layer in bf16, 1.5 GB a window layer of 1,024)
+# each block is a ``jax.checkpoint`` of its own and the backward pass
+# makes a block's scores again when it reaches it: one block's
+# probabilities live at a time. A function of the shapes alone.
+DENSE_SAVED_PROBS_MAX = 1 << 30
 
 
 def dense_block_rows(s: int, bq: int | None = None) -> int:
@@ -370,24 +421,41 @@ def dense_attention(
     key, so it runs in one block. A row with no valid key at all (left
     padding) softmaxes to uniform over its block's keys, not over S:
     finite either way, and loss-masked. ``window`` (a sliding layer) is
-    one more term of the causal mask; the blocks still meet every key at
-    or before their last row."""
+    one more term of the causal mask, and bounds the keys a block meets:
+    the block of rows [start, end) reads keys [max(0, start - window +
+    1), end) and no others, the first key its first row may see.
+
+    Where all the blocks' probabilities together pass
+    ``DENSE_SAVED_PROBS_MAX`` bytes, each block is recomputed in the
+    backward pass (``jax.checkpoint`` around a block)."""
     b, s, h, hd = q.shape
     scale = 1.0 / math.sqrt(hd)
     explicit = mask is not None and mask.ndim == 4
     rows = s if explicit else dense_block_rows(s, bq)
-    out = []
-    for start in range(0, s, rows):
-        end = start + rows
+
+    def keys_from(start: int) -> int:
+        return 0 if window is None else max(0, start - window + 1)
+
+    def one_block(start, q, k, v, valid):
+        # the slices are made in here: a block made again in the backward
+        # pass keeps q, k and v whole, which the layer holds anyway
+        end, lo = start + rows, keys_from(start)
         scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q[:, start:end], k[:, :end]
+            "bqhd,bkhd->bhqk", q[:, start:end], k[:, lo:end]
         ).astype(jnp.float32) * scale
         block = mask if explicit else causal_mask(
-            end, None if mask is None else mask[:, :end], start, window
+            end, None if valid is None else valid[:, :end], start, window, lo
         )
         scores = scores + block.astype(jnp.float32)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out.append(jnp.einsum("bhqk,bkhd->bqhd", probs, v[:, :end]))
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v[:, lo:end])
+
+    saved = b * h * q.dtype.itemsize * sum(
+        rows * (start + rows - keys_from(start)) for start in range(0, s, rows))
+    if saved > DENSE_SAVED_PROBS_MAX:
+        one_block = jax.checkpoint(one_block, static_argnums=0)
+    out = [one_block(start, q, k, v, None if explicit else mask)
+           for start in range(0, s, rows)]
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
@@ -474,24 +542,28 @@ def _decoder_layer(
     return mlp_block(cfg, x, layer, valid, sp_axis=sp_axis, with_stats=with_stats)
 
 
-def mixed_mlp_block(cfg: LlamaConfig, x, layer: Params, valid=None):
+def mixed_mlp_block(cfg: LlamaConfig, x, layer: Params, valid=None, with_stats=False):
     """``mlp_block`` of a mixed configuration's layer, dense or sparse by
     the weights it holds: (x, counters int32[4], chosen experts
     [B, S, k] or None) with the sparse layer's counters and choice
     (``moe.sparse_mlp``), zeros and None for a dense one. Shared by the
-    training forward and the cached programs."""
+    training forward and the cached programs. ``with_stats`` (the
+    training forward asks, the cached programs do not) puts the float32
+    vector ``moe.ROUTER_STATS`` in the choice's place, zeros for a dense
+    layer."""
     cdt = x.dtype
     h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
     with jax.named_scope("mlp"):
         if "router" in layer:
             from nanodiloco_tpu.models.moe import sparse_mlp
 
-            out, counters, chosen = sparse_mlp(cfg, h, layer, valid)
-            return x + out, counters, chosen
+            out, counters, rec = sparse_mlp(cfg, h, layer, valid, with_stats)
+            return x + out, counters, rec
         gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
         up = h @ layer["w_up"].astype(cdt)
         return (x + (gate * up) @ layer["w_down"].astype(cdt),
-                jnp.zeros((len(COUNTERS),), jnp.int32), None)
+                jnp.zeros((len(COUNTERS),), jnp.int32),
+                jnp.zeros((len(ROUTER_STATS),), jnp.float32) if with_stats else None)
 
 
 def mlp_block(
@@ -539,6 +611,7 @@ def forward(
     return_hidden: bool = False,
     with_aux: bool = False,
     collect_stats: bool = False,
+    with_choices: bool = False,
 ) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, vocab] float32 (or the final
     normed hidden states [B, S, d] in compute dtype if ``return_hidden`` —
@@ -554,13 +627,30 @@ def forward(
     ``collect_stats`` (implies an extra return value; diagnostics only,
     never the training program) appends the layer-mean MoE router stats
     [dropped_frac, router_entropy] — see moe.make_router_stats_fn.
+
+    A mixed sparse configuration's ``with_aux`` returns ``(out, aux,
+    counters)``: ``moe.TRAIN_COUNTERS`` as int32, summed over the layers
+    (the four ``moe.COUNTERS`` and the largest group's rows).
+    ``with_choices`` (the same configurations; a probe, never the
+    training program) returns ``(out, choices)``: the experts each
+    sparse layer chose, [sparse layers, B, S, k] int32.
     """
     cdt = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
     with jax.named_scope("embed"):
         x = params["embed"].astype(cdt)[tokens]
     with jax.named_scope("attn_proj"):
-        cos, sin = rope_tables(cfg, s, offset=position_offset)
+        if cfg.mixed:
+            # one table a layer kind, built once a pass: the kind's own
+            # rotary parameters where the configuration has them
+            with jax.named_scope("rope"):
+                if cfg.rope_parameters is None:
+                    tables = dict.fromkeys(LAYER_KINDS, rope_tables(cfg, s, position_offset))
+                else:
+                    tables = {kind: rope_tables(cfg, s, position_offset, kind)
+                              for kind in sorted({k[0] for k in layer_plan(cfg).kinds})}
+        else:
+            cos, sin = rope_tables(cfg, s, offset=position_offset)
 
     # flash and ring are PACKED-sequence kernels: attn_mask only weights
     # the loss, it never restricts attention (dense honors it for the
@@ -584,21 +674,33 @@ def forward(
         return out[0], out[1:]
 
     if cfg.mixed:
-        # leading layers and scanned periods (run_layers). The gate of a
-        # mixed configuration has no auxiliary loss and no capacity:
-        # aux and the probe's [dropped_frac, router_entropy] read zero
+        # leading layers and scanned periods (run_layers). Nothing is
+        # dropped (no capacity); a softmax gate has the Switch balance
+        # term over the router's full width, a sigmoid gate balances by
+        # its selection bias and has none
+        sparse = bool(cfg.num_experts)
+
         def mixed_layer(x, layer, kind, _):
             def fn(x, layer):
+                cos, sin = tables[kind[0]]
                 x = _attn_block(cfg, x, layer, cos, sin, attn_mask, sp_axis, kind[0])
-                return mixed_mlp_block(cfg, x, layer, attn_mask)[:2]
+                return mixed_mlp_block(cfg, x, layer, attn_mask, sparse and not with_choices)
 
             if cfg.remat:
                 fn = jax.checkpoint(fn, policy=checkpoint_policy(cfg))
-            x, counters = fn(x, layer)
-            return x, None, counters, None
+            x, counters, rec = fn(x, layer)
+            return x, None, counters, rec
 
-        x = run_layers(cfg, params, x, mixed_layer)[0]
+        x, _, counters, recs = run_layers(cfg, params, x, mixed_layer)
         aux, stats = jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.float32)
+        if sparse and not with_choices:
+            balance, max_rows, entropy = jnp.sum(jnp.stack(recs), axis=0)
+            if cfg.scoring_func == "softmax":
+                aux = balance
+            n_sparse = sum(kind[1] for kind in layer_plan(cfg).kinds)
+            stats = jnp.stack([jnp.zeros((), jnp.float32), entropy / n_sparse])
+            # a layer's largest group is at most k*T rows: exact in float32
+            moe_counters = jnp.concatenate([counters, max_rows.astype(jnp.int32)[None]])
     else:
         # the scan's own work (a layer's weights sliced out of the stack,
         # the residuals stacked for the backward pass) reads as layer_scan
@@ -609,8 +711,14 @@ def forward(
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
     def pack(out):
+        if with_choices:
+            if not (cfg.mixed and cfg.num_experts):
+                raise ValueError("with_choices reads a mixed sparse configuration's layers")
+            return out, jnp.stack([r for r in recs if r is not None])
         if collect_stats:
             return (out, aux, stats) if with_aux else (out, stats)
+        if with_aux and cfg.mixed and cfg.num_experts:
+            return out, aux, moe_counters
         return (out, aux) if with_aux else out
 
     if return_hidden:
@@ -643,10 +751,12 @@ def causal_lm_loss(
     microbatch losses can be combined exactly under grad accumulation.
     """
     targets = tokens[:, 1:]
+    # a mixed sparse configuration's pass also hands out what its expert
+    # layers did (forward): "moe_counters" in the loss's aux
     if cfg.loss_chunk:
         from nanodiloco_tpu.ops.fused_ce import chunked_softmax_xent
 
-        h, aux = forward(
+        h, aux, *moe = forward(
             params, tokens, cfg, attn_mask=loss_mask, sp_axis=sp_axis,
             return_hidden=True, with_aux=True,
         )
@@ -670,9 +780,10 @@ def causal_lm_loss(
             loss = sum_loss / n + cfg.router_aux_coef * aux
         return loss, {
             "n_tokens": n_tok, "sum_loss": sum_loss, "router_aux": aux,
+            **({"moe_counters": moe[0]} if moe else {}),
         }
 
-    logits, aux = forward(
+    logits, aux, *moe = forward(
         params, tokens, cfg, attn_mask=loss_mask, sp_axis=sp_axis, with_aux=True
     )
     with jax.named_scope("loss"):
@@ -688,6 +799,7 @@ def causal_lm_loss(
         loss = sum_loss / n + cfg.router_aux_coef * aux
     return loss, {
         "n_tokens": jnp.sum(m), "sum_loss": sum_loss, "router_aux": aux,
+        **({"moe_counters": moe[0]} if moe else {}),
     }
 
 

@@ -54,10 +54,15 @@ and scatter-add over the first ``short_rows`` sorted rows alone (twice
 the expected held pairs, in an odd number of 128s) whenever the held
 pairs fit them, and over all k*T rows otherwise (a ``lax.cond`` on
 ``sum(group_sizes)``): the same pairs, groups and weights either way,
-nothing dropped. Where all experts are held there is no short path and
-no conditional.
+nothing dropped. Where a held expert expects whole tiles of 512 rows (a
+training step) the short rows are three times the expected pairs in
+such tiles (``short_rows``). Where all experts are held there is no
+short path and no conditional.
 ``sparse_mlp`` returns four counters (``COUNTERS``); the fourth says how
-often the short path was taken. On the chip (PERF.md, PR 28).
+often the short path was taken. On the chip (PERF.md, PR 28). Trained,
+a softmax gate's layer also hands out its balance term over the router's
+full width, its largest group's rows and its scores' entropy
+(``ROUTER_STATS``; PERF.md, PR 32).
 
 Capacity factor (measured, round 5 — phase "cf", fixed 120-step budget
 on the pylib corpus, 8 experts top-2, ``runs/moe_evidence_r5.jsonl``):
@@ -92,9 +97,32 @@ from nanodiloco_tpu.models.config import LlamaConfig
 # factor (640 rows 3.24, 1,408 rows 3.37), so the factor buys headroom.
 SHORT_ROWS_FACTOR = 2
 SHORT_ROWS_TILE = 128
+# Where a held expert expects a whole tile of 512 rows or more (a training
+# step: 2,048 rows a group at 16,384 tokens, 8 of 64 experts held) the
+# same kernel wants its largest tile, and a skewed router's share passes
+# twice the expected pairs in a fifth of the calls. One sweep on the chip
+# (PERF.md, PR 32: one sparse layer forward, made again and backward):
+# over 32,896 rows (tiles of 128) 63.2 ms at the expected load and 34 ms
+# more for each further expected load; over 32,768 (tiles of 512: an
+# expert's 4 MB of weights read once a tile) 44.1 and 13 more; over 49,152
+# 48.2 and 14 more; all 131,072 rows 70.6 and 89 at 2.3 times the
+# expected. So there the
+# rows come in whole tiles of 512, three times the expected pairs of
+# them: a call at twice the expected takes 61 ms and not 89.
+SHORT_ROWS_WIDE_FACTOR = 3
+SHORT_ROWS_WIDE_TILE = 512
 
 # What ``sparse_mlp``'s int32 counter vector holds, in order.
 COUNTERS = ("moe_held_pairs", "moe_experts_hit", "moe_pairs", "moe_short_path")
+# What the training forward sums over a pass's layers
+# (``llama.forward(with_aux=True)``): the counters and the rows of
+# each layer's largest held group (what imbalance the grouped products saw)
+TRAIN_COUNTERS = COUNTERS + ("moe_max_group_rows",)
+# What ``sparse_mlp`` hands out in the choice's place when asked
+# (``with_stats``), a float32 each: the Switch balance term E sum_e f_e
+# P_e over the router's full width, the largest held group's rows, the
+# mean entropy of a token's scores in nats
+ROUTER_STATS = ("balance", "max_group_rows", "entropy")
 
 
 def expert_capacity(cfg: LlamaConfig, n_tokens: int) -> int:
@@ -110,7 +138,9 @@ def make_router_stats_fn(cfg: LlamaConfig):
     sync on one microbatch, so a collapsed router or capacity-bound
     token dropping shows up in the JSONL instead of staying silent
     (VERDICT r3 weak #4). One extra forward per sync (~1/H of a step);
-    the training program itself is untouched. Ring attention swaps to
+    the training program itself is untouched. A mixed stack has no
+    capacity, so nothing is dropped and the first reads 0; its entropy
+    is that of a token's scores over their sum. Ring attention swaps to
     the numerically-identical blockwise flash, as Evaluator does."""
     import dataclasses
 
@@ -190,13 +220,60 @@ def short_rows(cfg: LlamaConfig, n_pairs: int) -> int | None:
     sorted token-expert pairs, or None where there is no short path:
     ``SHORT_ROWS_FACTOR`` times the pairs expected at held experts
     (``n_pairs`` x held / router width), rounded up to an odd number of
-    ``SHORT_ROWS_TILE`` rows. None where that passes half of
-    ``n_pairs``: every configuration that holds all its experts, and a
-    share too large for the short path to save much."""
+    ``SHORT_ROWS_TILE`` rows; where a held expert expects a whole
+    ``SHORT_ROWS_WIDE_TILE`` rows or more, ``SHORT_ROWS_WIDE_FACTOR``
+    times the expected in whole such tiles. None where that passes half
+    of ``n_pairs``: every configuration that holds all its experts, and
+    a share too large for the short path to save much."""
     expected = n_pairs * cfg.held_experts[1] / cfg.num_experts
-    tiles = max(1, math.ceil(SHORT_ROWS_FACTOR * expected / SHORT_ROWS_TILE))
-    cap = SHORT_ROWS_TILE * (tiles + 1 - tiles % 2)
+    if expected >= SHORT_ROWS_WIDE_TILE * cfg.held_experts[1]:
+        cap = SHORT_ROWS_WIDE_TILE * math.ceil(
+            SHORT_ROWS_WIDE_FACTOR * expected / SHORT_ROWS_WIDE_TILE)
+    else:
+        tiles = max(1, math.ceil(SHORT_ROWS_FACTOR * expected / SHORT_ROWS_TILE))
+        cap = SHORT_ROWS_TILE * (tiles + 1 - tiles % 2)
     return cap if 2 * cap <= n_pairs else None
+
+
+@jax.custom_vjp
+def _held_rows_gradient(xg: jax.Array, held: jax.Array) -> jax.Array:
+    """``xg`` [n, d] as it is; in the backward pass the gradient of the
+    rows that ``held`` [n] marks and zero for the rest. A grouped
+    product leaves the rows in no group alone, in its transpose too, so
+    on the chip the gathered rows' gradient holds whatever was there for
+    a pair routed elsewhere, and the gather's transpose would add it
+    into a token's gradient (my chip run, PR 32: a first backward pass
+    of NaN). Forward programs are what they were. Where every expert is
+    held only padding lies in no group, and that path is left as it
+    was (ROADMAP B5)."""
+    return xg
+
+
+_held_rows_gradient.defvjp(
+    lambda xg, held: (xg, held),
+    lambda held, ct: (jnp.where(held[:, None], ct, 0), None))
+
+
+def _weigh(out: jax.Array, w: jax.Array, held: jax.Array) -> jax.Array:
+    """The grouped products' rows [n, d] times their weights [n], zero
+    where ``held`` [n] is not set. A select, not a product by 0: a row
+    in no group holds whatever the grouped product left there."""
+    return jnp.where(held[:, None], out * w[:, None], 0)
+
+
+def _weigh_held_bwd(res, ct):
+    out, w, held = res
+    ct = jnp.where(held[:, None], ct, 0)
+    d_w = jnp.sum(ct.astype(jnp.float32) * jnp.where(held[:, None], out, 0), axis=-1)
+    return ct * w[:, None], d_w.astype(w.dtype), None
+
+
+# ``_weigh`` for a share: its backward pass (``_held_rows_gradient`` says
+# why) keeps what a row in no group holds out of the weights' gradient
+# too, where a zero gradient times such a row would be NaN
+_weigh_held = jax.custom_vjp(_weigh)
+_weigh_held.defvjp(lambda out, w, held: (_weigh(out, w, held), (out, w, held)),
+                   _weigh_held_bwd)
 
 
 def _ragged_mlp(
@@ -232,9 +309,9 @@ def _ragged_mlp(
     trailing rows); a ``lax.cond`` on ``sum(group_sizes)`` takes all
     k*T rows otherwise, so no pair is ever dropped. Where
     ``short_rows`` is None (all experts held) there is one body over
-    k*T rows and no conditional. Under a ``vmap`` (DiLoCo's worker
-    axis) the conditional becomes a select and both branches run:
-    exact still, and as slow as before.
+    k*T rows and no conditional. Under a ``vmap`` the conditional
+    would become a select and both branches run, so DiLoCo runs such a
+    configuration's workers unbatched (``Diloco._over_workers``).
 
     Padding tokens (valid_t = 0) are treated as routed elsewhere: no
     group, no output. Numerics vs dense dispatch at non-binding
@@ -262,6 +339,8 @@ def _ragged_mlp(
 
     def experts(rows, w_sorted, held_sorted):
         xg = x[rows]                                         # [n, d] gather
+        if count != cfg.num_experts:
+            xg = _held_rows_gradient(xg, held_sorted)
         gate = jax.nn.silu(
             jax.lax.ragged_dot(xg, layer["w_gate"].astype(cdt), group_sizes)
         )
@@ -269,9 +348,8 @@ def _ragged_mlp(
         out = jax.lax.ragged_dot(
             gate * up, layer["w_down"].astype(cdt), group_sizes
         )                                                    # [n, d]
-        # a select, not a product by 0: a row in no group holds whatever
-        # the grouped product left there
-        out = jnp.where(held_sorted[:, None], out * w_sorted.astype(cdt)[:, None], 0)
+        out = (_weigh_held if count != cfg.num_experts else _weigh)(
+            out, w_sorted.astype(cdt), held_sorted)
         return jnp.zeros((t, d), cdt).at[rows].add(out)
 
     cap = short_rows(cfg, t * k)
@@ -290,7 +368,8 @@ def _ragged_mlp(
 
 def route(cfg: LlamaConfig, x: jax.Array, layer: dict):
     """The gate of a mixed configuration's sparse layer. x [T, d] ->
-    (weights [T, k] float32, experts [T, k] int32). Scores are softmax
+    (weights [T, k] float32, experts [T, k] int32, scores [T, E]
+    float32). Scores are softmax
     or sigmoid of the router's float32
     logits; the k experts are those with the largest score plus the
     layer's selection bias (``router_bias``, where the layer has one:
@@ -307,11 +386,29 @@ def route(cfg: LlamaConfig, x: jax.Array, layer: dict):
     w = jnp.take_along_axis(scores, topk_e, axis=-1)
     if cfg.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return w * cfg.routed_scaling_factor, topk_e
+    return w * cfg.routed_scaling_factor, topk_e, scores
+
+
+@jax.named_scope("moe_aux")
+def balance_term(cfg: LlamaConfig, scores: jax.Array, topk_e: jax.Array,
+                 valid_t: jax.Array | None) -> jax.Array:
+    """The Switch load-balance term of one sparse layer, float32:
+    E sum_e f_e P_e over ALL the router's experts, held here or not, with
+    f_e the share of the real tokens' k*T pairs that chose e (no
+    gradient) and P_e the mean of a real token's score for e."""
+    e = cfg.num_experts
+    v = jnp.ones(scores.shape[:1], jnp.float32) if valid_t is None \
+        else (valid_t > 0).astype(jnp.float32)
+    n = jnp.maximum(jnp.sum(v), 1.0)
+    chose = jnp.zeros((e,), jnp.float32).at[topk_e.reshape(-1)].add(
+        jnp.repeat(v, topk_e.shape[1]))
+    f = chose / (n * topk_e.shape[1])
+    p = jnp.sum(scores * v[:, None], axis=0) / n
+    return e * jnp.sum(f * p)
 
 
 def sparse_mlp(cfg: LlamaConfig, h: jax.Array, layer: dict,
-               valid: jax.Array | None = None):
+               valid: jax.Array | None = None, with_stats: bool = False):
     """The sparse feed-forward of a mixed configuration: ``route``, the
     held experts' grouped products (``_ragged_mlp``: no capacity, no
     dropped token) and the shared experts (one SwiGLU of width
@@ -321,12 +418,14 @@ def sparse_mlp(cfg: LlamaConfig, h: jax.Array, layer: dict,
     experts [B, S, k] int32); the counters (``COUNTERS`` names them):
     token-expert pairs routed to held experts, held experts at least one
     token chose, all pairs (k a real token), and 1 where the grouped
-    products took the short path (``_ragged_mlp``)."""
+    products took the short path (``_ragged_mlp``). ``with_stats`` (the
+    training forward asks, the serving programs do not) puts the float32
+    vector ``ROUTER_STATS`` in the choice's place."""
     b, s, d = h.shape
     x = h.reshape(b * s, d)
     valid_t = None if valid is None else valid.reshape(b * s)
     with jax.named_scope("moe_route"):
-        w, topk_e = route(cfg, x, layer)
+        w, topk_e, scores = route(cfg, x, layer)
     y, group_sizes, short = _ragged_mlp(cfg, x, w, topk_e, layer, valid_t)
     if "shared_gate" in layer:
         with jax.named_scope("moe_shared"):
@@ -337,6 +436,11 @@ def sparse_mlp(cfg: LlamaConfig, h: jax.Array, layer: dict,
     n_tok = jnp.int32(b * s) if valid_t is None else jnp.sum(valid_t > 0).astype(jnp.int32)
     counters = jnp.stack([jnp.sum(group_sizes), jnp.sum(group_sizes > 0).astype(jnp.int32),
                           n_tok * cfg.num_experts_per_tok, short])
+    if with_stats:  # what a pass does not read of them the compiler drops
+        return y.reshape(b, s, d), counters, jnp.stack([
+            balance_term(cfg, scores, topk_e, valid_t),
+            jnp.max(group_sizes).astype(jnp.float32),
+            _router_entropy(scores / jnp.sum(scores, axis=-1, keepdims=True), valid_t, None)])
     return y.reshape(b, s, d), counters, topk_e.reshape(b, s, -1)
 
 

@@ -382,6 +382,20 @@ class Diloco:
                     "live program inputs every round — there is no "
                     "between-syncs window to park them in host memory"
                 )
+        # a mixed sparse configuration's expert layer reports what it did
+        # (models/moe.py TRAIN_COUNTERS, the balance term): the inner step
+        # and the round hand it out beside their losses
+        self.moe_stats = bool(
+            loss_fn is None and model_cfg.mixed and model_cfg.num_experts
+            and self.sp == 1 and self.pp == 1)  # those paths refuse a mixed stack
+        self._stats_keys = ("router_aux", "moe_counters") if self.moe_stats else ()
+        # a held share of the experts picks one of two bodies for its
+        # grouped products by a scalar (moe._ragged_mlp's lax.cond); under
+        # a vmap over the worker axis the scalar is batched, the cond a
+        # select, and both bodies run. Such a configuration's workers run
+        # unbatched instead (_over_workers)
+        self._unbatched_workers = bool(
+            loss_fn is None and model_cfg.mixed and model_cfg.experts_held is not None)
         self.loss_fn = loss_fn or (
             lambda p, t, m: causal_lm_loss(p, t, model_cfg, loss_mask=m)
         )
@@ -441,9 +455,16 @@ class Diloco:
         # dispatch side effects, so the probe never touches state)
         self._inner_jit = jax.jit(self._inner_step, donate_argnums=(0,))
         _inner_call = self._with_mesh(self._inner_jit)
-        self.inner_step = lambda state, tokens, mask: _inner_call(
+        # the step's and the round's last output is a dict of what they
+        # measured of themselves ({"router_aux", "moe_counters"} from a
+        # mixed sparse configuration, the dynamics readout); the public
+        # entries drop it where it is empty
+        def _sans_empty(out):
+            return out[:-1] if isinstance(out[-1], dict) and not out[-1] else out
+
+        self.inner_step = lambda state, tokens, mask: _sans_empty(_inner_call(
             self._fetch(state), tokens, mask, *self._hb()
-        )
+        ))
         _outer_jit = self._with_mesh(
             jax.jit(self._outer_step_state, donate_argnums=(0,))
         )
@@ -461,8 +482,8 @@ class Diloco:
 
         def _round_step_entry(state, tokens, mask):
             with trace_span("diloco.round"):
-                return _round_call(
-                    self._fetch(state), tokens, mask, *self._hb())
+                return _sans_empty(_round_call(
+                    self._fetch(state), tokens, mask, *self._hb()))
 
         self.round_step = _round_step_entry
         # H inner steps with NO outer sync: same dispatch count as
@@ -751,10 +772,11 @@ class Diloco:
                     else jnp.ones((), jnp.float32)
                 )
                 g_acc = jax.tree.map(lambda a, b: a + w * b, g_acc, g)
-                return (g_acc, loss_acc + loss, n_acc + w), None
+                stats = {k: aux[k] for k in self._stats_keys}
+                return (g_acc, loss_acc + loss, n_acc + w), stats
 
             zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
-            (g_sum, loss_sum, n_sum), _ = jax.lax.scan(
+            (g_sum, loss_sum, n_sum), stats = jax.lax.scan(
                 micro,
                 (zeros, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
                 (w_tokens, w_mask),
@@ -762,21 +784,19 @@ class Diloco:
             accum = w_tokens.shape[0]
             params, opt_state = self._inner_update(
                 g_sum, n_sum, opt_state, params)
-            return params, opt_state, loss_sum / accum
+            if stats:  # the step's microbatches: mean term, summed counts
+                stats = {"router_aux": jnp.mean(stats["router_aux"]),
+                         "moe_counters": jnp.sum(stats["moe_counters"], axis=0)}
+            return params, opt_state, loss_sum / accum, stats
 
+        stats = {}
         if self.pp > 1:  # handles sp>1 too (sequence-sharded pipeline)
             params, inner_opt_state, loss = self._pp_inner_update(state, tokens, loss_mask)
         elif self.sp > 1:
             params, inner_opt_state, loss = self._sp_inner_update(state, tokens, loss_mask)
         else:
-            # naming the mesh axis tells a shard_map inside the loss (the
-            # flash kernel's, ops/flash_attention.py) that the worker
-            # dimension is sharded over ``diloco``; without it the region
-            # would gather every worker's activations onto every device
-            on_diloco = self.mesh.shape["diloco"] > 1
-            params, inner_opt_state, loss = jax.vmap(
-                worker_update, spmd_axis_name="diloco" if on_diloco else None
-            )(state.params, state.inner_opt_state, tokens, loss_mask)
+            params, inner_opt_state, loss, stats = self._over_workers(
+                worker_update, state.params, state.inner_opt_state, tokens, loss_mask)
         if h_budget is not None:
             pos = jnp.mod(state.inner_step_count, self.cfg.inner_steps)
             active = pos < h_budget  # [W]
@@ -795,7 +815,39 @@ class Diloco:
             inner_opt_state=inner_opt_state,
             inner_step_count=state.inner_step_count + 1,
         )
-        return state, loss  # loss: [W] per-worker mean microbatch loss
+        # loss: [W] per-worker mean microbatch loss; stats: {}, or from a
+        # mixed sparse configuration {"router_aux": [W], "moe_counters": [W, 5]}
+        return state, loss, stats
+
+    def _over_workers(self, fn, *args):
+        """``fn`` (one worker's update) over the leading worker axis of
+        ``args``: a ``vmap``, XLA partitioning it over ``diloco``. A
+        configuration that holds a share of its experts runs its workers
+        unbatched instead, so that the scalar that picks the grouped
+        products' body stays one (``__init__``): a region manual over
+        ``diloco`` where the axis spans devices, and within a device its
+        workers one after the other."""
+        on_diloco = self.mesh.shape["diloco"] > 1
+        if not self._unbatched_workers:
+            # naming the mesh axis tells a shard_map inside the loss (the
+            # flash kernel's, ops/flash_attention.py) that the worker
+            # dimension is sharded over ``diloco``; without it the region
+            # would gather every worker's activations onto every device
+            return jax.vmap(fn, spmd_axis_name="diloco" if on_diloco else None)(*args)
+
+        def local(*args):
+            if jax.tree.leaves(args)[0].shape[0] == 1:
+                out = fn(*jax.tree.map(lambda x: x[0], args))
+                return jax.tree.map(lambda x: x[None], out)
+            return jax.lax.map(lambda one: fn(*one), args)
+
+        if not on_diloco:
+            return local(*args)
+        return jax.shard_map(
+            local, mesh=self.mesh, in_specs=P("diloco"), out_specs=P("diloco"),
+            axis_names={"diloco"},
+            check_vma=False,  # the model's scans start from constants, which vary over no axis
+        )(*args)
 
     @jax.named_scope("inner_opt")
     def _inner_update(self, g_sum, n_sum, opt_state, params):
@@ -1635,8 +1687,14 @@ class Diloco:
         tokens/loss_mask: [H, W, accum, B, S]. Returns (state, [H, W]
         losses, [W] effective sync mask — the workers whose replicas
         entered the outer mean; all ones when quarantine is off), plus
-        a 4th element — the ``_sync_dynamics`` dict — when
-        ``dynamics_metrics`` is on.
+        a 4th element, ONE dict of what the round measured of itself,
+        where the configuration has any such thing (the public entry
+        drops an empty one): the ``_sync_dynamics`` keys when
+        ``dynamics_metrics`` is on, and from a mixed sparse
+        configuration what its expert layers did at each inner step,
+        ``"router_aux"`` [H, W] float32 and ``"moe_counters"`` [H, W, 5]
+        int32 (the balance term and ``moe.TRAIN_COUNTERS``, each summed
+        over the step's layers).
 
         One program per round is the TPU-native shape of the training
         loop: no host round-trips between steps, no executable switching
@@ -1649,10 +1707,10 @@ class Diloco:
             )
 
         def one(s, batch):
-            s, loss = self._inner_step(s, batch[0], batch[1], h_budget)
-            return s, loss
+            s, loss, stats = self._inner_step(s, batch[0], batch[1], h_budget)
+            return s, (loss, stats)
 
-        state, losses = jax.lax.scan(one, state, (tokens, loss_mask))
+        state, (losses, stats) = jax.lax.scan(one, state, (tokens, loss_mask))
         wmask = None
         if self.cfg.quarantine_nonfinite:
             # [H, W] -> [W]: a non-finite inner loss is an EXTRA reason
@@ -1661,9 +1719,7 @@ class Diloco:
             # final update) is applied inside _outer_step
             wmask = jnp.all(jnp.isfinite(losses), axis=0)
         state, eff, dyn = self._outer_step(state, wmask, h_budget)
-        if self.cfg.dynamics_metrics:
-            return state, losses, eff, dyn
-        return state, losses, eff
+        return state, losses, eff, {**(dyn or {}), **stats}
 
     def _inner_round_step(
         self, state: DilocoState, tokens, loss_mask,
@@ -1677,7 +1733,7 @@ class Diloco:
         (tiny) cost is honestly billed to the sync by the differencing."""
 
         def one(s, batch):
-            s, loss = self._inner_step(s, batch[0], batch[1], h_budget)
+            s, loss, _ = self._inner_step(s, batch[0], batch[1], h_budget)
             return s, loss
 
         state, losses = jax.lax.scan(one, state, (tokens, loss_mask))
@@ -1827,7 +1883,7 @@ class Diloco:
         )
 
         def one(s, batch):
-            s, loss = self._inner_step(s, batch[0], batch[1], h_budget)
+            s, loss, _ = self._inner_step(s, batch[0], batch[1], h_budget)
             return s, loss
 
         state, losses = jax.lax.scan(one, state, (tokens, loss_mask))

@@ -289,7 +289,7 @@ class StreamingDiloco(Diloco):
         if self.scfg.delay > 0:
             for p in apply_:
                 state = self._apply_fragment(state, p)
-        new_base, loss = super()._inner_step(
+        new_base, loss, _ = super()._inner_step(
             state_as_diloco(state), tokens, loss_mask
         )
         state = state.replace(
@@ -333,7 +333,7 @@ class StreamingDiloco(Diloco):
                         lambda s: s,
                         s,
                     )
-            base, loss = self._inner_step(state_as_diloco(s), tok, m)
+            base, loss, _ = self._inner_step(state_as_diloco(s), tok, m)
             s = s.replace(
                 params=base.params,
                 inner_opt_state=base.inner_opt_state,

@@ -227,6 +227,12 @@ class InferenceEngine:
                 "expert-choice routing is training-only (see generate()); "
                 "use router_type='tokens_choose' for serving"
             )
+        if cfg.rope_parameters is not None:
+            raise ValueError(
+                "the serving programs build one rotary table and this "
+                "configuration has one a layer kind (rope_parameters): "
+                "served so, its full layers would lose their YaRN"
+            )
         if kv_dtype not in (None, "model", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'model' or 'int8'; got {kv_dtype!r}"

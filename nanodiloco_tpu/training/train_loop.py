@@ -356,6 +356,21 @@ def _finite_worker_mean(losses: jax.Array) -> jax.Array:
     return jnp.where(fin.any(-1), mean, jnp.nan)
 
 
+def _moe_records(stats) -> list[dict]:
+    """What a mixed sparse configuration's expert layers did, one dict
+    an inner step, for the steps' JSONL records: the balance term (the
+    workers' mean) and ``moe.TRAIN_COUNTERS`` summed over layers and
+    workers. ``stats``: ``round_step``'s ``{"router_aux": [H, W],
+    "moe_counters": [H, W, 5]}``. Reduced on the device first: the
+    worker axis may span other processes."""
+    from nanodiloco_tpu.models.moe import TRAIN_COUNTERS
+
+    aux = np.asarray(jnp.mean(stats["router_aux"], axis=1))
+    counts = np.asarray(jnp.sum(stats["moe_counters"], axis=1))
+    return [{"router_aux": float(a), **dict(zip(TRAIN_COUNTERS, map(int, row)))}
+            for a, row in zip(aux, counts)]
+
+
 def train(cfg: TrainConfig) -> dict[str, Any]:
     """Run the full DiLoCo training job; returns a summary dict."""
     set_seed_all(cfg.seed)
@@ -1487,11 +1502,14 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                                 eff_mask = jnp.ones(
                                     (cfg.num_workers,), bool
                                 )
-                                round_dyn = None
+                                round_dyn = round_moe = None
                             else:
                                 out = dl.round_step(state, toks, masks)
                                 state, losses, eff_mask = out[0], out[1], out[2]
-                                round_dyn = out[3] if dynamics_on else None
+                                # the round's own measurements, one dict
+                                measured = out[3] if len(out) > 3 else {}
+                                round_dyn = measured if dynamics_on else None
+                                round_moe = _moe_records(measured) if dl.moe_stats else None
                             jax.block_until_ready(losses)
                             # straggler fault hook, ON the round's clock
                             # (once per round): the sleep lands in this
@@ -1706,6 +1724,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                                     "total_samples": step * cfg.batch_size * cfg.num_workers,
                                     "tokens_per_sec": tps,
                                     "outer_synced": int(i == cfg.inner_steps - 1),
+                                    **(round_moe[i] if round_moe is not None else {}),
                                     **(
                                         quarantine_metrics
                                         if i == cfg.inner_steps - 1 else {}
@@ -1777,6 +1796,7 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
             # the start_step%H guard alone could not see)
             state, pending_baux = dl.async_boundary(state)
         round_t0 = time.perf_counter()  # sync-to-sync wall-clock (watchdog)
+        step_moe: list = []  # the stepwise inner step's, from a mixed sparse model
         for real_step in _round_annotated(
                 [] if fused else range(start_step + 1, cfg.total_steps + 1),
                 lambda step: (step - 1) // cfg.inner_steps):
@@ -1833,7 +1853,8 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         _guarded_save(real_step, state)
             else:
                 with trace_span("inner", layer="train"):
-                    state, loss = dl.inner_step(state, dl.feed(tokens), dl.feed(mask))
+                    state, loss, *step_moe = dl.inner_step(  # stats where it has any
+                        state, dl.feed(tokens), dl.feed(mask))
                     if cfg.quarantine_nonfinite:
                         # accumulate ON DEVICE ([W] stays diloco-sharded; a
                         # host fetch of the raw loss would fail on a pod) —
@@ -2066,6 +2087,8 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         "total_samples": real_step * cfg.batch_size * cfg.num_workers,
                         "tokens_per_sec": tps,
                         "outer_synced": int(synced),
+                        **(_moe_records(jax.tree.map(lambda x: x[None], step_moe[0]))[0]
+                           if step_moe else {}),
                         "avg_sync_time_s": sync_timer.avg_sync_time,
                         "comm_share": sync_timer.total / total_time if total_time else 0.0,
                         **round_budget,

@@ -212,8 +212,12 @@ def test_the_trainer_steps_a_mixed_configuration(params):
                            (2, 1, 2, 32))
     losses = []
     for _ in range(4):
-        state, loss = dl.inner_step(state, tok, jnp.ones_like(tok))
+        # a mixed sparse configuration's step hands out what its expert
+        # layers did, a row a worker (a sigmoid gate has no balance term)
+        state, loss, stats = dl.inner_step(state, tok, jnp.ones_like(tok))
         losses.append(float(loss[0]))
+        assert stats["moe_counters"].shape == (2, 5) and float(stats["router_aux"][0]) == 0.0
+        assert int(stats["moe_counters"][0, 2]) == 4 * 64 * 4  # sparse layers x tokens x k
     state = dl.outer_step(state)
     assert losses[-1] < losses[0] and np.isfinite(losses).all()
 
@@ -276,6 +280,14 @@ def _lower_toy(cfg, program):
                     build_mesh(MeshConfig(diloco=1), devices=jax.devices()[:1]))
         state = jax.eval_shape(lambda: dl.init_state(jax.random.key(0)))
         return dl._round_jit.lower(state, _S((2, 1, 1, 2, 32), i32), _S((2, 1, 1, 2, 32), i32))
+    if program == "fused_round_4dev":  # the four-chip cell's layout: a worker a device
+        dl = Diloco(cfg, DilocoConfig(num_workers=4, inner_steps=2, lr=1e-3, warmup_steps=1,
+                                      total_steps=8),
+                    build_mesh(MeshConfig(diloco=4), devices=jax.devices()[:4]))
+        state = dl.init_state(jax.random.key(0))
+        with jax.set_mesh(dl.mesh):
+            return dl._round_jit.lower(
+                state, _S((2, 4, 1, 2, 32), i32), _S((2, 4, 1, 2, 32), i32))
     pool = jax.eval_shape(lambda: init_kv_pool(cfg, 16, 4))
     if program == "paged_chunk":
         return prefill_chunk_paged_fn(cfg).lower(
@@ -324,6 +336,8 @@ LOWERED = {
     ("dense", "paged_chunk"): "22fd6380ece8aa8e",
     ("dense", "paged_tick"): "36ba83875010c97a",
     ("dense", "fused_round"): "2236461a9273367d",
+    # four workers, one a device, taken on the commit before PR 32 (55034a2)
+    ("dense", "fused_round_4dev"): "7b995d4f44e9668b",
     ("ragged", "forward"): "a67ab44a2c55b2b3",
     ("ragged", "loss_gradient"): "1dfd52542ec6df87",
     ("ragged", "paged_chunk"): "4c6051755649e1bf",
@@ -343,7 +357,8 @@ def test_a_dense_configuration_lowers_to_the_program_it_had(toy, program):
     assert not cfg.mixed
     text = _lower_toy(cfg, program).as_text()
     assert hashlib.sha256(text.encode()).hexdigest().startswith(LOWERED[toy, program])
-    if program in ("forward", "loss_gradient", "fused_round"):  # sampling has a case of its own
+    if program in ("forward", "loss_gradient", "fused_round", "fused_round_4dev"):
+        # sampling has a case of its own
         assert "stablehlo.case" not in text and "stablehlo.if" not in text
 
 

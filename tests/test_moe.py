@@ -709,3 +709,56 @@ def test_short_path_under_a_vmap_stays_exact():
             y, _, flag = jax.jit(one)(c[0], c[1], c[2], c[4])
             np.testing.assert_array_equal(ys[i], y)
             assert int(flags[i]) == int(flag) == (i == 0)
+
+
+# a share whose held expert expects two tiles of 512 rows: 4,096 tokens
+# top-2 of 8 experts, one held (8,192 pairs, 1,024 expected at it)
+WIDE = LlamaConfig(
+    vocab_size=96, hidden_size=16, intermediate_size=32, num_attention_heads=2,
+    num_hidden_layers=2, first_k_dense_replace=1, moe_intermediate_size=8,
+    num_experts=8, num_experts_per_tok=2, moe_dispatch="ragged", experts_held=(3, 1),
+)
+
+
+def test_wide_groups_take_whole_tiles_at_three_times_the_expected():
+    assert moe.short_rows(WIDE, 8192) == 3072
+    # the training cell's step: 16,384 tokens top-8 of 64, 8 held
+    cell = LlamaConfig(**{**WIDE.to_dict(), "num_experts": 64, "num_experts_per_tok": 8,
+                          "experts_held": (0, 8)})
+    assert moe.short_rows(cell, 8 * 16384) == 49152
+    # narrow groups keep their odd count of 128s
+    exaone = LlamaConfig(**{**HELD.to_dict(), "num_experts": 128,
+                            "num_experts_per_tok": 8, "experts_held": (0, 16)})
+    assert moe.short_rows(exaone, 8 * 512) == 1152 and moe.short_rows(HELD, _T * _K) == 128
+
+
+@pytest.mark.parametrize("n_held,short", [(1500, 1), (3072, 1), (3073, 0), (5000, 0)])
+def test_the_wide_short_path_equals_the_full_rows(n_held, short, monkeypatch):
+    """Under the wide rows, at them, one pair over and far over: the
+    output and both gradients are those of the one body over all rows."""
+    t, k = 4096, 2
+    ks = jax.random.split(jax.random.key(n_held), 5)
+    x = jax.random.normal(ks[0], (t, 16))
+    topk_p = jax.random.uniform(ks[1], (t, k), minval=0.1)
+    topk_e = np.tile(np.array([0, 6]), (t, 1))
+    topk_e[:min(n_held, t), 0] = 3
+    topk_e[:max(n_held - t, 0), 1] = 3  # (a second pick of the same expert: a pair as any)
+    topk_e = jnp.asarray(topk_e, jnp.int32)
+    layer = {"w_gate": 0.3 * jax.random.normal(ks[2], (1, 16, 8)),
+             "w_up": 0.3 * jax.random.normal(ks[3], (1, 16, 8)),
+             "w_down": 0.3 * jax.random.normal(ks[4], (1, 8, 16))}
+
+    def run(x, layer):
+        y, sizes, took = _ragged_mlp(WIDE, x, topk_p, topk_e, layer, None)
+        return jnp.sum(y * y), (y, sizes, took)
+
+    grad = jax.jit(jax.value_and_grad(run, argnums=(0, 1), has_aux=True))
+    (_, (y, sizes, took)), (dx, dw) = grad(x, layer)
+    assert int(sizes[0]) == n_held and int(took) == short
+    _full_rows(monkeypatch)
+    (_, (y_full, _, _)), (dx_full, dw_full) = jax.jit(
+        jax.value_and_grad(run, argnums=(0, 1), has_aux=True))(x, layer)
+    np.testing.assert_allclose(y, y_full, atol=1e-5)
+    np.testing.assert_allclose(dx, dx_full, atol=1e-4)
+    for name in layer:
+        np.testing.assert_allclose(dw[name], dw_full[name], rtol=1e-4, atol=1e-4)
