@@ -94,9 +94,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="compute dtype override (e.g. bfloat16)")
     p.add_argument("--attention", type=str, default=None,
                    choices=["dense", "flash", "ring"],
-                   help="dense honors attention padding masks; flash/ring "
-                        "are packed-sequence kernels that ignore them "
-                        "(fine for packed data and tail-only padding)")
+                   help="dense (the default) honors attention padding masks "
+                        "and picks its implementation from the shapes: a "
+                        "fused kernel on a one-device TPU program with heads "
+                        "of 128 and a sequence in tiles of 1024, query "
+                        "blocks elsewhere; flash/ring are packed-sequence "
+                        "kernels that ignore the masks (fine for packed "
+                        "data and tail-only padding)")
     p.add_argument("--loss-chunk", type=int, default=None,
                    help="rows per chunk of the blockwise cross-entropy "
                         "(avoids materializing [B,S,vocab] logits; 512 is "

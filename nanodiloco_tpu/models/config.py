@@ -247,7 +247,11 @@ class LlamaConfig:
             raise ValueError(
                 f"attention_impl={self.attention_impl!r} does not carry a mixed layer "
                 "stack (sliding-window layers, per-head q/k norms, layers without "
-                "RoPE): the flash and ring kernels know one causal mask; use 'dense'")
+                "RoPE): the repo's flash and ring kernels know one causal mask and no "
+                "padding mask; use 'dense', the default, under which the program picks "
+                "a layer's implementation itself (a fused kernel with the layer's "
+                "window on a TPU where the shapes allow, query blocks elsewhere: "
+                "models/llama.py:fused_attention_applies)")
         if not self.num_experts:
             if (self.num_shared_experts or self.experts_held is not None
                     or self.first_k_dense_replace):

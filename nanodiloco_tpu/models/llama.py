@@ -411,7 +411,11 @@ def dense_attention(
     """Reference attention: q,k,v [B, S, H, hd] (k/v already GQA-expanded),
     softmax in float32. ``mask`` is None (causal), a [B, S] 0/1 validity
     mask (causal over the valid keys) or an explicit additive [B?, 1, S, S]
-    mask, taken as given.
+    mask, taken as given. Every block's scores are an array in HBM: the
+    path of every shape ``fused_attention_applies`` does not send to the
+    kernel (heads not of 128, short or ragged sequences, an explicit
+    mask, a partitioned mesh, any backend but a TPU), and the statement
+    of the mathematics the kernel is tested against.
 
     Causal attention runs in query blocks of ``dense_block_rows(S, bq)``
     rows (``bq`` is for tests; the program's size follows from S): block
@@ -459,15 +463,70 @@ def dense_attention(
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
+def fused_attention_applies(
+    platform: str, s: int, head_dim: int, mask_ndim: int | None,
+    sp_axis: bool, partitioned: bool,
+) -> bool:
+    """Whether ``_attention`` hands a layer of the default
+    (``attention_impl="dense"``) implementation to the fused kernel and
+    not to ``dense_attention``'s blocks: one algorithm whose best
+    implementation depends on what the call can observe. The kernel
+    where the backend is a TPU, the heads are whole lanes of 128, the
+    mask is none (``mask_ndim`` None) or a [B, S] validity array (an
+    explicit [B?, 1, S, S] mask is taken as given, which only dense
+    blocks can), no sequence-parallel axis is bound, the ambient mesh is
+    one device or wholly manual (``partitioned`` false: Mosaic refuses a
+    kernel inside an automatically partitioned program), and the
+    sequence is whole tiles of 1,024: one tile is the least length the
+    chip sweep measured, and the kernel was 2.1 to 4.6 times ahead of
+    the blocks at every length up to 8,192 (PERF.md section 6, PR 33)."""
+    if (platform != "tpu" or head_dim % 128 or mask_ndim not in (None, 2)
+            or sp_axis or partitioned):
+        return False
+    # the kernels' module loads Pallas: only where a kernel can run
+    from nanodiloco_tpu.ops.splash_attention import whole_tiles
+
+    return whole_tiles(s)
+
+
+def mesh_partitions() -> bool:
+    """Whether the ambient mesh (``jax.set_mesh``) spans devices over an
+    axis no enclosing ``shard_map`` has made manual: a program the
+    compiler partitions itself."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return not (mesh.empty or mesh.size == 1
+                or not set(mesh.axis_names) - set(mesh.manual_axes))
+
+
+def attention_paths(cfg: LlamaConfig, s: int, *, sp_axis: bool = False,
+                    partitioned: bool = False) -> dict[str, int]:
+    """How many of the stack's layers take the fused kernel and how many
+    ``dense_attention``'s blocks at sequence length ``s``, by the rule
+    ``_attention`` applies when the program is traced (a [B, S] validity
+    array or no mask: the rule does not tell them apart). Neither for
+    "flash" and "ring", which name their own kernels."""
+    n = cfg.num_hidden_layers if cfg.attention_impl == "dense" else 0
+    fused = fused_attention_applies(
+        jax.default_backend(), s, cfg.head_dim, None, sp_axis, partitioned)
+    return {"fused": n if fused else 0, "dense": 0 if fused else n}
+
+
 @jax.named_scope("attention")
 def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None,
                window: int | None = None):
     """Dispatch on cfg.attention_impl. Ring attention requires being inside
     a shard_map with the sequence axis bound to ``axis_name``; flash and
     ring ignore ``valid``, the [B, S] padding mask (packed fixed-length
-    sequences don't need one), which dense attention honors. flash and
-    ring take k/v at Hkv heads (GQA un-expanded); dense gets them
-    pre-expanded here."""
+    sequences don't need one) and know one causal mask. ``"dense"``, the
+    default, names the mathematics (causal over the valid keys, a window
+    where the layer has one, every row's softmax whole in float32) and
+    the program picks its implementation from the call
+    (``fused_attention_applies``): on a TPU, heads of 128 over a long
+    sequence in whole tiles run the fused kernel forward and backward
+    (ops/splash_attention.py: no score reaches HBM, K and V stay at Hkv
+    heads); every other shape, platform and mesh runs ``dense_attention``'s
+    query blocks, for which K and V are expanded to the query heads
+    here. flash and ring take k/v at Hkv heads too."""
     if cfg.attention_impl not in ("dense", "flash", "ring"):
         raise ValueError(f"unknown attention_impl: {cfg.attention_impl!r}")
     if cfg.attention_impl == "flash":
@@ -478,8 +537,15 @@ def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None,
         from nanodiloco_tpu.ops.ring_attention import ring_attention
 
         return ring_attention(q, k, v, axis_name=axis_name)
-    # dense (and the ring-without-axis fallback, e.g. sp=1): expand GQA
-    # K/V to the query heads — dense scores are computed per query head
+    if cfg.attention_impl == "dense" and fused_attention_applies(
+            jax.default_backend(), q.shape[1], q.shape[3],
+            None if valid is None else valid.ndim, axis_name is not None,
+            mesh_partitions()):
+        from nanodiloco_tpu.ops.splash_attention import splash_attention
+
+        return splash_attention(q, k, v, valid, window=window)
+    # dense blocks (and the ring-without-axis fallback, e.g. sp=1): expand
+    # GQA K/V to the query heads — dense scores are computed per query head
     if k.shape[2] != q.shape[2]:
         g = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, g, axis=2)

@@ -178,6 +178,7 @@ def build_cost_record(
     model_cfg=None,
     seq: int | None = None,
     moe_tokens: int | None = None,
+    attention_paths: dict[str, int] | None = None,
 ) -> dict[str, Any]:
     """The one-time ``cost_analysis`` JSONL record: the raw XLA numbers
     plus everything a later ``report cost`` needs without re-deriving
@@ -189,11 +190,16 @@ def build_cost_record(
     ``billed`` is the dispatched executable's own analysis (loop bodies
     counted once — module docstring); ``probe`` is the unrolled
     one-microbatch fwd+bwd over ``probe_tokens`` tokens, the basis for
-    ``flops_per_token`` and therefore analytic MFU."""
+    ``flops_per_token`` and therefore analytic MFU. ``attention_paths``
+    (``Diloco.attention_paths``) is the count of the model's layers whose
+    attention the program runs through the fused kernel and through dense
+    blocks: the FLOPs are the same, the seconds are not."""
     rec: dict[str, Any] = {
         "program": program,
         "num_devices": int(num_devices),
     }
+    if attention_paths is not None:
+        rec["attention_paths"] = dict(attention_paths)
     if billed:
         if "flops" in billed:
             rec["flops_billed"] = billed["flops"]

@@ -1296,6 +1296,19 @@ class Diloco:
             return q.astype(jnp.float32) * scale
         return d.astype(dt).astype(jnp.float32)
 
+    def attention_paths(self, seq_len: int) -> dict[str, int]:
+        """``{"fused": n, "dense": m}``: how many of the model's layers run
+        their attention through the fused kernel and how many through
+        dense blocks in this object's programs at rows of ``seq_len``
+        (``models/llama.py:attention_paths``; decided when a program is
+        traced, from the platform, the shapes and this mesh). A mesh of
+        more than one device is partitioned by the compiler, which a
+        Mosaic kernel does not survive: dense there."""
+        from nanodiloco_tpu.models.llama import attention_paths
+
+        return attention_paths(
+            self.model_cfg, seq_len, sp_axis=self.sp > 1, partitioned=self.mesh.size > 1)
+
     def sync_payload_report(self) -> dict:
         """What one outer sync actually moves per worker, by wire mode —
         the byte-accounting companion to the measured sync wall-clock

@@ -1367,6 +1367,10 @@ def train(cfg: TrainConfig) -> dict[str, Any]:
                         model_cfg=model_cfg,
                         seq=row_len,
                         moe_tokens=cfg.per_device_batch_size * row_len,
+                        # which implementation the program's attention
+                        # takes at this row length: the log says which
+                        # path the run measured
+                        attention_paths=dl.attention_paths(row_len),
                     )
                 },
                 step=start_step,

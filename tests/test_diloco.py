@@ -720,6 +720,33 @@ def test_int4_wire_trains():
     assert last < first - 0.3, f"int4 wire failed to train: {first} -> {last}"
 
 
+@pytest.mark.parametrize("platform,workers,want", [
+    ("tpu", 1, {"fused": 2, "dense": 0}),   # one device: the kernel
+    ("tpu", 4, {"fused": 0, "dense": 2}),   # a mesh the compiler partitions
+    ("cpu", 1, {"fused": 0, "dense": 2}),
+])
+def test_attention_paths_describe_the_round(monkeypatch, platform, workers, want):
+    """``Diloco.attention_paths``: the count of layers whose attention
+    takes the fused kernel and dense blocks in this object's programs,
+    from the platform, the model's head size, the row length and the
+    mesh (heads of 128 here; TINY's heads are narrower and stay dense)."""
+    import dataclasses
+
+    from nanodiloco_tpu.ops.splash_attention import TILE
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    wide = dataclasses.replace(
+        TINY, hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
+        num_hidden_layers=2)
+    mesh = build_mesh(MeshConfig(diloco=workers), devices=jax.devices()[:workers])
+    dl = Diloco(wide, DilocoConfig(num_workers=workers), mesh)
+    assert dl.attention_paths(2 * TILE) == want
+    assert dl.attention_paths(TILE // 2)["fused"] == 0
+    narrow = Diloco(dataclasses.replace(wide, num_attention_heads=4), DilocoConfig(
+        num_workers=workers), mesh)
+    assert narrow.attention_paths(2 * TILE) == {"fused": 0, "dense": 2}
+
+
 def test_sync_payload_report_accounting():
     """Byte accounting per wire mode: every numerics-only mode (bf16
     cast included — _wire_quantize dequantizes to f32 BEFORE the mean)

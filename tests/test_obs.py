@@ -491,6 +491,10 @@ def test_train_emits_trace_phases_and_wire_metrics(tmp_path, fused):
     assert cost[0]["program"] == ("fused_round" if fused else "inner_step")
     assert cost[0]["flops"] > 0 and cost[0]["flops_per_token"] > 0
     assert cost[0]["flops_per_token_hand"] > 0
+    # and which implementation the program's attention takes: on the CPU
+    # every layer runs dense blocks (models/llama.py:attention_paths)
+    paths = cost[0]["attention_paths"]
+    assert paths["fused"] == 0 and paths["dense"] > 0
     for r in syncs:
         assert r["t_inner"] > 0 and "t_data" in r
         assert r["wire_bytes_per_sync"] > 0 and r["wire_compression"] == 1.0

@@ -145,6 +145,46 @@ def test_blocked_dense_attention_halves_the_compiled_work(one_chip):
     )
 
 
+# (a'') the fused kernel behind _attention, at the benchmark's shape -----------
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full_layer", "window_layer"])
+def test_attention_at_mellum_shapes_compiles_to_three_kernels_and_no_scores(
+        one_chip, monkeypatch, window):
+    """``_attention`` at the shapes of ``mellum2-12b.train.seq8k`` (2 x
+    8,192, 32 heads over 4 of 128, bf16, a [B, S] validity array, value
+    and gradient) where it sees a TPU (this test steers the one question
+    the rule asks of the backend): the program holds three Mosaic calls
+    (forward, dq, dk/dv), and no array with a row of S scores, which is
+    what ``dense_attention``'s blocks make (bf16[2,32,512,8192] and
+    f32[...] for the full layer, [2,32,512,1535] for a window block)."""
+    import re
+
+    from nanodiloco_tpu.models.llama import _attention
+
+    b, s, h, hkv, hd = 2, 8192, 32, 4, 128
+    cfg = LlamaConfig(hidden_size=h * hd, num_attention_heads=h, num_key_value_heads=hkv)
+    assert cfg.attention_impl == "dense" and cfg.head_dim == hd
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(q, k, v, valid):
+        return jnp.sum(_attention(cfg, q, k, v, valid, None, window).astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((b, s, h, hd), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, hd), jnp.bfloat16, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, valid).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3
+    # every array of the program: none has rows x keys of scores as its
+    # last two dimensions (a dense block of 512 rows meets 1,535 keys or more)
+    shapes = {tuple(map(int, dims.split(","))) for dims in re.findall(
+        r"(?:bf16|f32)\[([0-9,]+)\]", text)}
+    scores = [d for d in shapes if len(d) >= 2 and d[-2] >= 512 and d[-1] >= 1535]
+    assert not scores, scores
+    assert _live_bytes(compiled) < 2 * 1024**3
+
+
 # (b) the paged serve programs at llama3_8b.json widths ----------------------
 
 @pytest.fixture(scope="module")
@@ -257,6 +297,32 @@ def fsdp4(topo):
         sharding=NamedSharding(mesh, batch_spec(sp=False)),
     )
     return mesh, dl, state, tok
+
+
+def test_inner_step_on_one_chip_with_heads_of_128_carries_the_fused_kernel(topo, monkeypatch):
+    """The plain stack's training step (``lax.scan`` over the layers,
+    each a ``jax.checkpoint``, under the worker ``vmap``) on ONE described
+    chip, heads of 128 over a sequence of two tiles, where the rule sees
+    a TPU: the layer body holds the fused kernel four times (the pass;
+    in the backward scan its recompute, dq and dk/dv), and the round's
+    own description says so."""
+    from nanodiloco_tpu.parallel import Diloco, DilocoConfig, MeshConfig, build_mesh
+    from nanodiloco_tpu.parallel.sharding import batch_spec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(diloco=1), devices=topo.devices[:1])
+    model = LlamaConfig(
+        vocab_size=4096, hidden_size=512, intermediate_size=1024, num_attention_heads=4,
+        num_key_value_heads=2, num_hidden_layers=3, dtype="bfloat16", loss_chunk=512,
+        remat=True)
+    assert model.head_dim == 128
+    dl = Diloco(model, DilocoConfig(num_workers=1, inner_steps=2, grad_accum=1), mesh)
+    assert dl.attention_paths(2048) == {"fused": 3, "dense": 0}
+    state = _abstract_diloco_state(dl, mesh)
+    tok = jax.ShapeDtypeStruct(
+        (1, 1, 2, 2048), np.int32, sharding=NamedSharding(mesh, batch_spec(sp=False)))
+    compiled = dl._inner_jit.lower(state, tok, tok).compile()
+    assert len(_kernel_calls(compiled)) == 4
 
 
 def test_inner_step_fsdp4_at_8b_widths_fits_v5e(fsdp4):
