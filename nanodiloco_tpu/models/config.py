@@ -16,6 +16,12 @@ from typing import Any
 
 
 LAYER_KINDS = ("sliding_attention", "full_attention")
+# layer kinds whose cache is no plain K/V rows: block-sparse attention
+# (compressed keys a slot beside the paged pool: models/sparse_attention.py)
+# and decayed linear attention (a per-slot state and no rows at all:
+# models/linear_attention.py). Beside LAYER_KINDS, which stays the set
+# a rotary table a layer kind is keyed by
+STATE_LAYER_KINDS = ("sparse_attention", "linear_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,6 +131,39 @@ class LlamaConfig:
     # holds weights for, and adds the outputs of, experts
     # first..first+count-1 alone. None = all of them
     experts_held: tuple[int, int] | None = None
+    # -- block-sparse and linear attention layers (STATE_LAYER_KINDS in
+    # ``layer_types``; serving only). A sparse layer's query at position
+    # t sees n = t + 1 keys: all of them up to ``sparse_dense_len``,
+    # else the first ``sparse_init_blocks`` blocks of ``sparse_block_size``
+    # keys, every block that holds one of the last ``sparse_window_size``
+    # keys, and the ``sparse_topk`` best of the rest by the scores of
+    # compressed keys (means of ``sparse_kernel_size`` keys every
+    # ``sparse_kernel_stride``): models/sparse_attention.py
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
+    # a linear layer's head h decays its state by exp(-2^(-e (h + 1) / H)
+    # * f_l) a token, e = ``linear_decay_exponent``, f_l = 1 - l / (N - 1)
+    # + 1e-5 for PUBLISHED layer index l = ``first_layer_index`` + i of
+    # N = ``published_layers`` (None: this stack is the whole model)
+    linear_decay_exponent: float = 8.0
+    first_layer_index: int = 0
+    published_layers: int | None = None
+    # sigmoid(W_g h) on the attention output of a sparse (``attn_``) and
+    # a linear layer, and an RMSNorm over a linear layer's joined heads
+    attn_output_gate: bool = False
+    linear_output_gate: bool = False
+    linear_output_norm: bool = False
+    # muP scalings: the embedding times ``scale_emb``; every residual
+    # branch times ``scale_depth`` / sqrt(published layers) (None: 1);
+    # the head's input divided by hidden_size / ``dim_model_base``
+    scale_emb: float = 1.0
+    scale_depth: float | None = None
+    dim_model_base: int | None = None
 
     @property
     def head_dim(self) -> int:
@@ -144,7 +183,45 @@ class LlamaConfig:
             or self.routed_scaling_factor != 1.0
             or self.experts_held is not None
             or self.moe_intermediate_size is not None
+            or self.state_layers
+            or self.attn_output_gate or self.linear_output_gate
+            or self.linear_output_norm or self.scale_emb != 1.0
+            or self.scale_depth is not None or self.dim_model_base is not None
         )
+
+    @property
+    def state_layers(self) -> bool:
+        """Whether any layer is of STATE_LAYER_KINDS."""
+        return bool(self.layer_types) and bool(
+            set(self.layer_types) & set(STATE_LAYER_KINDS))
+
+    @property
+    def residual_scale(self) -> float | None:
+        """What every residual branch is multiplied by (None: nothing)."""
+        if self.scale_depth is None:
+            return None
+        return self.scale_depth / (self.published_layers or self.num_hidden_layers) ** 0.5
+
+    @property
+    def head_divisor(self) -> float | None:
+        """What the head's input is divided by (None: nothing)."""
+        if self.dim_model_base is None:
+            return None
+        return self.hidden_size / self.dim_model_base
+
+    def rotates(self, kind: str) -> bool:
+        """Whether a layer of attention kind ``kind`` rotates q and k."""
+        return self.rope_layers == "all" or (
+            self.rope_layers, kind) in (("sliding", "sliding_attention"),
+                                        ("linear", "linear_attention"))
+
+    def linear_log_decay(self, i: int) -> list[float]:
+        """log(lambda_h) of linear layer ``i``'s heads: negative, fixed."""
+        n = self.published_layers or self.num_hidden_layers
+        f = 1.0 - (self.first_layer_index + i) / max(n - 1, 1) + 1e-5
+        nh = self.num_attention_heads
+        return [-(2.0 ** (-self.linear_decay_exponent * (h + 1) / nh)) * f
+                for h in range(nh)]
 
     @property
     def expert_width(self) -> int:
@@ -222,13 +299,17 @@ class LlamaConfig:
                 raise ValueError(
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"num_hidden_layers is {n}")
-            bad = set(self.layer_types) - set(LAYER_KINDS)
+            bad = set(self.layer_types) - set(LAYER_KINDS) - set(STATE_LAYER_KINDS)
             if bad:
-                raise ValueError(f"layer_types must be of {LAYER_KINDS}; got {sorted(bad)}")
+                raise ValueError(f"layer_types must be of {LAYER_KINDS + STATE_LAYER_KINDS}; "
+                                 f"got {sorted(bad)}")
             if "sliding_attention" in self.layer_types and not self.sliding_window:
                 raise ValueError("sliding_attention layers need sliding_window >= 1")
-        if self.rope_layers not in ("all", "sliding"):
-            raise ValueError(f"rope_layers must be 'all' or 'sliding'; got {self.rope_layers!r}")
+        if self.rope_layers not in ("all", "sliding", "linear"):
+            raise ValueError(
+                f"rope_layers must be 'all', 'sliding' or 'linear'; got {self.rope_layers!r}")
+        if self.state_layers:
+            self._check_state_layers()
         if self.rope_parameters is not None:
             kinds = {kind for kind, _ in self.rope_parameters}
             if kinds != set(LAYER_KINDS):
@@ -276,6 +357,32 @@ class LlamaConfig:
                 f"experts_held {self.experts_held} must lie inside the "
                 f"router's {self.num_experts} experts")
 
+    def _check_state_layers(self) -> None:
+        """The geometry a sparse layer's selection is written for, and
+        what a stack with a state does not carry."""
+        blk, kern, stride = (self.sparse_block_size, self.sparse_kernel_size,
+                             self.sparse_kernel_stride)
+        if min(blk, kern, stride, self.sparse_topk, self.sparse_window_size) < 1 \
+                or self.sparse_init_blocks < 0:
+            raise ValueError("the sparse layers' sizes must be positive")
+        if blk % stride or kern % stride or kern - stride > blk:
+            raise ValueError(
+                f"sparse_kernel_stride {stride} must divide sparse_block_size {blk} and "
+                f"sparse_kernel_size {kern}, and a compressed key may reach back over "
+                "at most one block")
+        if self.sparse_dense_len < self.sparse_window_size + blk * (self.sparse_init_blocks + 1):
+            raise ValueError(
+                "sparse_dense_len must hold the first blocks and the window's apart: "
+                "a query past it forces both and they may not overlap")
+        if self.num_experts:
+            raise ValueError(
+                "sparse_attention / linear_attention layers beside expert layers are "
+                "not carried: the layers' counters are the attention's or the experts'")
+        if self.rope_parameters is not None:
+            raise ValueError(
+                "sparse_attention / linear_attention layers take one rotary table "
+                "(rope_theta), not rope_parameters by layer kind")
+
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "LlamaConfig":
         """Build from an HF-style config dict, ignoring unknown keys.
@@ -317,6 +424,16 @@ class LlamaConfig:
         attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 2 * d  # q, k, v, o, norms
         if self.qk_norm:
             attn += 2 * hd
+        if self.state_layers:
+            wide = d * nh * hd
+            per_kind = {
+                "sparse_attention": attn + (wide if self.attn_output_gate else 0),
+                "linear_attention": 4 * wide + 2 * d + (2 * hd if self.qk_norm else 0)
+                + (wide if self.linear_output_gate else 0)
+                + (nh * hd if self.linear_output_norm else 0)}
+            head = 0 if self.tie_word_embeddings else d * v
+            return (v * d + sum(per_kind.get(k, attn) for k in self.layer_types)
+                    + l * 3 * d * f + d + head)
         if self.num_experts:
             fe = self.expert_width
             held = self.held_experts[1]

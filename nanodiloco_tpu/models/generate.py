@@ -39,15 +39,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from nanodiloco_tpu.models import linear_attention, sparse_attention
 from nanodiloco_tpu.models.config import LlamaConfig
 from nanodiloco_tpu.models.llama import (
     MASK_VALUE,
     Params,
     apply_rope,
+    attn_output,
     layer_plan,
     mixed_mlp_block,
     mlp_block,
     qkv_proj,
+    residual,
     rms_norm,
     rope_tables,
     run_layers,
@@ -73,17 +76,20 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_length: int) -> dict:
 
 
 def _plan_cache(cfg: LlamaConfig, entry) -> dict:
-    """The cache ``run_layers`` takes: ``entry(attention kind)`` for each
-    leading layer, and for each layer of the period stacked over the
-    periods."""
+    """The cache ``run_layers`` takes: ``entry(attention kind)`` (zeros)
+    for each leading layer, and for each layer of the period the same
+    zeros stacked over the periods."""
     plan = layer_plan(cfg)
     lead, period = _plan_kinds(cfg)
-    return {
-        "lead": tuple(entry(kind) for kind in lead),
-        "period": tuple(
-            jax.tree.map(lambda a: jnp.broadcast_to(a, (plan.periods,) + a.shape),
-                         entry(kind)) for kind in period),
-    }
+
+    def stacked(kind):
+        # made at its stacked shape: a broadcast of the unstacked zeros
+        # would hold both at once, and a serving cache is gigabytes
+        shapes = jax.eval_shape(lambda: entry(kind))
+        return jax.tree.map(lambda a: jnp.zeros((plan.periods,) + a.shape, a.dtype), shapes)
+
+    return {"lead": tuple(entry(kind) for kind in lead),
+            "period": tuple(stacked(kind) for kind in period)}
 
 
 def _plan_kinds(cfg: LlamaConfig) -> tuple[list, list]:
@@ -106,8 +112,15 @@ def _mixed_layer(cfg: LlamaConfig, x, layer, kind, rope, attend, token_valid):
     with jax.named_scope("attn_proj"):
         q, k, v = qkv_proj(cfg, h, layer, rope, kind[0])
     attn, entry = attend(q, k, v)
+    if cfg.state_layers:
+        # a sparse or linear layer hands out its own counters and choice
+        # beside the entry; neither kind stands beside expert layers
+        entry, counters, chosen = entry
+        attn = attn_output(cfg, attn, h, layer, kind[0])
     with jax.named_scope("attn_proj"):
-        x = x + attn @ layer["wo"].astype(cdt)
+        x = residual(cfg, x, attn @ layer["wo"].astype(cdt))
+    if cfg.state_layers:
+        return mixed_mlp_block(cfg, x, layer, token_valid)[0], entry, counters, chosen
     x, counters, chosen = mixed_mlp_block(cfg, x, layer, token_valid)
     return x, entry, counters, chosen
 
@@ -179,6 +192,13 @@ def _cached_block(
     at the long contexts the training side supports (VERDICT r2 weak #5)
     — and the block loop's upper bound is the live prefix ``pos + T``,
     so early decode steps never touch the untouched cache tail."""
+    if cfg.state_layers:
+        raise ValueError(
+            "generate()'s one-program cache holds K and V rows alone: a "
+            "linear_attention layer's per-row state and a sparse_attention "
+            "layer's compressed keys are kept by the serving engine only "
+            "(serve/engine.py: InferenceEngine), and left-padded prompts "
+            "would need a mask neither carries")
     if cfg.mixed:
         if block:
             raise ValueError(
@@ -1207,18 +1227,38 @@ def verify_slots_paged_fn(cfg: LlamaConfig, kv_dtype: str | None = None,
 
 
 def init_mixed_serve_cache(cfg: LlamaConfig, slots: int, ring_rows: int,
-                           num_blocks: int, block_size: int) -> dict:
+                           num_blocks: int, block_size: int, comp_rows: int = 0) -> dict:
     """The serve cache of a mixed configuration: a pool
     ``[num_blocks, block_size, Hkv, hd]`` for each full layer, a ring
     ``[slots, ring_rows, Hkv, hd]`` for each sliding layer, k and v, in
-    the compute dtype."""
+    the compute dtype; for each sparse layer a pool ``[num_blocks, Hkv,
+    block_size, hd]`` and its compressed keys ``c`` ``[slots, comp_rows,
+    Hkv, hd]`` (row j of a slot is its stream's compressed key j); for
+    each linear layer a float32 state ``s`` ``[slots, H, hd, hd]``."""
     cdt = jnp.dtype(cfg.dtype)
+    nkv, nh, hd = cfg.kv_heads, cfg.num_attention_heads, cfg.head_dim
     shapes = {
-        "full_attention": (num_blocks, block_size, cfg.kv_heads, cfg.head_dim),
-        "sliding_attention": (slots, ring_rows, cfg.kv_heads, cfg.head_dim),
+        "full_attention": (num_blocks, block_size, nkv, hd),
+        "sliding_attention": (slots, ring_rows, nkv, hd),
+        # a (block, KV head) is one contiguous tile: a tick gathers the
+        # blocks each KV group chose
+        "sparse_attention": (num_blocks, nkv, block_size, hd),
     }
-    return _plan_cache(cfg, lambda kind: {"k": jnp.zeros(shapes[kind], cdt),
-                                          "v": jnp.zeros(shapes[kind], cdt)})
+
+    def entry(kind):
+        if kind == "linear_attention":  # a state and no rows
+            return {"s": jnp.zeros((slots, nh, hd, hd), jnp.float32)}
+        rows = {"k": jnp.zeros(shapes[kind], cdt), "v": jnp.zeros(shapes[kind], cdt)}
+        if kind == "sparse_attention":
+            # the selector's cache, one row a stride of a slot's stream,
+            # addressed by slot and row as a ring is: a tick reads every
+            # live stream's rows, and read through the block tables they
+            # were 512-byte pieces that the chip gathered at a twentieth
+            # of its bandwidth (PERF.md, PR 34); here they are one slice
+            rows["c"] = jnp.zeros((slots, comp_rows, nkv, hd), cdt)
+        return rows
+
+    return _plan_cache(cfg, entry)
 
 
 def mixed_cache_bytes(cfg: LlamaConfig, cache: dict) -> dict:
@@ -1226,8 +1266,132 @@ def mixed_cache_bytes(cfg: LlamaConfig, cache: dict) -> dict:
     lead, period = _plan_kinds(cfg)
     out = {"full_attention": 0, "sliding_attention": 0}
     for kind, entry in zip(lead + period, cache["lead"] + cache["period"]):
-        out[kind] += sum(a.nbytes for a in jax.tree.leaves(entry))
+        for name, a in entry.items():
+            # a sparse layer's compressed keys are a cache kind of their own
+            of = "compressed_keys" if name == "c" else kind
+            out[of] = out.get(of, 0) + a.nbytes
     return out
+
+
+def _sparse_attend(cfg: LlamaConfig, entry, tables, ring_slot, qpos, active, token_valid):
+    """``attend(q, k, v)`` of a sparse layer through its pool ``k``,
+    ``v`` [blocks, Hkv, bs, hd] behind ``tables`` [B, mb] and its
+    compressed keys ``c`` [slots, rows, Hkv, hd] (row b is slot b, or
+    the one row is slot ``ring_slot``): writes the new rows and the
+    compressed keys they complete, then attends. A chunk (T > 1) reads
+    the table's view at a rung of ``view_ladder`` and masks every
+    query's own choice over it (``sparse_attention.masked``). A tick (T
+    = 1) reads every slot's compressed keys, chooses, and gathers the
+    chosen blocks alone (``sparse_attention.gathered``);
+    rows still within ``dense_len`` read their view, a narrow one, and
+    either side is skipped where no live row needs it. Returns
+    (attention [B, T, H * hd], (entry, COUNTERS, choice))."""
+    nb, nkv, bs, hd = entry["k"].shape
+    slots = entry["c"].shape[0]
+    b, t = qpos.shape
+    mb = tables.shape[1]
+    kern, stride = cfg.sparse_kernel_size, cfg.sparse_kernel_stride
+    head = jnp.arange(nkv)
+    ladder = view_ladder(mb)
+    live = (token_valid > 0) & (active[:, None] > 0)             # [B, T]
+
+    def block_of(at):
+        return jnp.take_along_axis(tables, jnp.clip(at // bs, 0, mb - 1), axis=1)
+
+    with jax.named_scope("kv_write"):
+        phys = jnp.where(active[:, None] > 0, block_of(qpos), nb)   # dead rows drop
+        off = qpos % bs
+    # the compressed keys this call completes: the one that ENDS at
+    # position e starts at e - kernel + 1, a multiple of the stride. A
+    # chunk starts at a multiple of the stride, so its candidates are
+    # known by their place in it; any other call asks every position
+    cand = [i for i in range(t) if (i - kern + 1) % stride == 0] if t % stride == 0 \
+        else list(range(t))
+    with jax.named_scope("attention"), jax.named_scope("kv_compress"):
+        cand = jnp.asarray(cand, jnp.int32)
+        start = qpos[:, cand] - (kern - 1)                           # [B, M]
+        good = (start >= 0) & (start % stride == 0) & live[:, cand]
+        kpos = start[..., None] + jnp.arange(kern)                   # [B, M, kern]
+        kphys = block_of(kpos.reshape(b, -1)).reshape(kpos.shape)
+        cslot = jnp.arange(b)[:, None] if ring_slot is None else ring_slot[None, None]
+        cslot = jnp.where(good, cslot, slots)                        # the others drop
+        crow = jnp.maximum(start, 0) // stride
+
+    def need(on):
+        """Rows the longest of the rows ``on`` holds after this call."""
+        return jnp.max(jnp.where(on, qpos[:, -1] + 1, 0))
+
+    def attend(q, k, v):
+        cdt = entry["k"].dtype
+        with jax.named_scope("kv_write"):
+            at = (phys[:, :, None], head[None, None, :], off[:, :, None])
+            pk = entry["k"].at[at].set(k.astype(cdt), mode="drop")
+            pv = entry["v"].at[at].set(v.astype(cdt), mode="drop")
+        with jax.named_scope("attention"), jax.named_scope("kv_compress"):
+            rows = pk[kphys[..., None], head, (kpos % bs)[..., None]]   # [B, M, kern, Hkv, hd]
+            made = jnp.mean(rows.astype(jnp.float32), axis=2).astype(cdt)
+            pc = entry["c"].at[cslot, crow].set(made, mode="drop")
+
+        @jax.named_scope("kv_gather")
+        def view(w):
+            tw = tables[:, :w]
+            rows = lambda p: jnp.moveaxis(p[tw], 2, 1).reshape(b, nkv, w * bs, hd)
+            return rows(pk), rows(pv)
+
+        def comp_view(w):
+            with jax.named_scope("attention"), jax.named_scope("sparse_select"):
+                mine = pc if ring_slot is None else \
+                    jax.lax.dynamic_index_in_dim(pc, ring_slot, 0, keepdims=True)
+                return mine[:, :w * bs // stride]
+
+        if t > 1:
+            def at_width(w):
+                return lambda q: sparse_attention.masked(
+                    cfg, q, *view(w), comp_view(w), qpos, live)
+
+            # every second width: a branch here is a loop over query
+            # blocks with a choice in it, a quarter of a minute of
+            # compiling each at published widths, and the masked scores
+            # are a fifth of a chunk
+            wide = ladder[1::2] if len(ladder) % 2 == 0 else ladder
+            rung = view_rung(wide, need(active > 0), bs)
+            out, idx, counters = jax.lax.switch(rung, [at_width(w) for w in wide], q)
+            return out, ({"k": pk, "v": pv, "c": pc}, counters, idx)
+
+        far = (active > 0) & sparse_attention.chooses(cfg, qpos[:, 0])
+        near = (active > 0) & ~far
+        # rows within dense_len see at most dense_len keys: the ladder's
+        # widths up to the first that holds them
+        short = ladder[:next((i for i, w in enumerate(ladder)
+                              if w * bs >= cfg.sparse_dense_len), len(ladder) - 1) + 1]
+
+        def chosen(q):
+            # every slot's compressed keys at the table's whole width: a
+            # slice of 76 MB where all 32 streams are longest, a tenth of
+            # a millisecond, so no rung is taken for it
+            out, idx, counters = sparse_attention.gathered(
+                cfg, q[:, 0], pk, pv, comp_view(mb), tables, qpos[:, 0], live[:, 0], bs)
+            return out[:, None], idx, counters
+
+        def whole(q):
+            def at_width(w):
+                def run(q):
+                    ok = jnp.arange(w * bs)[None, None, None, :] <= qpos[:, None, :, None]
+                    return sparse_attention.attend(q, *view(w), jnp.where(ok, 0.0, MASK_VALUE))
+                return run
+
+            rung = view_rung(short, need(near), bs)
+            return jax.lax.switch(rung, [at_width(w) for w in short], q)
+
+        none = (jnp.zeros((b, 1, q.shape[2] * hd), q.dtype),
+                jnp.full((b, 1, nkv, cfg.sparse_topk), -1, jnp.int32),
+                jnp.zeros((len(sparse_attention.COUNTERS),), jnp.int32))
+        out, idx, counters = jax.lax.cond(jnp.any(far), chosen, lambda q: none, q)
+        dense = jax.lax.cond(jnp.any(near), whole, lambda q: none[0], q)
+        out = jnp.where(far[:, None, None], out, dense)
+        return out, ({"k": pk, "v": pv, "c": pc}, counters, idx)
+
+    return attend
 
 
 def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slot,
@@ -1247,6 +1411,8 @@ def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slo
     window = cfg.sliding_window or 0
     with jax.named_scope("embed"):
         x = params["embed"].astype(cdt)[tokens]
+        if cfg.scale_emb != 1.0:
+            x = x * cfg.scale_emb
     qpos = pos[:, None] + jnp.arange(t)[None, :]             # [B, T]
     cos, sin = _slot_rope_tables(cfg, qpos, cdt)
 
@@ -1308,12 +1474,44 @@ def _serve_block_mixed(params, cfg: LlamaConfig, tokens, cache, tables, ring_slo
 
         return attend
 
+    def attend_sparse(entry):
+        return _sparse_attend(cfg, entry, tables, ring_slot, qpos, active, token_valid)
+
+    def attend_linear(entry, log_decay):
+        def attend(q, k, v):
+            whole = entry["s"]
+            state = whole if ring_slot is None else \
+                jax.lax.dynamic_index_in_dim(whole, ring_slot, 0, keepdims=True)
+            # a slot taken again starts from zero: a state has no mask
+            # that could hide what the last stream left in it
+            fresh = (active > 0) & (pos == 0)
+            state = jnp.where(fresh[:, None, None, None], 0.0, state)
+            if t == 1:
+                o, new = linear_attention.step(q[:, 0], k[:, 0], v[:, 0], state,
+                                               log_decay, active)
+                o = o[:, None]
+            else:
+                o, new = linear_attention.chunk(q, k, v, state, log_decay,
+                                                jnp.sum(token_valid, axis=1))
+            if ring_slot is not None:
+                new = jax.lax.dynamic_update_index_in_dim(whole, new[0], ring_slot, 0)
+            counters = jnp.zeros((len(sparse_attention.COUNTERS),), jnp.int32)
+            return o.reshape(b, t, -1), ({"s": new}, counters.at[-1].set(jnp.sum(active)), None)
+
+        return attend
+
     def body(x, layer, kind, c):
-        attend = (attend_ring if kind[0] == "sliding_attention" else attend_full)(c)
+        if kind[0] == "linear_attention":
+            attend = attend_linear(c, layer["log_decay"])
+        else:
+            attend = {"sliding_attention": attend_ring, "sparse_attention": attend_sparse}.get(
+                kind[0], attend_full)(c)
         return _mixed_layer(cfg, x, layer, kind, rope, attend, token_valid)
 
     x, cache, counters, recs = run_layers(cfg, params, x, body, cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if cfg.head_divisor is not None:
+        x = x / cfg.head_divisor
     chosen = [r for r in recs if r is not None]
     chosen = jnp.stack(chosen) if chosen else jnp.zeros((0, b, t, 1), jnp.int32)
     return x, cache, counters, chosen
@@ -1358,6 +1556,9 @@ def decode_slots_mixed_fn(cfg: LlamaConfig):
         logits = _head_logits(params, x[:, 0], jnp.dtype(cfg.dtype))
         keys = jax.random.wrap_key_data(key_data)
         nxt = _sample_slots(logits, keys, temperature, top_k, top_p)
+        if cfg.state_layers:
+            # the logits too: the engine's ``capture_decode_logits`` probe
+            return nxt, cache, counters, chosen, logits
         return nxt, cache, counters, chosen
 
     return jax.jit(run, donate_argnums=_serve_donate())

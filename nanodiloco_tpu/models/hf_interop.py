@@ -206,8 +206,34 @@ def from_hf_pretrained(path: str, cfg: LlamaConfig) -> Params:
     per-layer tensor — an 8B import never holds the ~32 GB fp32 state
     dict the in-memory path would (ref context: the reference lives in
     the HF ecosystem, ref nanodiloco/main.py:97-99)."""
+    _refuse_other_families(path)
     with _HFWeightSource(path) as src:
         return _build_params(src.get, src.has, cfg)
+
+
+def _refuse_other_families(path: str) -> None:
+    """A checkpoint directory says what it holds in its ``config.json``.
+    A ``minicpm_sala`` checkpoint shares every q/k/v/o and SwiGLU name
+    with a Llama, so it would load as one and run as a wrong model: it
+    is refused by name, with what an import of it lacks."""
+    import json
+    import os
+
+    conf = os.path.join(path if os.path.isdir(path) else os.path.dirname(path), "config.json")
+    if not os.path.exists(conf):
+        return
+    with open(conf) as f:
+        model_type = json.load(f).get("model_type")
+    if model_type == "minicpm_sala":
+        raise ValueError(
+            "this is a minicpm_sala checkpoint (block-sparse and lightning "
+            "linear-attention layers) and HF interop maps dense Llama weights "
+            "only. Missing for an import: the weight names of the output gates "
+            "(w_og), the q/k norms and the lightning layers' output norm "
+            "(q_norm, k_norm, o_norm), and a layer order read from the "
+            "checkpoint's mixer_types into LlamaConfig.layer_types; the "
+            "program serves this family from seeded weights "
+            "(models/sparse_attention.py, models/linear_attention.py)")
 
 
 def _export_plan(

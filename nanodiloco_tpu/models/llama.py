@@ -36,7 +36,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from nanodiloco_tpu.models.config import LAYER_KINDS, LlamaConfig
+from nanodiloco_tpu.models import linear_attention, sparse_attention
+from nanodiloco_tpu.models.config import LAYER_KINDS, STATE_LAYER_KINDS, LlamaConfig
 from nanodiloco_tpu.models.moe import COUNTERS, ROUTER_STATS
 
 Params = dict[str, Any]
@@ -126,6 +127,19 @@ def _init_mixed_params(cfg: LlamaConfig, keys, normal) -> Params:
         if cfg.qk_norm:
             g["q_norm"] = jnp.ones(n + (hd,), pdt)
             g["k_norm"] = jnp.ones(n + (hd,), pdt)
+        kind = plan.kinds[tag][0]
+        if kind == "linear_attention":
+            # every head its own k and v; the fixed decays ride with the
+            # weights (one row a period where the plan stacks them)
+            g["wk"] = normal(next(ks), n + (d, nh * hd))
+            g["wv"] = normal(next(ks), n + (d, nh * hd))
+            ld = [cfg.linear_log_decay(tag + p * plan.period) for p in range(n[0] if n else 1)]
+            g["log_decay"] = jnp.asarray(ld if n else ld[0], jnp.float32)
+            if cfg.linear_output_norm:
+                g["o_norm"] = jnp.ones(n + (nh * hd,), pdt)
+        if (kind == "linear_attention" and cfg.linear_output_gate) or (
+                kind == "sparse_attention" and cfg.attn_output_gate):
+            g["w_og"] = normal(next(ks), n + (d, nh * hd))
         if not sparse:
             g["w_gate"] = normal(next(ks), n + (d, f))
             g["w_up"] = normal(next(ks), n + (d, f))
@@ -191,6 +205,14 @@ def layer_plan(cfg: LlamaConfig) -> LayerPlan:
     return LayerPlan(kinds, dense, 1, 0)  # no layer after the dense ones
 
 
+def layer_counters(cfg: LlamaConfig) -> tuple[str, ...]:
+    """The names of what a mixed stack's layers count: the attention's
+    (``sparse_attention.COUNTERS``) where the stack has sparse or linear
+    layers, else the expert layers' (``moe.COUNTERS``); a stack has one
+    or the other."""
+    return sparse_attention.COUNTERS if cfg.state_layers else COUNTERS
+
+
 def run_layers(cfg: LlamaConfig, params: Params, x, body, cache=None):
     """Run a mixed configuration's layers over ``x`` by its
     ``layer_plan``. ``body(x, layer, kind, c) -> (x, c, counters, rec)``
@@ -205,7 +227,7 @@ def run_layers(cfg: LlamaConfig, params: Params, x, body, cache=None):
     (x, cache, summed counters, the layers' recs in layer order)."""
     plan = layer_plan(cfg)
     lead_c = list(cache["lead"]) if cache is not None else [None] * plan.lead
-    counters = jnp.zeros((len(COUNTERS),), jnp.int32)
+    counters = jnp.zeros((len(layer_counters(cfg)),), jnp.int32)
     recs = []
     for i in range(plan.lead):
         x, lead_c[i], n, rec = body(x, params["lead_layers"][i], plan.kinds[i], lead_c[i])
@@ -566,6 +588,8 @@ def qkv_proj(cfg: LlamaConfig, h, layer: Params, rope, attn_kind: str = "full_at
     training forward and of every cached program. Under ``attn_proj``."""
     b, t, _ = h.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    if attn_kind == "linear_attention":
+        nkv = nh
     cdt = h.dtype
     q = (h @ layer["wq"].astype(cdt)).reshape(b, t, nh, hd)
     k = (h @ layer["wk"].astype(cdt)).reshape(b, t, nkv, hd)
@@ -573,9 +597,28 @@ def qkv_proj(cfg: LlamaConfig, h, layer: Params, rope, attn_kind: str = "full_at
     if cfg.qk_norm:
         q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-    if cfg.rope_layers == "all" or attn_kind == "sliding_attention":
+    if cfg.rotates(attn_kind):
         q, k = rope(q), rope(k)
     return q, k, v
+
+
+def attn_output(cfg: LlamaConfig, attn, h, layer: Params, attn_kind: str):
+    """What stands between a layer's attention [B, T, H * hd] and its
+    output projection: a linear layer's RMSNorm over the joined heads
+    and the sigmoid gate ``sigmoid(h W_g)`` of the layers that have one
+    (``w_og``); the compute dtype out. Nothing for the other kinds."""
+    if attn_kind == "linear_attention" and cfg.linear_output_norm:
+        attn = rms_norm(attn, layer["o_norm"], cfg.rms_norm_eps)
+    if "w_og" in layer:
+        with jax.named_scope("attn_proj"):
+            attn = attn * jax.nn.sigmoid(h @ layer["w_og"].astype(h.dtype)).astype(attn.dtype)
+    return attn.astype(h.dtype)
+
+
+def residual(cfg: LlamaConfig, x, branch):
+    """x + branch, the branch times the configuration's muP scale."""
+    s = cfg.residual_scale
+    return x + branch if s is None else x + (s * branch).astype(x.dtype)
 
 
 def _attn_block(cfg: LlamaConfig, x, layer: Params, cos, sin, attn_valid, sp_axis,
@@ -589,10 +632,36 @@ def _attn_block(cfg: LlamaConfig, x, layer: Params, cos, sin, attn_valid, sp_axi
     # GQA K/V stay at Hkv heads here; flash/ring are GQA-native (K/V are
     # never expanded in HBM/ICI — the bandwidth GQA exists to save) and
     # _attention expands only for its dense paths.
+    if attn_kind in STATE_LAYER_KINDS:
+        attn = _state_attention(cfg, q, k, v, layer, attn_valid, attn_kind)
+        attn = attn_output(cfg, attn, h, layer, attn_kind)
+        with jax.named_scope("attn_proj"):
+            return residual(cfg, x, attn @ layer["wo"].astype(cdt))
     window = cfg.sliding_window if attn_kind == "sliding_attention" else None
     attn = _attention(cfg, q, k, v, attn_valid, sp_axis, window)
     with jax.named_scope("attn_proj"):
-        return x + attn.reshape(b, s, -1) @ layer["wo"].astype(cdt)
+        return residual(cfg, x, attn.reshape(b, s, -1) @ layer["wo"].astype(cdt))
+
+
+def _state_attention(cfg: LlamaConfig, q, k, v, layer: Params, attn_valid, attn_kind: str):
+    """A sparse or linear layer over a whole sequence from position 0:
+    every query's own choice of blocks as a mask, the recurrence from a
+    zero state in its chunked form. [B, S, H * hd]."""
+    if attn_valid is not None:
+        raise ValueError(
+            f"a {attn_kind} layer takes whole sequences: a padding mask would have to "
+            "reach its compressed keys or its state, and neither carries one")
+    b, s, nh, hd = q.shape
+    if attn_kind == "linear_attention":
+        state = jnp.zeros((b, nh, hd, hd), jnp.float32)
+        return linear_attention.chunk(q, k, v, state, layer["log_decay"])[0].reshape(b, s, -1)
+    qpos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    comp = sparse_attention.compress_keys(cfg, k)
+    if comp.shape[1] == 0:  # shorter than one compressed key: nobody chooses
+        comp = jnp.zeros((b, 1) + k.shape[2:], k.dtype)
+    return sparse_attention.masked(
+        cfg, q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), comp, qpos,
+        jnp.ones((b, s), jnp.int32))[0]
 
 
 def _decoder_layer(
@@ -627,8 +696,8 @@ def mixed_mlp_block(cfg: LlamaConfig, x, layer: Params, valid=None, with_stats=F
             return x + out, counters, rec
         gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
         up = h @ layer["w_up"].astype(cdt)
-        return (x + (gate * up) @ layer["w_down"].astype(cdt),
-                jnp.zeros((len(COUNTERS),), jnp.int32),
+        return (residual(cfg, x, (gate * up) @ layer["w_down"].astype(cdt)),
+                jnp.zeros((len(layer_counters(cfg)),), jnp.int32),
                 jnp.zeros((len(ROUTER_STATS),), jnp.float32) if with_stats else None)
 
 
@@ -705,13 +774,16 @@ def forward(
     b, s = tokens.shape
     with jax.named_scope("embed"):
         x = params["embed"].astype(cdt)[tokens]
+        if cfg.scale_emb != 1.0:
+            x = x * cfg.scale_emb
     with jax.named_scope("attn_proj"):
         if cfg.mixed:
             # one table a layer kind, built once a pass: the kind's own
             # rotary parameters where the configuration has them
             with jax.named_scope("rope"):
                 if cfg.rope_parameters is None:
-                    tables = dict.fromkeys(LAYER_KINDS, rope_tables(cfg, s, position_offset))
+                    tables = dict.fromkeys(LAYER_KINDS + STATE_LAYER_KINDS,
+                                           rope_tables(cfg, s, position_offset))
                 else:
                     tables = {kind: rope_tables(cfg, s, position_offset, kind)
                               for kind in sorted({k[0] for k in layer_plan(cfg).kinds})}
@@ -787,6 +859,8 @@ def forward(
             return out, aux, moe_counters
         return (out, aux) if with_aux else out
 
+    if cfg.head_divisor is not None:
+        x = x / cfg.head_divisor
     if return_hidden:
         return pack(x)
     with jax.named_scope("head"):

@@ -382,6 +382,14 @@ class Diloco:
                     "live program inputs every round — there is no "
                     "between-syncs window to park them in host memory"
                 )
+        if loss_fn is None and model_cfg.state_layers:
+            raise ValueError(
+                "training does not carry sparse_attention / linear_attention "
+                "layers: the chunked form of the decayed recurrence "
+                "(models/linear_attention.py) has no backward pass written for "
+                "it (its state would have to be recomputed or saved a chunk), "
+                "and the block choice is a top-k with no gradient path to the "
+                "compressed keys; these layers are served only")
         # a mixed sparse configuration's expert layer reports what it did
         # (models/moe.py TRAIN_COUNTERS, the balance term): the inner step
         # and the round hand it out beside their losses

@@ -120,6 +120,22 @@ over, and refused at construction by name: speculation, the prefix
 cache, int8 rows, a serving mesh; ``export_kv`` and ``import_kv``
 refuse at the call.
 
+Three kinds of cache (``cfg.state_layers``: block-sparse and linear
+attention layers, models/sparse_attention.py, models/linear_attention.py):
+a sparse layer keeps its K and V rows in the paged pool behind the block
+tables as a full layer does, and beside them its COMPRESSED keys (a mean
+of 32 keys every 16, the rows its selector scores) in a per-slot arena
+``[slots, max rows / 16, Hkv, hd]`` addressed by slot and row as a ring
+is (stale rows lie past what the mask of complete rows lets through); a
+linear layer keeps a float32 state ``[slots, heads, hd, hd]`` and no
+rows at all. A state has no such mask: the
+programs zero a row's state where the row is live at position 0 (the
+first chunk of whatever stream takes the slot). The tick and chunk
+programs return the attention's counters (``attn_stats()``) and, with
+``capture_routing`` set, the blocks every query chose. Refused for this
+stack by name, each with the cache kind that stops it: speculation, the
+prefix cache, int8 rows, a serving mesh, ``export_kv``/``import_kv``.
+
 Known divergence, inherited from ``generate`` and narrowed here: dense-
 dispatch token-choice MoE sizes expert capacity from the tokens in the
 call, so a decode tick routes over B slots where ``generate`` routes
@@ -152,6 +168,7 @@ from nanodiloco_tpu.models.generate import (
     view_ladder,
     view_rung,
 )
+from nanodiloco_tpu.models.llama import layer_counters
 from nanodiloco_tpu.models.moe import COUNTERS
 from nanodiloco_tpu.obs.devtime import DispatchAccountant
 from nanodiloco_tpu.obs.telemetry import Histogram
@@ -243,19 +260,31 @@ class InferenceEngine:
             # a mixed layer stack's two kinds of cache carry the paged
             # bf16 pool, chunked prefill and the plain tick; the rest
             # refuses here, by name, and takes no silent other path
-            for on, what in (
-                (spec_k, "speculation (spec_k > 0: the verify programs)"),
-                (prefix_cache_tokens, "the prefix cache (prefix_cache_tokens > 0)"),
-                (self.kv_dtype == "int8", "kv_dtype='int8' (quantized rows)"),
-                (int(tp) > 1, "a serving mesh (tp > 1)"),
+            # (on, the feature, what of a stack with sparse and linear
+            # layers cannot follow it: the cache kind that stops it)
+            for on, what, state_why in (
+                (spec_k, "speculation (spec_k > 0: the verify programs)",
+                 "a linear layer's state cannot step back over a rejected draft (it "
+                 "holds no rows to drop), and a sparse layer's compressed keys would "
+                 "be completed by tokens that are then taken back"),
+                (prefix_cache_tokens, "the prefix cache (prefix_cache_tokens > 0)",
+                 "shared blocks carry K and V rows, but the per-slot state and the "
+                 "per-slot compressed keys at the end of the shared prefix are held "
+                 "nowhere (no snapshot of a state)"),
+                (self.kv_dtype == "int8", "kv_dtype='int8' (quantized rows)",
+                 "the compressed keys and the float32 state have no quantized form"),
+                (int(tp) > 1, "a serving mesh (tp > 1)",
+                 "no partition rule is written for the compressed keys or the state"),
             ):
                 if on:
+                    why = (f"a stack with linear-attention layers: {state_why}"
+                           if cfg.state_layers else
+                           "a mixed layer stack (window layers in per-slot rings "
+                           "beside the paged pool)")
                     raise ValueError(
-                        f"{what} is not carried over to a mixed layer stack "
-                        "(window layers in per-slot rings beside the paged "
-                        "pool): serve this configuration with a "
-                        "model-dtype cache, no speculation, no prefix cache, "
-                        "tp=1")
+                        f"{what} is not carried over to {why}: serve this "
+                        "configuration with a model-dtype cache, no speculation, "
+                        "no prefix cache, tp=1")
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0; got {spec_k}")
         # tensor parallelism: shard params (param_specs), the compiled
@@ -337,8 +366,19 @@ class InferenceEngine:
             # full layers in the pool, sliding layers in rings a chunk
             # wider than the window (generate.py says why)
             self.ring_rows = int(cfg.sliding_window or 0) + self.chunk_size
+            if cfg.state_layers and (
+                    bs % cfg.sparse_kernel_stride or cfg.sparse_block_size % bs
+                    or self.chunk_size % cfg.sparse_kernel_stride):
+                raise ValueError(
+                    f"a sparse layer's pool needs sparse_kernel_stride "
+                    f"{cfg.sparse_kernel_stride} | kv_block_size {bs} | "
+                    f"sparse_block_size {cfg.sparse_block_size}, and chunks of "
+                    f"whole strides (chunk_size {self.chunk_size})")
             self.pool = init_mixed_serve_cache(
-                cfg, self.num_slots, self.ring_rows, nb, bs)
+                cfg, self.num_slots, self.ring_rows, nb, bs,
+                # a sparse layer's compressed keys: one a stride of the
+                # longest stream a table can hold
+                self.table_blocks * bs // cfg.sparse_kernel_stride)
             self._chunk_paged = prefill_chunk_mixed_fn(cfg)
             self._decode_paged = decode_slots_mixed_fn(cfg)
         else:
@@ -432,8 +472,20 @@ class InferenceEngine:
         # ``capture_routing`` set, the experts each slot's tokens chose
         # land in ``routing_log[slot]`` as [L_sparse, tokens, k] pieces,
         # in position order (the benchmark's check reads them)
-        self.moe_counts = {"prefill_chunk": np.zeros(len(COUNTERS), np.int64),
-                           "decode": np.zeros(len(COUNTERS), np.int64)}
+        # A stack with sparse and linear layers counts its attention in
+        # their place (``layer_counters``: attn_stats()); its
+        # ``routing_log`` holds the blocks each query chose, [L_sparse,
+        # tokens, Hkv, topk]. A third probe for such a stack: a stream
+        # whose prefill ends while ``capture_decode_logits`` is set has
+        # every tick's logits [V] appended to ``decode_logits_log[slot]``
+        # until its slot is released (the other slots' are not copied);
+        # the log stays until the caller clears it
+        self._counter_names = layer_counters(cfg) if self.mixed else COUNTERS
+        self.moe_counts = {"prefill_chunk": np.zeros(len(self._counter_names), np.int64),
+                           "decode": np.zeros(len(self._counter_names), np.int64)}
+        self.capture_decode_logits = False
+        self.decode_logits_log: dict[int, list[np.ndarray]] = {}
+        self._logit_slots: set[int] = set()
         self.capture_routing = False
         self.routing_log: dict[int, list[np.ndarray]] = {}
         # device-resident copies of the slot state that only changes at
@@ -572,6 +624,8 @@ class InferenceEngine:
         prompt + completion rows, rounded up to whole blocks. Allocation
         is up-front and exact, so a request admitted never runs out of
         cache mid-decode."""
+        # (a sparse layer's compressed keys are held a slot, not a block:
+        # the count stands)
         return -(-(prompt_tokens + max_new_tokens) // self.kv_block_size)
 
     def validate(self, prompt, max_new_tokens: int) -> None:
@@ -762,6 +816,9 @@ class InferenceEngine:
         tok0 = int(tok)
         if self.capture_prefill_logits:
             self.last_prefill_logits = np.asarray(logits)
+        if self.capture_decode_logits:
+            self.decode_logits_log[slot] = []
+            self._logit_slots.add(slot)
         pf.done = p
         n = int(req.max_new_tokens)
         with trace_span("engine.keys", slot=slot):
@@ -944,11 +1001,13 @@ class InferenceEngine:
                 counts = chosen = None
                 with trace_span("engine.decode_dispatch"):
                     if self.mixed:
-                        nxt, self.pool, counts, chosen = self._decode_paged(
+                        nxt, self.pool, counts, chosen, *logits = self._decode_paged(
                             params, self.pool, dev["tables"],
                             tokens, pos, keys,
                             dev["temp"], dev["topk"], dev["topp"], active,
                         )
+                        for s in self._logit_slots:
+                            self.decode_logits_log[s].append(np.asarray(logits[0][s]))
                     else:
                         nxt, self.pool = self._decode_paged(
                             params, self.pool, dev["tables"],
@@ -1151,10 +1210,27 @@ class InferenceEngine:
         ticks apart."""
         if not (self.mixed and self.cfg.num_experts):
             return None
-        by = {kind: dict(zip(COUNTERS, (int(x) for x in c)))
+        return self._counter_stats()
+
+    def _counter_stats(self) -> dict:
+        names = self._counter_names
+        by = {kind: dict(zip(names, (int(x) for x in c)))
               for kind, c in self.moe_counts.items()}
-        return {**{n: sum(c[n] for c in by.values()) for n in COUNTERS},
+        return {**{n: sum(c[n] for c in by.values()) for n in names},
                 "by_program": by}
+
+    def attn_stats(self) -> dict | None:
+        """The sparse and linear layers' counters over the engine's life
+        (None for a stack without them), summed over layers, ticks and
+        chunks, over queries past ``sparse_dense_len`` alone: K/V rows
+        the chosen blocks held (``sparse_rows_read``, a KV group's
+        mean), rows the streams held (``sparse_rows_held``: what full
+        attention would have read), compressed rows scored, queries
+        that chose, and state updates (live rows x linear layers a
+        call); ``by_program`` the same for chunks and ticks apart."""
+        if not (self.mixed and self.cfg.state_layers):
+            return None
+        return self._counter_stats()
 
     def release(self, slot: int) -> None:
         self._active[slot] = 0
@@ -1170,6 +1246,7 @@ class InferenceEngine:
         self._topk[slot] = 0
         self._topp[slot] = 1.0
         self._prefills[slot] = None
+        self._logit_slots.discard(slot)
         if self._spec_ok[slot]:
             self.speculator.release(slot)
         self._spec_ok[slot] = False
@@ -1206,6 +1283,11 @@ class InferenceEngine:
         device-side and transfers those, never the slot's whole
         allocation. Read-only: the slot stays live (release is the
         scheduler's call, after the export is in hand)."""
+        if self.mixed and self.cfg.state_layers:
+            raise ValueError(
+                "export_kv is not carried over to a stack with linear-attention "
+                "layers: the wire format ships K and V blocks, and a per-slot "
+                "state and the per-slot compressed keys are neither")
         if self.mixed:
             raise ValueError(
                 "export_kv is not carried over to a mixed layer stack: a "
@@ -1286,6 +1368,11 @@ class InferenceEngine:
         have run. The prefix cache is NOT populated from shipped rows
         (a requantized payload would hand non-parity rows to unrelated
         local requests)."""
+        if self.mixed and self.cfg.state_layers:
+            raise ValueError(
+                "import_kv is not carried over to a stack with linear-attention "
+                "layers: the wire format ships K and V blocks, and a per-slot "
+                "state and the per-slot compressed keys are neither")
         if self.mixed:
             raise ValueError(
                 "import_kv is not carried over to a mixed layer stack: a "
@@ -1459,7 +1546,9 @@ class InferenceEngine:
             extra = {
                 "kv_bytes": int(sum(by_kind.values())),
                 "kv_bytes_by_kind": {k: int(v) for k, v in by_kind.items()},
-                "layers_by_kind": {k: kinds.count(k) for k in by_kind},
+                # the compressed keys are the sparse layers' second cache
+                "layers_by_kind": {k: kinds.count(
+                    "sparse_attention" if k == "compressed_keys" else k) for k in by_kind},
                 "ring_rows_per_slot": self.ring_rows,
             }
         else:
@@ -1560,6 +1649,9 @@ class InferenceEngine:
         ``compile_counts`` key carries."""
         if self.kv_dtype == "int8":
             base = "paged-int8"
+        elif self.mixed and self.cfg.state_layers:
+            # sparse layers paged with compressed keys, linear layers' states
+            base = "paged-compressed-state"
         elif self.mixed:
             base = "paged-rings"  # full layers paged, sliding layers in rings
         else:

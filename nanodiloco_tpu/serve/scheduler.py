@@ -1310,6 +1310,12 @@ class Scheduler:
             moe = moe_stats()
             if moe is not None:
                 out["moe"] = moe
+        # the sparse and linear attention layers' counters
+        attn_stats = getattr(self.backend, "attn_stats", None)
+        if attn_stats is not None:
+            attn = attn_stats()
+            if attn is not None:
+                out["attn"] = attn
         # KV ship traffic (export/import requests, bytes, blocks,
         # seconds) — present only once a replica has actually shipped,
         # so non-disagg stats JSONLs are unchanged

@@ -968,6 +968,22 @@ class ServeServer:
                     "tick (accepted prefix + the verified bonus token)",
                     hist,
                 ))
+        # sparse and linear attention layers: what the selection saved
+        # (rows read against rows held) and the states' updates
+        attn = s.get("attn")
+        if attn is not None:
+            for name, doc in (
+                ("sparse_rows_read", "K/V rows of the blocks sparse layers' queries chose"),
+                ("sparse_rows_held", "K/V rows those queries' streams held (full attention's read)"),
+                ("sparse_compressed_rows", "compressed keys the sparse layers' selectors scored"),
+                ("sparse_queries", "queries of sparse layers past the dense length"),
+                ("state_updates", "linear-attention state updates (live rows x layers a call)"),
+            ):
+                families.append((
+                    f"nanodiloco_attn_{name}", "counter", doc,
+                    [({"program": kind}, c[name])
+                     for kind, c in sorted(attn["by_program"].items())],
+                ))
         # shared-prefix KV cache: the counters that tell an operator
         # whether the system-prompt traffic is actually being reused
         pc = s.get("prefix_cache")

@@ -249,6 +249,55 @@ def test_paged_decode_tick_compiles_at_8b_widths(serve_8b_abstract):
     assert _live_bytes(compiled) < V5E_HBM_BYTES
 
 
+# (b') the three-cache serve programs at MiniCPM-SALA's widths ---------------
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_sparse_and_linear_layers_compile_at_minicpm_sala_widths(one_chip, program):
+    """One block-sparse and one linear attention layer at the published
+    widths (hidden 4096, 32 heads over 2 KV heads of 128, FFN 16384, the
+    published sparse sizes) over 8 slots of 16,384 rows: the tick's
+    gather of the chosen blocks, the chunk's masked choice, the state's
+    update. The tick's temporaries stay under a gigabyte: rows within
+    the dense length read the first rung of the ladder, not the table's
+    whole width (at the benchmark's size that fault was 2.6 GB)."""
+    from nanodiloco_tpu.models import init_params
+    from nanodiloco_tpu.models.generate import (
+        decode_slots_mixed_fn,
+        init_mixed_serve_cache,
+        prefill_chunk_mixed_fn,
+    )
+
+    cfg = LlamaConfig(
+        vocab_size=73448, hidden_size=4096, intermediate_size=16384, num_hidden_layers=2,
+        num_attention_heads=32, num_key_value_heads=2, explicit_head_dim=128, qk_norm=True,
+        layer_types=("sparse_attention", "linear_attention"), rope_layers="linear",
+        attn_output_gate=True, linear_output_gate=True, linear_output_norm=True,
+        scale_emb=12.0, scale_depth=1.4, dim_model_base=256, published_layers=32,
+        first_layer_index=9, rms_norm_eps=1e-6, dtype="bfloat16", param_dtype="bfloat16")
+    slots, max_len, chunk, block = 8, 16384, 512, 16
+    table_blocks = max_len // block + chunk // block
+    params = _abstract(jax.eval_shape(lambda: init_params(jax.random.key(0), cfg)), one_chip)
+    cache = _abstract(jax.eval_shape(lambda: init_mixed_serve_cache(
+        cfg, slots, chunk, slots * (max_len // block), block, table_blocks)), one_chip)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32, u32 = np.int32, np.float32, np.uint32
+    if program == "tick":
+        compiled = decode_slots_mixed_fn(cfg).lower(
+            params, cache, arr(i32, slots, table_blocks), arr(i32, slots), arr(i32, slots),
+            arr(u32, slots, 2), arr(f32, slots), arr(i32, slots), arr(f32, slots),
+            arr(i32, slots)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    else:
+        compiled = prefill_chunk_mixed_fn(cfg).lower(
+            params, cache, arr(i32, table_blocks), arr(i32), arr(i32, 1, chunk),
+            arr(i32, 1, chunk), arr(i32), arr(i32), arr(u32, 2), arr(f32), arr(i32),
+            arr(f32)).compile()
+    assert _live_bytes(compiled) < V5E_HBM_BYTES
+
+
 # (c) one DiLoCo inner step at those widths over the four chips --------------
 
 def _abstract_diloco_state(dl, mesh):
