@@ -97,7 +97,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="dense (the default) honors attention padding masks "
                         "and picks its implementation from the shapes: a "
                         "fused kernel on a one-device TPU program with heads "
-                        "of 128 and a sequence in tiles of 1024, query "
+                        "of 64 or 128 and a sequence in tiles of 1024, query "
                         "blocks elsewhere; flash/ring are packed-sequence "
                         "kernels that ignore the masks (fine for packed "
                         "data and tail-only padding)")

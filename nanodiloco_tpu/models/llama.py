@@ -435,7 +435,7 @@ def dense_attention(
     mask (causal over the valid keys) or an explicit additive [B?, 1, S, S]
     mask, taken as given. Every block's scores are an array in HBM: the
     path of every shape ``fused_attention_applies`` does not send to the
-    kernel (heads not of 128, short or ragged sequences, an explicit
+    kernel (heads neither of 64 nor of 128, short or ragged sequences, an explicit
     mask, a partitioned mesh, any backend but a TPU), and the statement
     of the mathematics the kernel is tested against.
 
@@ -493,17 +493,17 @@ def fused_attention_applies(
     (``attention_impl="dense"``) implementation to the fused kernel and
     not to ``dense_attention``'s blocks: one algorithm whose best
     implementation depends on what the call can observe. The kernel
-    where the backend is a TPU, the heads are whole lanes of 128, the
-    mask is none (``mask_ndim`` None) or a [B, S] validity array (an
-    explicit [B?, 1, S, S] mask is taken as given, which only dense
-    blocks can), no sequence-parallel axis is bound, the ambient mesh is
-    one device or wholly manual (``partitioned`` false: Mosaic refuses a
-    kernel inside an automatically partitioned program), and the
-    sequence is whole tiles of 1,024: one tile is the least length the
-    chip sweep measured, and the kernel was 2.1 to 4.6 times ahead of
-    the blocks at every length up to 8,192 (PERF.md section 6, PR 33)."""
-    if (platform != "tpu" or head_dim % 128 or mask_ndim not in (None, 2)
-            or sp_axis or partitioned):
+    where the backend is a TPU, the heads are 64 or whole lanes of 128
+    (the sizes a chip sweep measured), the mask is none (``mask_ndim``
+    None) or a [B, S] validity array (an explicit [B?, 1, S, S] mask is
+    taken as given, which only dense blocks can), no sequence-parallel
+    axis is bound, the ambient mesh is one device or wholly manual
+    (``partitioned`` false: Mosaic refuses a kernel inside an automatically
+    partitioned program), and the sequence is whole tiles of 1,024: the
+    kernel was ahead at every length the sweeps measured, 1,024 to 8,192
+    (PERF.md section 6: 2.1-4.6 times at heads of 128, 1.2-2.7 at heads of 64)."""
+    if (platform != "tpu" or not (head_dim == 64 or head_dim % 128 == 0)
+            or mask_ndim not in (None, 2) or sp_axis or partitioned):
         return False
     # the kernels' module loads Pallas: only where a kernel can run
     from nanodiloco_tpu.ops.splash_attention import whole_tiles
@@ -543,8 +543,8 @@ def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None,
     default, names the mathematics (causal over the valid keys, a window
     where the layer has one, every row's softmax whole in float32) and
     the program picks its implementation from the call
-    (``fused_attention_applies``): on a TPU, heads of 128 over a long
-    sequence in whole tiles run the fused kernel forward and backward
+    (``fused_attention_applies``): on a TPU, heads of 64 or 128 over a
+    long sequence in whole tiles run the fused kernel forward and backward
     (ops/splash_attention.py: no score reaches HBM, K and V stay at Hkv
     heads); every other shape, platform and mesh runs ``dense_attention``'s
     query blocks, for which K and V are expanded to the query heads

@@ -7,7 +7,7 @@ dk/dv Mosaic kernels behind a custom VJP) under the repo's layout and
 semantics. Tiles of keys wholly outside the mask are never visited, K
 and V stay at their own head count, and a ``[B, S]`` validity array
 reaches the kernel as the segment ids of rows and keys. ``models/llama.py:_attention``
-decides which calls come here (``fused_attention_applies``).
+decides which calls come here (heads of 64 or 128: ``fused_attention_applies``).
 """
 
 from __future__ import annotations
@@ -91,7 +91,7 @@ def splash_attention(
     tiles follow from S."""
     s, h, hd = q.shape[1:]
     kernel = _kernel(s, h, window, interpret, sizes or block_sizes(s))
-    # the library applies no scale: on q, one more rounding of q in bf16
+    # the library applies no scale: on q (one more bf16 rounding; exact at heads of 64)
     q = q * jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
     q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     ids = None

@@ -729,7 +729,8 @@ def test_attention_paths_describe_the_round(monkeypatch, platform, workers, want
     """``Diloco.attention_paths``: the count of layers whose attention
     takes the fused kernel and dense blocks in this object's programs,
     from the platform, the model's head size, the row length and the
-    mesh (heads of 128 here; TINY's heads are narrower and stay dense)."""
+    mesh: heads of 128 and of 64 (SmolLM2's) alike, heads of 32 dense
+    everywhere."""
     import dataclasses
 
     from nanodiloco_tpu.ops.splash_attention import TILE
@@ -742,9 +743,10 @@ def test_attention_paths_describe_the_round(monkeypatch, platform, workers, want
     dl = Diloco(wide, DilocoConfig(num_workers=workers), mesh)
     assert dl.attention_paths(2 * TILE) == want
     assert dl.attention_paths(TILE // 2)["fused"] == 0
-    narrow = Diloco(dataclasses.replace(wide, num_attention_heads=4), DilocoConfig(
-        num_workers=workers), mesh)
-    assert narrow.attention_paths(2 * TILE) == {"fused": 0, "dense": 2}
+    for heads, paths in ((4, want), (8, {"fused": 0, "dense": 2})):
+        narrow = Diloco(dataclasses.replace(wide, num_attention_heads=heads), DilocoConfig(
+            num_workers=workers), mesh)
+        assert narrow.attention_paths(2 * TILE) == paths
 
 
 def test_sync_payload_report_accounting():

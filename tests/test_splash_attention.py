@@ -15,7 +15,11 @@ from nanodiloco_tpu.models.llama import (
 )
 from nanodiloco_tpu.ops.splash_attention import TILE, block_sizes, splash_attention, whole_tiles
 
-B, S, H, HKV, HD = 2, 512, 4, 2, 128   # grouped heads of 128, 32-over-4 style
+B, S = 2, 512
+# (query heads, KV heads, head size): grouped heads of 128, 32-over-4
+# style, and heads of 64 in SmolLM2's groups of 3 (15 over 5)
+H, HKV, HD = 4, 2, 128
+HEADS = [pytest.param((H, HKV, HD), id="hd128"), pytest.param((6, 2, 64), id="hd64_gqa3")]
 PAD = 37                               # the first sequence is left-padded by this
 WINDOW = 200
 # tiles of 128 so that 512 rows are 4 x 4 of them: skipped, partial and full
@@ -24,12 +28,13 @@ SMALL = splash.BlockSizes(
     block_kv_dkv_compute=128, block_q_dq=128, block_kv_dq=128)
 
 
-def _inputs(dtype=jnp.float32):
+def _inputs(dtype=jnp.float32, heads=(H, HKV, HD)):
+    h, hkv, hd = heads
     ks = jax.random.split(jax.random.key(33), 4)
-    q = jax.random.normal(ks[0], (B, S, H, HD), dtype)
-    k = jax.random.normal(ks[1], (B, S, HKV, HD), dtype)
-    v = jax.random.normal(ks[2], (B, S, HKV, HD), dtype)
-    w = jax.random.normal(ks[3], (B, S, H, HD), jnp.float32)
+    q = jax.random.normal(ks[0], (B, S, h, hd), dtype)
+    k = jax.random.normal(ks[1], (B, S, hkv, hd), dtype)
+    v = jax.random.normal(ks[2], (B, S, hkv, hd), dtype)
+    w = jax.random.normal(ks[3], (B, S, h, hd), jnp.float32)
     return q, k, v, w
 
 
@@ -47,7 +52,7 @@ def _losses(valid, window, w):
         return jnp.sum(out.astype(jnp.float32) * w * rows)
 
     def dense(q, k, v):
-        g = H // HKV
+        g = q.shape[2] // k.shape[2]
         out = dense_attention(q, jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2),
                               valid, window=window)
         return jnp.sum(out.astype(jnp.float32) * w * rows)
@@ -55,10 +60,11 @@ def _losses(valid, window, w):
     return fused, dense
 
 
+@pytest.mark.parametrize("heads", HEADS)
 @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "left_padded"])
 @pytest.mark.parametrize("window", [None, WINDOW], ids=["full", "window"])
-def test_fused_kernel_matches_dense_forward_and_gradients(window, masked):
-    q, k, v, w = _inputs()
+def test_fused_kernel_matches_dense_forward_and_gradients(window, masked, heads):
+    q, k, v, w = _inputs(heads=heads)
     fused, dense = _losses(_valid() if masked else None, window, w)
     with jax.default_matmul_precision("highest"):
         lf, gf = jax.jit(jax.value_and_grad(fused, (0, 1, 2)))(q, k, v)
@@ -68,9 +74,10 @@ def test_fused_kernel_matches_dense_forward_and_gradients(window, masked):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("heads", HEADS)
 @pytest.mark.parametrize("window", [None, WINDOW], ids=["full", "window"])
-def test_a_padding_row_is_finite_and_no_gradient_reaches_a_masked_key(window):
-    q, k, v, w = _inputs()
+def test_a_padding_row_is_finite_and_no_gradient_reaches_a_masked_key(window, heads):
+    q, k, v, w = _inputs(heads=heads)
     valid = _valid()
     out = splash_attention(q, k, v, valid, window=window, interpret=True, sizes=SMALL)
     assert bool(jnp.isfinite(out).all())  # rows 0..PAD-1 of sequence 0 see no real key
@@ -150,7 +157,12 @@ RULE = [
     pytest.param(("tpu", 8192, 128, 2, False, False), True, id="mellum_on_a_tpu"),
     pytest.param(("tpu", 8192, 128, None, False, False), True, id="mellum_no_mask"),
     pytest.param(("tpu", TILE, 128, 2, False, False), True, id="one_tile"),
-    pytest.param(("tpu", 2048, 64, 2, False, False), False, id="smollm2_heads_of_64"),
+    pytest.param(("tpu", 2048, 64, 2, False, False), True, id="smollm2_heads_of_64"),
+    pytest.param(("tpu", TILE, 64, None, False, False), True, id="heads_of_64_one_tile"),
+    pytest.param(("tpu", 2048, 96, 2, False, False), False, id="heads_of_96"),
+    pytest.param(("tpu", 2048, 32, 2, False, False), False, id="heads_of_32"),
+    pytest.param(("tpu", 2048, 192, 2, False, False), False, id="heads_of_192"),
+    pytest.param(("tpu", 2048, 64, 2, False, True), False, id="heads_of_64_partitioned_mesh"),
     pytest.param(("tpu", 8192, 128, 4, False, False), False, id="explicit_4d_mask"),
     pytest.param(("tpu", 8192 + 128, 128, 2, False, False), False, id="no_whole_tiles"),
     pytest.param(("tpu", TILE // 2, 128, 2, False, False), False, id="under_a_tile"),
@@ -177,7 +189,7 @@ SMOLLM = LlamaConfig(hidden_size=128, num_attention_heads=2, num_hidden_layers=3
 @pytest.mark.parametrize("platform,cfg,s,want", [
     ("tpu", MELLUM, 8192, {"fused": 4, "dense": 0}),
     ("cpu", MELLUM, 8192, {"fused": 0, "dense": 4}),
-    ("tpu", SMOLLM, 2048, {"fused": 0, "dense": 3}),
+    ("tpu", SMOLLM, 2048, {"fused": 3, "dense": 0}),
 ], ids=["mellum_tpu", "mellum_cpu", "heads_of_64_tpu"])
 def test_the_count_of_layers_on_each_path(monkeypatch, platform, cfg, s, want):
     monkeypatch.setattr(jax, "default_backend", lambda: platform)

@@ -147,21 +147,26 @@ def test_blocked_dense_attention_halves_the_compiled_work(one_chip):
 
 # (a'') the fused kernel behind _attention, at the benchmark's shape -----------
 
-@pytest.mark.parametrize("window", [None, 1024], ids=["full_layer", "window_layer"])
-def test_attention_at_mellum_shapes_compiles_to_three_kernels_and_no_scores(
-        one_chip, monkeypatch, window):
+@pytest.mark.parametrize("b,s,h,hkv,hd,window", [
+    pytest.param(2, 8192, 32, 4, 128, None, id="mellum_full_layer"),
+    pytest.param(2, 8192, 32, 4, 128, 1024, id="mellum_window_layer"),
+    pytest.param(8, 2048, 15, 5, 64, None, id="smollm2"),
+])
+def test_attention_at_benchmark_shapes_compiles_to_three_kernels_and_no_scores(
+        one_chip, monkeypatch, b, s, h, hkv, hd, window):
     """``_attention`` at the shapes of ``mellum2-12b.train.seq8k`` (2 x
-    8,192, 32 heads over 4 of 128, bf16, a [B, S] validity array, value
-    and gradient) where it sees a TPU (this test steers the one question
-    the rule asks of the backend): the program holds three Mosaic calls
-    (forward, dq, dk/dv), and no array with a row of S scores, which is
-    what ``dense_attention``'s blocks make (bf16[2,32,512,8192] and
-    f32[...] for the full layer, [2,32,512,1535] for a window block)."""
+    8,192, 32 heads over 4 of 128) and of ``smollm2-360m.train.1chip``
+    (8 x 2,048, 15 heads over 5 of 64), bf16, a [B, S] validity array,
+    value and gradient, where it sees a TPU (this test steers the one
+    question the rule asks of the backend): the program holds three
+    Mosaic calls (forward, dq, dk/dv), and no block of score rows, which
+    is what ``dense_attention``'s blocks make ([2,32,512,8192] for
+    Mellum's full layer, [2,32,512,1535] for a window block,
+    [8,15,256,2048] for SmolLM2's last block)."""
     import re
 
-    from nanodiloco_tpu.models.llama import _attention
+    from nanodiloco_tpu.models.llama import _attention, dense_block_rows
 
-    b, s, h, hkv, hd = 2, 8192, 32, 4, 128
     cfg = LlamaConfig(hidden_size=h * hd, num_attention_heads=h, num_key_value_heads=hkv)
     assert cfg.attention_impl == "dense" and cfg.head_dim == hd
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -176,11 +181,12 @@ def test_attention_at_mellum_shapes_compiles_to_three_kernels_and_no_scores(
         q, kv, kv, valid).compile()
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 3
-    # every array of the program: none has rows x keys of scores as its
-    # last two dimensions (a dense block of 512 rows meets 1,535 keys or more)
+    # every array of the program: none has a dense block's rows of scores
+    # as its last two dimensions (a block meets at least its own rows' keys)
+    rows = dense_block_rows(s)
     shapes = {tuple(map(int, dims.split(","))) for dims in re.findall(
         r"(?:bf16|f32)\[([0-9,]+)\]", text)}
-    scores = [d for d in shapes if len(d) >= 2 and d[-2] >= 512 and d[-1] >= 1535]
+    scores = [d for d in shapes if len(d) >= 2 and d[-2] == rows and d[-1] >= rows]
     assert not scores, scores
     assert _live_bytes(compiled) < 2 * 1024**3
 
