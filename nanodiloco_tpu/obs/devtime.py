@@ -115,19 +115,6 @@ class DispatchAccountant:
         with self._lock:
             return sum(self._device_s.values())
 
-    def reset(self) -> None:
-        """Zero every ledger AND the first-dispatch memory — warm-up
-        traffic (``Engine.warm_spec``, bench warm legs) must not leak
-        into measured windows, the same contract as
-        ``reset_spec_stats``. Compile state resets too: a post-reset
-        first dispatch of a key is warm in reality (the executable is
-        cached), so callers that want compile seconds kept should
-        snapshot before resetting."""
-        with self._lock:
-            self._device_s.clear()
-            self._compile_s.clear()
-            self._dispatches.clear()
-
     def reset_device_seconds(self) -> None:
         """Zero the warm-dispatch ledger but KEEP compile seconds and
         the first-dispatch memory: warmup traffic (``warm_spec``'s

@@ -34,6 +34,10 @@ timeline as the device's operations. Each annotation carries a
 ``data``): what tells a span of this program from the runtime's own
 events. With no capture running a ``TraceMe`` costs a flag check. JAX
 is imported at the first span, never at import of this module.
+
+A thread may also install a ``HostLedger`` (the serving loop does, for
+its own thread): ``trace_span`` then adds each span's count and own
+host seconds to it, by name, with no profiler and no tracer running.
 """
 
 from __future__ import annotations
@@ -452,6 +456,62 @@ def current_tracer() -> SpanTracer:
 _TraceAnnotation = None  # jax.profiler.TraceAnnotation, from the first span on
 
 
+class HostLedger:
+    """Host seconds by span name on ONE thread, always on: every
+    ``trace_span`` the thread enters while this ledger is installed
+    (``install_ledger``) adds its count, its whole duration and its own
+    time (the duration less its children's) under its name. Over a tree
+    of spans the own times add up to the root's duration, so a serving
+    tick's host time is split by what the host was doing with no
+    profiler running. The thread that installed it writes; any thread
+    may take a ``snapshot()``."""
+
+    __slots__ = ("_open", "_rows", "_lock")
+
+    def __init__(self) -> None:
+        self._open: list[list[float]] = []   # [start, children's seconds]
+        self._rows: dict[str, list] = {}     # name -> [count, total, own]
+        self._lock = threading.Lock()
+
+    def _enter(self) -> None:
+        self._open.append([time.perf_counter(), 0.0])
+
+    def _exit(self, name: str) -> None:
+        t1 = time.perf_counter()
+        t0, children = self._open.pop()
+        dur = t1 - t0
+        if self._open:
+            self._open[-1][1] += dur
+        with self._lock:
+            row = self._rows.get(name)
+            if row is None:
+                self._rows[name] = [1, dur, dur - children]
+            else:
+                row[0] += 1
+                row[1] += dur
+                row[2] += dur - children
+
+    def snapshot(self) -> dict[str, dict[str, float]]:
+        """``{span name: {"count", "total_s", "self_s"}}`` since the
+        ledger was made; cumulative, so a window's share is the
+        difference of two snapshots."""
+        with self._lock:
+            return {name: {"count": c, "total_s": t, "self_s": s}
+                    for name, (c, t, s) in sorted(self._rows.items())}
+
+
+_ledgers = threading.local()
+
+
+def install_ledger(ledger: HostLedger | None) -> HostLedger | None:
+    """Make ``ledger`` the CALLING thread's host ledger (None: none).
+    Returns the thread's previous one, for the caller to restore. No
+    other thread's spans reach it."""
+    prev = getattr(_ledgers, "ledger", None)
+    _ledgers.ledger = ledger
+    return prev
+
+
 class trace_span:
     """``with trace_span("sched.admit"):`` — record on the current
     tracer AND as a ``jax.profiler.TraceAnnotation`` of that name. The
@@ -460,10 +520,11 @@ class trace_span:
     ``ckpt``); ``args`` (a request's ``rid``, a ``slot``) ride on both.
     Spans nest on a thread in both. The tracer indirection is resolved
     at ENTRY so an install/restore race mid-span still closes the span
-    on the tracer that opened it. A class and not a generator: a dozen
-    of these run in every serving tick."""
+    on the tracer that opened it; the thread's ``HostLedger``, where one
+    is installed, times the span around both. A class and not a
+    generator: a dozen of these run in every serving tick."""
 
-    __slots__ = ("_annotation", "_span")
+    __slots__ = ("_annotation", "_span", "_name", "_ledger")
 
     def __init__(self, name: str, layer: str | None = None, **args: Any) -> None:
         global _TraceAnnotation
@@ -472,8 +533,12 @@ class trace_span:
         self._annotation = _TraceAnnotation(
             name, layer=layer or name.partition(".")[0], **args)
         self._span = _current.span(name, **args)
+        self._name = name
 
     def __enter__(self):
+        ledger = self._ledger = getattr(_ledgers, "ledger", None)
+        if ledger is not None:
+            ledger._enter()
         self._annotation.__enter__()
         return self._span.__enter__()
 
@@ -482,6 +547,8 @@ class trace_span:
             return self._span.__exit__(*exc)
         finally:
             self._annotation.__exit__(*exc)
+            if self._ledger is not None:
+                self._ledger._exit(self._name)
 
 
 def trace_shard_path(path: str, process_index: int) -> str:

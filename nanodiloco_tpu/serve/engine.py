@@ -738,6 +738,7 @@ class InferenceEngine:
                 self._jarr(temp, np.float32), self._jarr(top_k, np.int32),
                 self._jarr(top_p, np.float32),
             )
+        counts = None
         with self.accountant.section("prefill_chunk", len(chunk),
                                      self.kv_layout), \
                 trace_span("engine.prefill_chunk", slot=slot,
@@ -747,11 +748,6 @@ class InferenceEngine:
                     params, self.pool, self._jarr(self._tables[slot]),
                     self._jarr(slot, np.int32), *args,
                 )
-                self.moe_counts["prefill_chunk"] += np.asarray(counts, np.int64)
-                if self.capture_routing:
-                    real = int(np.asarray(valid).sum())
-                    self.routing_log.setdefault(slot, []).append(
-                        np.asarray(chosen)[:, 0, :real])
             else:
                 tok, logits, self.pool = self._chunk_paged(
                     params, self.pool,
@@ -761,8 +757,20 @@ class InferenceEngine:
             # consumer (the final chunk's int(tok) is the only natural
             # sync), and an unfenced async dispatch would be timed as
             # free. One output suffices — the chunk is one executable,
-            # its buffers materialize together.
-            jax.block_until_ready(tok)
+            # its buffers materialize together. The counters' copy is
+            # asked for before the wait, so that it queues behind the
+            # program and does not start at the host's notice of its end
+            if counts is not None:
+                counts.copy_to_host_async()
+            with trace_span("engine.wait"):
+                jax.block_until_ready(tok)
+            if counts is not None:
+                with trace_span("engine.fetch_counts"):
+                    self.moe_counts["prefill_chunk"] += np.asarray(counts, np.int64)
+                    if self.capture_routing:
+                        real = int(np.asarray(valid).sum())
+                        self.routing_log.setdefault(slot, []).append(
+                            np.asarray(chosen)[:, 0, :real])
         return tok, logits
 
     def prefill_step(self, slot: int) -> int | None:
@@ -1006,8 +1014,6 @@ class InferenceEngine:
                             tokens, pos, keys,
                             dev["temp"], dev["topk"], dev["topp"], active,
                         )
-                        for s in self._logit_slots:
-                            self.decode_logits_log[s].append(np.asarray(logits[0][s]))
                     else:
                         nxt, self.pool = self._decode_paged(
                             params, self.pool, dev["tables"],
@@ -1016,16 +1022,26 @@ class InferenceEngine:
                         )
                 # the host fetch below is the tick's natural fence;
                 # inside the section so the measured seconds cover the
-                # program, not just its dispatch
+                # program, not just its dispatch. ``engine.wait`` holds
+                # the program's run and the host's notice of its end; the
+                # tokens' copy is asked for first, so that it queues
+                # behind the program and does not start at that notice
                 with trace_span("engine.fetch_tokens"):
+                    nxt.copy_to_host_async()
+                    with trace_span("engine.wait"):
+                        jax.block_until_ready(nxt)
                     nxt = np.asarray(nxt)
                     if counts is not None:
-                        self.moe_counts["decode"] += np.asarray(counts, np.int64)
-                        if self.capture_routing:
-                            chosen = np.asarray(chosen)
-                            for s in slots:
-                                self.routing_log.setdefault(s, []).append(
-                                    chosen[:, s])
+                        with trace_span("engine.fetch_counts"):
+                            self.moe_counts["decode"] += np.asarray(counts, np.int64)
+                            if self.capture_routing:
+                                chosen = np.asarray(chosen)
+                                for s in slots:
+                                    self.routing_log.setdefault(s, []).append(
+                                        chosen[:, s])
+                            for s in self._logit_slots:
+                                self.decode_logits_log[s].append(
+                                    np.asarray(logits[0][s]))
             with trace_span("engine.advance"):
                 for s in slots:
                     self._pos[s] += 1
@@ -1085,6 +1101,9 @@ class InferenceEngine:
                         dev["temp"], dev["topk"], dev["topp"], active,
                     )
                 with trace_span("engine.fetch_tokens"):
+                    sampled.copy_to_host_async()
+                    with trace_span("engine.wait"):
+                        jax.block_until_ready(sampled)
                     sampled = np.asarray(sampled)
                     counts = np.asarray(counts)
             with trace_span("engine.advance"):

@@ -73,7 +73,7 @@ import numpy as np
 
 from nanodiloco_tpu.obs import flightrec
 from nanodiloco_tpu.obs.telemetry import Histogram, nearest_rank_percentile
-from nanodiloco_tpu.obs.tracer import TraceContext, trace_span
+from nanodiloco_tpu.obs.tracer import HostLedger, TraceContext, trace_span
 from nanodiloco_tpu.serve.block_pool import BlocksExhausted
 
 
@@ -394,6 +394,9 @@ class Scheduler:
         self.hist_queue_wait = Histogram()
         self.hist_decode_tick = Histogram()
         self.hist_queue_wait_by_priority: dict[int, Histogram] = {}
+        # the tick thread's own host seconds by span (obs/tracer), fed
+        # once the serving loop installs it on that thread
+        self.host_ledger = HostLedger()
 
     # -- submission (any thread) --------------------------------------------
 
@@ -1332,4 +1335,9 @@ class Scheduler:
             dt = devtime_stats()
             if dt is not None:
                 out["devtime"] = dt
+        # the tick thread's host seconds by span: count, whole and own
+        # seconds (the span less its children), cumulative; over a
+        # window, the difference of two snapshots divided by the count
+        # of ``engine.decode_dispatch`` is a tick's host time by span
+        out["host"] = self.host_ledger.snapshot()
         return out

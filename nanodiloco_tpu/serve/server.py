@@ -72,7 +72,7 @@ from nanodiloco_tpu.obs.telemetry import (
     handle_profile_request,
     render_exposition,
 )
-from nanodiloco_tpu.obs.tracer import TraceContext, trace_span
+from nanodiloco_tpu.obs.tracer import TraceContext, install_ledger, trace_span
 from nanodiloco_tpu.serve import kvship
 from nanodiloco_tpu.serve.scheduler import (
     ClassShed,
@@ -261,7 +261,10 @@ class ServeServer:
 
     def _loop(self) -> None:
         """The engine's single driver thread: tick until stopped; idle
-        politely when no slot is live and the queue is empty."""
+        politely when no slot is live and the queue is empty. The
+        scheduler's host ledger is installed on this thread alone, so
+        every span of a tick adds its own seconds to it."""
+        install_ledger(getattr(self._scheduler, "host_ledger", None))
         while not self._stop.is_set():
             if self._tick_delay_s > 0:
                 time.sleep(self._tick_delay_s)
@@ -1104,4 +1107,17 @@ class ServeServer:
         # one family definition (obs/devtime) shared with the trainer's
         # telemetry endpoint so the exposition cannot drift
         families.extend(devtime_families(s.get("devtime")))
+        # the tick thread's own host seconds by span (obs/tracer's
+        # HostLedger): the label set is the program's fixed span names
+        host = s.get("host") or {}
+        if host:
+            families.append((
+                "nanodiloco_serve_host_seconds", "counter",
+                "the serving loop thread's host seconds by span, each "
+                "span's own time less its children's (launch: "
+                "engine.decode_dispatch; the chip's wait: engine.wait; "
+                "copies back: engine.fetch_tokens, engine.fetch_counts)",
+                [({"span": name}, round(row["self_s"], 6))
+                 for name, row in host.items()],
+            ))
         return render_exposition(families)

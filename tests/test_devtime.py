@@ -85,12 +85,6 @@ def test_reset_device_seconds_keeps_compile_state():
     snap = acct.snapshot()
     assert snap["compile_seconds_by_program"] == {"verify:4:paged": 3.0}
     assert snap["device_seconds_by_program"] == {"verify:4:paged": 0.2}
-    # full reset drops everything including the memory
-    acct.reset()
-    acct.record("verify", 4, "paged", 1.0)
-    assert acct.snapshot()["compile_seconds_by_program"] == {
-        "verify:4:paged": 1.0
-    }
 
 
 def test_accountant_concurrent_records_lose_nothing():

@@ -36,6 +36,7 @@ LABEL_ALLOWLIST = {
     "outcome", "reason", "result", "priority", "shard", "worker",
     "target", "kind", "op", "cause", "phase", "event", "state",
     "replica", "rule", "program", "tier", "direction", "role",
+    "span",  # the serving tick's span names: SPAN_NAME_ALLOWLIST below
     "le",  # histogram bucket bound (rendered by the exposition layer)
 }
 
@@ -239,6 +240,7 @@ SPAN_NAME_ALLOWLIST = {
     "engine.start_prefill", "engine.keys", "engine.stage_chunk",
     "engine.prefill_chunk", "engine.draft", "engine.stage",
     "engine.decode_dispatch", "engine.fetch_tokens", "engine.advance",
+    "engine.wait", "engine.fetch_counts",
     "diloco.round", "diloco.outer", "diloco.inner_round",
     # the synthetic root stitch_trace mints for request_id-joined shards
     "trace",

@@ -8,8 +8,10 @@ import ast
 import glob
 import os
 import re
+import sys
 import threading
 import time
+import urllib.request
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +25,13 @@ from nanodiloco_tpu.models.generate import (
     prefill_chunk_paged_fn,
 )
 from nanodiloco_tpu.obs import telemetry, tracer
-from nanodiloco_tpu.obs.tracer import SpanTracer, set_tracer, trace_span
+from nanodiloco_tpu.obs.tracer import (
+    HostLedger,
+    SpanTracer,
+    install_ledger,
+    set_tracer,
+    trace_span,
+)
 from nanodiloco_tpu.serve import (
     GenRequest,
     InferenceEngine,
@@ -273,10 +281,14 @@ def test_one_engine_tick_shows_its_spans_in_order_under_one_tick(params, tmp_pat
         "sched.tick", "sched.control", "sched.expire",
         "sched.admit", "engine.start_prefill",
         "sched.prefill", "engine.keys", "engine.stage_chunk",
-        "engine.prefill_chunk", "engine.keys",
+        "engine.prefill_chunk", "engine.wait", "engine.keys",
         "engine.stage", "engine.decode_dispatch", "engine.fetch_tokens",
-        "engine.advance", "sched.deliver", "sched.retire"]
+        "engine.wait", "engine.advance", "sched.deliver", "sched.retire"]
+    waits = [s for s in spans if s[3] == "engine.wait"]
     by_name = {s[3]: s for s in spans}
+    # the chip's wait inside the chunk's span, then inside the tick's fetch
+    for wait, parent in zip(waits, ("engine.prefill_chunk", "engine.fetch_tokens")):
+        assert by_name[parent][1] <= wait[1] and wait[2] <= by_name[parent][2]
     assert by_name["engine.start_prefill"][4]["rid"] == second.result["request_id"]
     assert by_name["sched.retire"][4]["rid"] == second.result["request_id"]
     assert {s[4]["layer"] for s in spans} == {"sched", "engine"}
@@ -307,6 +319,193 @@ def test_server_timing_carries_token_s(params):
     assert len(stamps) == 6 and stamps == sorted(stamps)
     assert abs(stamps[0] - out["timing"]["ttft_s"]) < 1e-5  # rounded to 10 us
     assert stamps[-1] <= out["timing"]["total_s"] + 1e-5
+
+
+# -- the serving thread's host ledger ---------------------------------------
+
+
+def _inside(child, parent):
+    """Whether tracer event ``child`` lies in ``parent``, one level down,
+    on its thread."""
+    return (child["tid"] == parent["tid"] and child["depth"] == parent["depth"] + 1
+            and parent["t0"] <= child["t0"]
+            and child["t0"] + child["dur"] <= parent["t0"] + parent["dur"])
+
+
+def _parents(events, name):
+    """The names of the spans each ``name`` event lies directly in."""
+    return [next((p["name"] for p in events if _inside(e, p)), None)
+            for e in events if e["name"] == name]
+
+
+def test_host_ledger_keeps_each_spans_own_time_on_its_thread_alone():
+    ledger = HostLedger()
+    prev = install_ledger(ledger)
+    try:
+        with trace_span("sched.tick"):
+            with trace_span("engine.fetch_tokens"):
+                with trace_span("engine.wait"):
+                    time.sleep(0.003)
+                time.sleep(0.002)
+            with pytest.raises(KeyError):   # a span that raises still closes
+                with trace_span("engine.fetch_tokens"):
+                    raise KeyError("the error goes on")
+        def on_another_thread():
+            with trace_span("sched.idle"):
+                pass
+
+        elsewhere = threading.Thread(target=on_another_thread)
+        elsewhere.start()
+        elsewhere.join()
+    finally:
+        assert install_ledger(prev) is ledger
+    with trace_span("sched.tick"):          # not installed any more
+        pass
+    got = ledger.snapshot()
+    assert {n: r["count"] for n, r in got.items()} == {
+        "sched.tick": 1, "engine.fetch_tokens": 2, "engine.wait": 1}
+    assert got["engine.wait"]["self_s"] == got["engine.wait"]["total_s"] >= 0.003
+    fetch = got["engine.fetch_tokens"]
+    assert fetch["self_s"] >= 0.002 and fetch["total_s"] >= 0.005
+    assert fetch["self_s"] == pytest.approx(fetch["total_s"] - got["engine.wait"]["total_s"])
+    # over a tree of spans the own times add up to the root's whole time
+    assert sum(r["self_s"] for r in got.values()) == pytest.approx(
+        got["sched.tick"]["total_s"], rel=1e-9)
+
+
+def test_host_ledger_snapshots_from_other_threads_lose_nothing():
+    """One thread writes, as the serving loop does, while scrapes read:
+    every snapshot is whole (a tick's count never behind its child's by
+    more than the one span in flight) and the last one counts every
+    span."""
+    ledger, n, seen, errors = HostLedger(), 3000, [], []
+    done = threading.Event()
+
+    def write():
+        install_ledger(ledger)
+        try:
+            for _ in range(n):
+                with trace_span("sched.tick"):
+                    with trace_span("engine.wait"):
+                        pass
+        finally:
+            install_ledger(None)
+            done.set()
+
+    def read():
+        while not done.is_set():
+            snap = ledger.snapshot()
+            if snap:
+                ticks, waits = snap["sched.tick"]["count"], snap["engine.wait"]["count"]
+                if not 0 <= waits - ticks <= 1:
+                    errors.append((ticks, waits))
+                seen.append(ticks)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=write)] + [
+            threading.Thread(target=read) for _ in range(2 * (os.cpu_count() or 1))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and seen
+    final = ledger.snapshot()
+    assert final["sched.tick"]["count"] == final["engine.wait"]["count"] == n
+
+
+def test_the_serving_loop_feeds_its_ledger_and_metrics(params):
+    eng = InferenceEngine(params, CFG, num_slots=2, max_len=32, chunk_size=8,
+                          kv_block_size=4)
+    sched = Scheduler(eng)
+    mine = SpanTracer()
+    prev = set_tracer(mine)
+    server = ServeServer(sched, None, port=0, host="127.0.0.1").start()
+    try:
+        for prompt in ([3, 1, 4, 1, 5], [2, 7, 1, 8, 2, 8, 1, 8, 2, 8]):
+            status, _ = http_post_json(
+                f"http://127.0.0.1:{server.port}/v1/generate",
+                {"token_ids": prompt, "max_new_tokens": 4, "temperature": 0.0,
+                 "stop": False}, timeout=120)
+            assert status == 200
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/metrics", timeout=30) as r:
+            text = r.read().decode()
+    finally:
+        server.stop()
+        set_tracer(prev)
+    host = sched.stats()["host"]
+    assert {"sched.tick", "sched.admit", "sched.prefill", "engine.stage_chunk",
+            "engine.prefill_chunk", "engine.wait", "engine.stage",
+            "engine.decode_dispatch", "engine.fetch_tokens", "engine.advance",
+            "sched.deliver"} <= set(host)
+    assert "engine.fetch_counts" not in host      # a dense stack has no counters
+    # every span of the loop but its sleep is inside a tick, so the
+    # ticks' whole time is the sum of those spans' own times
+    ticks = host["sched.tick"]
+    assert sum(r["self_s"] for n, r in host.items() if n != "sched.idle") == (
+        pytest.approx(ticks["total_s"], rel=1e-9))
+    loop = [e for e in mine.events if e["name"] == "sched.tick"]
+    assert len(loop) == ticks["count"]
+    assert sum(e["dur"] for e in loop) == pytest.approx(ticks["total_s"], rel=0.05)
+    # the chip's wait in the chunk and in the tick's fetch; the tick's
+    # launch holds nothing
+    events = mine.events
+    assert set(_parents(events, "engine.wait")) == {"engine.prefill_chunk",
+                                                    "engine.fetch_tokens"}
+    dispatch = [e for e in events if e["name"] == "engine.decode_dispatch"]
+    assert dispatch and not any(_inside(e, d) for d in dispatch for e in events)
+    assert host["engine.wait"]["count"] == (host["engine.prefill_chunk"]["count"]
+                                            + host["engine.fetch_tokens"]["count"])
+    # one counter family on /metrics, labelled by the fixed span names
+    rows = dict(re.findall(
+        r'^nanodiloco_serve_host_seconds_total\{span="([^"]+)"\} (\S+)$', text, re.M))
+    assert "# TYPE nanodiloco_serve_host_seconds counter" in text
+    assert {"sched.tick", "engine.wait", "engine.fetch_tokens",
+            "engine.decode_dispatch"} <= set(rows) <= set(host)
+    assert all(float(v) >= 0 for v in rows.values())
+
+
+MIXED = LlamaConfig(
+    vocab_size=80, hidden_size=32, intermediate_size=64, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=2, explicit_head_dim=8,
+    layer_types=("sliding_attention", "full_attention"), sliding_window=8,
+    first_k_dense_replace=1, moe_intermediate_size=16, num_experts=4,
+    num_experts_per_tok=2, scoring_func="sigmoid", moe_dispatch="ragged",
+    loss_chunk=0)
+
+
+def test_a_mixed_stacks_counters_are_copied_after_the_wait():
+    eng = InferenceEngine(init_params(jax.random.key(0), MIXED), MIXED, num_slots=2,
+                          max_len=48, chunk_size=8, kv_block_size=4)
+    eng.capture_routing = True
+    mine = SpanTracer()
+    prev = set_tracer(mine)
+    try:
+        eng.prefill(0, GenRequest(prompt=tuple(range(1, 12)), max_new_tokens=4))
+        eng.step()
+        eng.step()
+    finally:
+        set_tracer(prev)
+    events = mine.events
+    # two chunks and two ticks, each: the wait, then the counters' copy
+    assert _parents(events, "engine.wait") == ["engine.prefill_chunk"] * 2 + [
+        "engine.fetch_tokens"] * 2
+    assert _parents(events, "engine.fetch_counts") == ["engine.prefill_chunk"] * 2 + [
+        "engine.fetch_tokens"] * 2
+    for parent in (e for e in events if e["name"] in ("engine.prefill_chunk",
+                                                      "engine.fetch_tokens")):
+        wait, counts = (next(e for e in events if e["name"] == n and _inside(e, parent))
+                        for n in ("engine.wait", "engine.fetch_counts"))
+        assert wait["t0"] + wait["dur"] <= counts["t0"]
+    dispatch = [e for e in events if e["name"] == "engine.decode_dispatch"]
+    assert len(dispatch) == 2 and not any(_inside(e, d) for d in dispatch for e in events)
+    assert eng.moe_stats()["by_program"]["decode"]["moe_pairs"] > 0
+    assert len(eng.routing_log[0]) == 2 + 2
 
 
 # -- named scopes in the lowered programs -----------------------------------
