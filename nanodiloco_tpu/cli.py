@@ -96,9 +96,9 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["dense", "flash", "ring"],
                    help="dense (the default) honors attention padding masks "
                         "and picks its implementation from the shapes: a "
-                        "fused kernel on a one-device TPU program with heads "
-                        "of 64 or 128 and a sequence in tiles of 1024, query "
-                        "blocks elsewhere; flash/ring are packed-sequence "
+                        "fused kernel on a TPU (one device or a mesh) with "
+                        "heads of 64 or 128 and a sequence in tiles of 1024, "
+                        "query blocks elsewhere; flash/ring are packed-sequence "
                         "kernels that ignore the masks (fine for packed "
                         "data and tail-only padding)")
     p.add_argument("--loss-chunk", type=int, default=None,

@@ -436,7 +436,7 @@ def dense_attention(
     mask, taken as given. Every block's scores are an array in HBM: the
     path of every shape ``fused_attention_applies`` does not send to the
     kernel (heads neither of 64 nor of 128, short or ragged sequences, an explicit
-    mask, a partitioned mesh, any backend but a TPU), and the statement
+    mask, a sequence-parallel axis, any backend but a TPU), and the statement
     of the mathematics the kernel is tested against.
 
     Causal attention runs in query blocks of ``dense_block_rows(S, bq)``
@@ -487,7 +487,7 @@ def dense_attention(
 
 def fused_attention_applies(
     platform: str, s: int, head_dim: int, mask_ndim: int | None,
-    sp_axis: bool, partitioned: bool,
+    sp_axis: bool,
 ) -> bool:
     """Whether ``_attention`` hands a layer of the default
     (``attention_impl="dense"``) implementation to the fused kernel and
@@ -497,13 +497,14 @@ def fused_attention_applies(
     (the sizes a chip sweep measured), the mask is none (``mask_ndim``
     None) or a [B, S] validity array (an explicit [B?, 1, S, S] mask is
     taken as given, which only dense blocks can), no sequence-parallel
-    axis is bound, the ambient mesh is one device or wholly manual
-    (``partitioned`` false: Mosaic refuses a kernel inside an automatically
-    partitioned program), and the sequence is whole tiles of 1,024: the
-    kernel was ahead at every length the sweeps measured, 1,024 to 8,192
-    (PERF.md section 6: 2.1-4.6 times at heads of 128, 1.2-2.7 at heads of 64)."""
+    axis is bound (ring attention keeps ``sp``), and the sequence is whole
+    tiles of 1,024: the kernel was ahead at every length the sweeps
+    measured, 1,024 to 8,192 (PERF.md section 6: 2.1-4.6 times at heads
+    of 128, 1.2-2.7 at heads of 64). The mesh is not asked: on one the
+    compiler partitions, the kernel runs inside a ``shard_map`` over its
+    free axes (``ops/on_mesh.py:kernel_on_mesh``)."""
     if (platform != "tpu" or not (head_dim == 64 or head_dim % 128 == 0)
-            or mask_ndim not in (None, 2) or sp_axis or partitioned):
+            or mask_ndim not in (None, 2) or sp_axis):
         return False
     # the kernels' module loads Pallas: only where a kernel can run
     from nanodiloco_tpu.ops.splash_attention import whole_tiles
@@ -511,17 +512,7 @@ def fused_attention_applies(
     return whole_tiles(s)
 
 
-def mesh_partitions() -> bool:
-    """Whether the ambient mesh (``jax.set_mesh``) spans devices over an
-    axis no enclosing ``shard_map`` has made manual: a program the
-    compiler partitions itself."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return not (mesh.empty or mesh.size == 1
-                or not set(mesh.axis_names) - set(mesh.manual_axes))
-
-
-def attention_paths(cfg: LlamaConfig, s: int, *, sp_axis: bool = False,
-                    partitioned: bool = False) -> dict[str, int]:
+def attention_paths(cfg: LlamaConfig, s: int, *, sp_axis: bool = False) -> dict[str, int]:
     """How many of the stack's layers take the fused kernel and how many
     ``dense_attention``'s blocks at sequence length ``s``, by the rule
     ``_attention`` applies when the program is traced (a [B, S] validity
@@ -529,7 +520,7 @@ def attention_paths(cfg: LlamaConfig, s: int, *, sp_axis: bool = False,
     "flash" and "ring", which name their own kernels."""
     n = cfg.num_hidden_layers if cfg.attention_impl == "dense" else 0
     fused = fused_attention_applies(
-        jax.default_backend(), s, cfg.head_dim, None, sp_axis, partitioned)
+        jax.default_backend(), s, cfg.head_dim, None, sp_axis)
     return {"fused": n if fused else 0, "dense": 0 if fused else n}
 
 
@@ -546,9 +537,10 @@ def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None,
     (``fused_attention_applies``): on a TPU, heads of 64 or 128 over a
     long sequence in whole tiles run the fused kernel forward and backward
     (ops/splash_attention.py: no score reaches HBM, K and V stay at Hkv
-    heads); every other shape, platform and mesh runs ``dense_attention``'s
-    query blocks, for which K and V are expanded to the query heads
-    here. flash and ring take k/v at Hkv heads too."""
+    heads), on one device and on a mesh alike; every other shape and
+    platform, and a bound sequence-parallel axis, runs
+    ``dense_attention``'s query blocks, for which K and V are expanded to
+    the query heads here. flash and ring take k/v at Hkv heads too."""
     if cfg.attention_impl not in ("dense", "flash", "ring"):
         raise ValueError(f"unknown attention_impl: {cfg.attention_impl!r}")
     if cfg.attention_impl == "flash":
@@ -561,8 +553,7 @@ def _attention(cfg: LlamaConfig, q, k, v, valid, axis_name: str | None,
         return ring_attention(q, k, v, axis_name=axis_name)
     if cfg.attention_impl == "dense" and fused_attention_applies(
             jax.default_backend(), q.shape[1], q.shape[3],
-            None if valid is None else valid.ndim, axis_name is not None,
-            mesh_partitions()):
+            None if valid is None else valid.ndim, axis_name is not None):
         from nanodiloco_tpu.ops.splash_attention import splash_attention
 
         return splash_attention(q, k, v, valid, window=window)

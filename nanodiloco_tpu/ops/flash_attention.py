@@ -9,7 +9,7 @@ hardware:
   sequence does not divide into the kernel's blocks. It never gives way
   to another path there: a run that asked for the kernel and is not
   getting it must say so. Under a multi-device mesh the call sits in a
-  ``shard_map`` (``_pallas_on_mesh``).
+  ``shard_map`` (``ops/on_mesh.py:kernel_on_mesh``).
 - Elsewhere (and under ``impl="scan"``, on any backend) it runs the
   same algorithm as a ``lax.scan`` over key/value blocks with per-block
   rematerialization — O(S * block) live memory instead of O(S^2),
@@ -30,8 +30,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
+from nanodiloco_tpu.ops.on_mesh import kernel_on_mesh
 from nanodiloco_tpu.ops.online_softmax import block_update, finalize_grouped
 
 
@@ -145,37 +145,11 @@ def flash_attention(
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "scan"
     if impl == "pallas":
-        return _pallas_on_mesh(q, k, v, causal=causal, block_q=bq, block_k=bk)
+        from nanodiloco_tpu.ops.pallas.flash_attention import pallas_flash_attention
+
+        kernel = partial(pallas_flash_attention, causal=causal, block_q=bq, block_k=bk)
+        return kernel_on_mesh(kernel, q, k, v)
     return _flash_attention_scan(q, k, v, causal=causal, block_size=block_size)
-
-
-def _pallas_on_mesh(q, k, v, **kernel_args) -> jax.Array:
-    """The Pallas kernel under the ambient mesh (``jax.set_mesh``).
-
-    Mosaic refuses a kernel inside an automatically partitioned program,
-    so on a multi-device mesh the call is a ``shard_map`` over every axis
-    not already manual: batch over ``fsdp`` and heads over ``tp`` — the
-    layout the batch and the projections arrive in (parallel/sharding.py)
-    — each where it divides, else whole on every device. Attention mixes
-    neither axis, so the region holds no collective. The DiLoCo worker
-    axis reaches here as a vmap named ``spmd_axis_name="diloco"``
-    (parallel/diloco.py), which shards the vmapped dimension the same
-    way."""
-    from nanodiloco_tpu.ops.pallas.flash_attention import pallas_flash_attention
-
-    kernel = partial(pallas_flash_attention, **kernel_args)
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1 or not set(mesh.axis_names) - set(mesh.manual_axes):
-        return kernel(q, k, v)
-
-    def over(axis: str, *sizes: int) -> str | None:
-        free = axis in mesh.axis_names and axis not in mesh.manual_axes
-        return axis if free and all(n % mesh.shape[axis] == 0 for n in sizes) else None
-
-    spec = P(over("fsdp", q.shape[0]), None, over("tp", q.shape[2], k.shape[2]), None)
-    return jax.shard_map(
-        kernel, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
-    )(q, k, v)
 
 
 @partial(jax.jit, static_argnames=("causal", "block_size"))

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
+from nanodiloco_tpu.ops.on_mesh import kernel_on_mesh
+
 # Rows of a query tile and of a key tile in all three kernels, and the
 # keys a tile's inner step computes at once: one chip sweep on a v5e at
 # 16,384 tokens of 32 heads over 4 of 128, S 1,024 to 8,192, full and
@@ -88,15 +90,21 @@ def splash_attention(
     products in q's dtype accumulated in float32; the scores stay in
     float32 (the dense blocks round them to q's dtype first).
     ``interpret`` and ``sizes`` are for tests and sweeps: the program's
-    tiles follow from S."""
-    s, h, hd = q.shape[1:]
-    kernel = _kernel(s, h, window, interpret, sizes or block_sizes(s))
-    # the library applies no scale: on q (one more bf16 rounding; exact at heads of 64)
-    q = q * jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
-    q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    ids = None
-    if valid is not None:
-        real = (valid > 0).astype(jnp.int32)
-        ids = splash.SegmentIds(q=real, kv=real)
-    out = jax.vmap(kernel)(q, k, v, ids)
-    return jnp.swapaxes(out, 1, 2)
+    tiles follow from S. On a mesh the compiler partitions, the call
+    runs inside ``kernel_on_mesh``'s ``shard_map`` (ops/on_mesh.py),
+    each device's kernel over its own rows and heads."""
+    def local(q, k, v, *rows):
+        # the kernel is built for the heads this device holds
+        s, h, hd = q.shape[1:]
+        kernel = _kernel(s, h, window, interpret, sizes or block_sizes(s))
+        # the library applies no scale: on q (one more bf16 rounding; exact at heads of 64)
+        q = q * jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
+        q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+        ids = None
+        if rows:
+            real = (rows[0] > 0).astype(jnp.int32)
+            ids = splash.SegmentIds(q=real, kv=real)
+        out = jax.vmap(kernel)(q, k, v, ids)
+        return jnp.swapaxes(out, 1, 2)
+
+    return kernel_on_mesh(local, q, k, v, *(() if valid is None else (valid,)))

@@ -1309,13 +1309,13 @@ class Diloco:
         their attention through the fused kernel and how many through
         dense blocks in this object's programs at rows of ``seq_len``
         (``models/llama.py:attention_paths``; decided when a program is
-        traced, from the platform, the shapes and this mesh). A mesh of
-        more than one device is partitioned by the compiler, which a
-        Mosaic kernel does not survive: dense there."""
+        traced, from the platform, the shapes and the sequence-parallel
+        axis). On a mesh of several devices the kernel runs inside a
+        ``shard_map`` over the axes the compiler would partition
+        (``ops/on_mesh.py:kernel_on_mesh``), so the mesh changes no count."""
         from nanodiloco_tpu.models.llama import attention_paths
 
-        return attention_paths(
-            self.model_cfg, seq_len, sp_axis=self.sp > 1, partitioned=self.mesh.size > 1)
+        return attention_paths(self.model_cfg, seq_len, sp_axis=self.sp > 1)
 
     def sync_payload_report(self) -> dict:
         """What one outer sync actually moves per worker, by wire mode —

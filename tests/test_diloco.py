@@ -722,15 +722,15 @@ def test_int4_wire_trains():
 
 @pytest.mark.parametrize("platform,workers,want", [
     ("tpu", 1, {"fused": 2, "dense": 0}),   # one device: the kernel
-    ("tpu", 4, {"fused": 0, "dense": 2}),   # a mesh the compiler partitions
+    ("tpu", 4, {"fused": 2, "dense": 0}),   # a mesh the compiler partitions: the door
     ("cpu", 1, {"fused": 0, "dense": 2}),
 ])
 def test_attention_paths_describe_the_round(monkeypatch, platform, workers, want):
     """``Diloco.attention_paths``: the count of layers whose attention
     takes the fused kernel and dense blocks in this object's programs,
-    from the platform, the model's head size, the row length and the
-    mesh: heads of 128 and of 64 (SmolLM2's) alike, heads of 32 dense
-    everywhere."""
+    from the platform, the model's head size and the row length, on one
+    device and on a mesh alike: heads of 128 and of 64 (SmolLM2's) alike,
+    heads of 32 dense everywhere."""
     import dataclasses
 
     from nanodiloco_tpu.ops.splash_attention import TILE

@@ -1,6 +1,9 @@
 """The fused attention kernel of the training path (ops/splash_attention.py)
-against ``dense_attention``, in interpret mode on the CPU, and the rule by
-which ``_attention`` chooses between them, as a table."""
+against ``dense_attention``, in interpret mode on the CPU, through the
+``shard_map`` a partitioned mesh puts it in, and the rule by which
+``_attention`` chooses between them, as a table."""
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -152,7 +155,7 @@ def test_tiles_are_a_function_of_the_sequence_alone():
         block_sizes(TILE + 128)
 
 
-# (platform, S, head_dim, mask_ndim, sp_axis, partitioned) -> fused?
+# (platform, S, head_dim, mask_ndim, sp_axis, asked under a partitioned mesh) -> fused?
 RULE = [
     pytest.param(("tpu", 8192, 128, 2, False, False), True, id="mellum_on_a_tpu"),
     pytest.param(("tpu", 8192, 128, None, False, False), True, id="mellum_no_mask"),
@@ -162,21 +165,31 @@ RULE = [
     pytest.param(("tpu", 2048, 96, 2, False, False), False, id="heads_of_96"),
     pytest.param(("tpu", 2048, 32, 2, False, False), False, id="heads_of_32"),
     pytest.param(("tpu", 2048, 192, 2, False, False), False, id="heads_of_192"),
-    pytest.param(("tpu", 2048, 64, 2, False, True), False, id="heads_of_64_partitioned_mesh"),
+    pytest.param(("tpu", 2048, 64, 2, False, True), True, id="heads_of_64_partitioned_mesh"),
     pytest.param(("tpu", 8192, 128, 4, False, False), False, id="explicit_4d_mask"),
     pytest.param(("tpu", 8192 + 128, 128, 2, False, False), False, id="no_whole_tiles"),
     pytest.param(("tpu", TILE // 2, 128, 2, False, False), False, id="under_a_tile"),
     pytest.param(("tpu", 8192, 128, 2, True, False), False, id="sequence_parallel_axis"),
-    pytest.param(("tpu", 8192, 128, 2, False, True), False, id="partitioned_mesh"),
+    pytest.param(("tpu", 8192, 128, 2, False, True), True, id="partitioned_mesh"),
     pytest.param(("cpu", 8192, 128, 2, False, False), False, id="mellum_on_the_cpu"),
     pytest.param(("gpu", 8192, 128, None, False, False), False, id="mellum_on_a_gpu"),
     pytest.param(("cpu", 2048, 64, 2, False, False), False, id="smollm2_on_the_cpu"),
 ]
 
 
+def _partitioned():
+    """Two devices over ``fsdp`` as the ambient mesh: a program the
+    compiler partitions."""
+    from jax.sharding import Mesh
+
+    return jax.set_mesh(Mesh(np.array(jax.devices()[:2]), ("fsdp",)))
+
+
 @pytest.mark.parametrize("args,fused", RULE)
 def test_the_rule_that_chooses_the_kernel(args, fused):
-    assert fused_attention_applies(*args) is fused
+    *args, partitioned = args
+    with _partitioned() if partitioned else contextlib.nullcontext():
+        assert fused_attention_applies(*args) is fused
 
 
 MELLUM = LlamaConfig(
@@ -194,7 +207,8 @@ SMOLLM = LlamaConfig(hidden_size=128, num_attention_heads=2, num_hidden_layers=3
 def test_the_count_of_layers_on_each_path(monkeypatch, platform, cfg, s, want):
     monkeypatch.setattr(jax, "default_backend", lambda: platform)
     assert attention_paths(cfg, s) == want
-    assert attention_paths(cfg, s, partitioned=True)["fused"] == 0
+    with _partitioned():
+        assert attention_paths(cfg, s) == want
 
 
 @pytest.mark.parametrize("platform", ["tpu", "cpu"])
@@ -225,14 +239,65 @@ def test_attention_hands_the_kernel_unexpanded_heads_the_mask_and_the_window(
 
 
 def test_inside_a_partitioned_mesh_attention_stays_dense(monkeypatch):
-    from jax.sharding import Mesh
-
+    """Only a bound sequence-parallel axis keeps a layer on dense blocks
+    inside a partitioned mesh: without one, ``_attention`` on a TPU hands
+    the layer to the kernel, which sits in a ``shard_map`` over the
+    mesh's free axes, each device's call over its own rows."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert not llama.mesh_partitions()
-    mesh = Mesh(np.array(jax.devices()[:2]), ("fsdp",))
-    with jax.set_mesh(mesh):
-        assert llama.mesh_partitions()
-        manual = jax.shard_map(
-            lambda x: x + llama.mesh_partitions(), in_specs=jax.P("fsdp"),
-            out_specs=jax.P("fsdp"))(jnp.zeros(2))
-    assert manual.tolist() == [0.0, 0.0]  # wholly manual: not partitioned
+    q = jax.ShapeDtypeStruct((2, 2 * TILE, 2, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 2 * TILE, 1, 128), jnp.bfloat16)
+    valid = jax.ShapeDtypeStruct((2, 2 * TILE), jnp.int32)
+
+    def primitives(axis_name):
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, m: _attention(MELLUM, q, k, v, m, axis_name, 1024))(q, kv, kv, valid)
+        return str(jaxpr)
+
+    with _partitioned():
+        fused, dense = primitives(None), primitives("sp")
+    assert "shard_map" in fused and "pallas_call" in fused
+    assert "bf16[1,2,2048,128]" in fused  # a device's rows: 1 of 2 sequences
+    assert "pallas_call" not in dense and "shard_map" not in dense
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "left_padded"])
+def test_the_kernel_through_the_mesh_door_matches_one_device(masked):
+    """Four DiLoCo workers over a four-device mesh (``MeshConfig(diloco=4)``),
+    the worker axis a vmap named ``spmd_axis_name="diloco"`` as the trainer's
+    (parallel/diloco.py): the kernel runs in ``kernel_on_mesh``'s
+    ``shard_map``, one worker a device, and gives each worker's loss and
+    all three gradients as the same call on one device does, with a
+    left-padded [B, S] validity array and without; nothing is gathered."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from nanodiloco_tpu.parallel import MeshConfig, build_mesh
+
+    W = 4
+    q, k, v, w = zip(*(_inputs(heads=(6, 2, 64)) for _ in range(W)))
+    # each worker its own inputs
+    scale = 1 + jnp.arange(W).reshape(W, 1, 1, 1, 1) / 8
+    args = tuple(jnp.stack(x) * scale for x in (q, k, v, w))
+    if masked:
+        args += (jnp.stack([_valid()] * W),)
+
+    def loss(q, k, v, w, *valid):
+        out = splash_attention(q, k, v, *valid, interpret=True, sizes=SMALL)
+        rows = valid[0][:, :, None, None].astype(jnp.float32) if valid else 1.0
+        return jnp.sum(out.astype(jnp.float32) * w * rows)
+
+    grad = jax.value_and_grad(loss, (0, 1, 2))
+    mesh = build_mesh(MeshConfig(diloco=W), devices=jax.devices()[:W])
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.vmap(grad))(*args)
+        placed = [jax.device_put(x, NamedSharding(mesh, P("diloco"))) for x in args]
+        with jax.set_mesh(mesh):
+            fn = jax.jit(jax.vmap(grad, spmd_axis_name="diloco"))
+            lowered = fn.lower(*placed)
+            got = fn(*placed)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=2e-5)
+    assert "manual_computation" in lowered.as_text()  # the door's shard_map
+    text = lowered.compile().as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert got[1][0].addressable_shards[0].data.shape[0] == 1  # one worker a device

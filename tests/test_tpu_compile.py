@@ -460,3 +460,45 @@ def test_flash_kernel_stays_on_its_worker_shard(topo, monkeypatch, workers, fsdp
     assert len(calls) >= 3
     rows = (8 // fsdp) * model.num_attention_heads  # one worker's, a device
     assert all(f"bf16[{rows},1024,32]" in c for c in calls), calls[0][:300]
+
+
+def test_smollm2_over_four_workers_runs_the_kernel_on_each_worker_shard(topo, monkeypatch):
+    """``smollm2-360m.diloco.4chip``'s layout, ``MeshConfig(diloco=4)`` over
+    the four described chips, at SmolLM2-360M's widths (hidden 960, 15
+    heads over 5 of 64, FFN 2560, vocabulary 49,152) and depth 2, where
+    the rule sees a TPU: the inner step is partitioned by the compiler,
+    and its attention runs the fused kernel in ``kernel_on_mesh``'s
+    ``shard_map`` — every Mosaic call over one worker's 8 rows, never the
+    32 of all four gathered, and no block of dense score rows left
+    ([8,15,256,2048] is the last query block's)."""
+    import re
+
+    from nanodiloco_tpu.ops.splash_attention import _kernel, block_sizes
+    from nanodiloco_tpu.parallel import Diloco, DilocoConfig, MeshConfig, build_mesh
+    from nanodiloco_tpu.parallel.sharding import batch_spec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = LlamaConfig(
+        vocab_size=49152, hidden_size=960, intermediate_size=2560, num_attention_heads=15,
+        num_key_value_heads=5, num_hidden_layers=2, tie_word_embeddings=True,
+        dtype="bfloat16", loss_chunk=512, remat=True)
+    assert model.head_dim == 64 and model.attention_impl == "dense"
+    mesh = build_mesh(MeshConfig(diloco=4), devices=topo.devices)
+    dl = Diloco(model, DilocoConfig(num_workers=4, inner_steps=2, grad_accum=1), mesh)
+    assert dl.attention_paths(2048) == {"fused": 2, "dense": 0}
+    state = _abstract_diloco_state(dl, mesh)
+    tok = jax.ShapeDtypeStruct(
+        (4, 1, 8, 2048), np.int32, sharding=NamedSharding(mesh, batch_spec(sp=False)))
+    # the kernel's tables are arrays made while the program is traced, on
+    # the ambient mesh's devices, which described chips cannot run: made
+    # here first, on the host (the chip makes them on its own devices)
+    _kernel(2048, 15, None, False, block_sizes(2048))
+    with jax.set_mesh(mesh):
+        compiled = dl._inner_jit.lower(state, tok, tok).compile()
+    calls = _kernel_calls(compiled)
+    # the layer body's pass, and in the backward scan its recompute, dq and dk/dv
+    assert len(calls) == 4
+    assert all("bf16[8,15,2048,64]" in c and "[32," not in c for c in calls), calls[0][:400]
+    text = compiled.as_text()
+    assert not re.search(r"(?:bf16|f32)\[8,15,256,2048\]", text)
+    assert _live_bytes(compiled) < V5E_HBM_BYTES
